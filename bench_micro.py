@@ -191,17 +191,11 @@ def bench_routing() -> dict:
     import tempfile
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"  # trust the (virtual) platform
     os.environ.pop("CBFT_TPU_MIN_BATCH", None)
     os.environ.pop("CBFT_TPU_MERKLE_MIN_LEAVES", None)
-    import jax
+    from cometbft_tpu.crypto.tpu import aot
 
-    jax.config.update("jax_platforms", "cpu")
-    cache = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-    )
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+    aot.compile_cache_dir()
 
     from cometbft_tpu.crypto import batch as cryptobatch
     from cometbft_tpu.crypto.batch import BackendSpec
@@ -291,7 +285,6 @@ def bench_scheduler() -> dict:
     import threading
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     from bench import _make_batch
     from cometbft_tpu.crypto import batch as cryptobatch
@@ -403,7 +396,6 @@ def bench_telemetry() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     from bench import _make_batch
     from cometbft_tpu.crypto import ed25519 as ed
@@ -495,7 +487,6 @@ def bench_memory() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     from bench import _make_batch
     from cometbft_tpu.crypto import ed25519 as ed
@@ -579,18 +570,11 @@ def bench_coldboot() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
-    import jax
-
-    cache = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-    )
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-
     from cometbft_tpu.crypto import ed25519 as ed
     from cometbft_tpu.crypto.tpu import aot, ed25519_batch
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
+
+    aot.compile_cache_dir()
 
     reg = aot.default_registry()
     # single-device variants are skipped: with the virtual mesh up,
@@ -653,7 +637,6 @@ def bench_wire() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     from bench import _make_batch
     from cometbft_tpu.crypto import ed25519 as ed
@@ -742,7 +725,6 @@ def bench_decisions() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     from bench import _make_batch
     from cometbft_tpu.crypto import decisions as declib
@@ -832,7 +814,6 @@ def bench_pack() -> dict:
     import os
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ["CBFT_TPU_PROBE"] = "0"
 
     import numpy as np
 

@@ -231,8 +231,9 @@ class ConsensusState(BaseService):
         t = getattr(self, "_receive_thread", None)
         if t is not None and t is not threading.current_thread():
             # 180 s: must outlast the longest bounded stall a finalize
-            # can hit (the one-time device probe is capped at
-            # CBFT_TPU_PROBE_TIMEOUT 120 s + 30 s slack)
+            # can hit (a supervised device dispatch is abandoned after
+            # [crypto] dispatch_timeout_ms, 60 s by default, and the CPU
+            # re-verify of a large commit follows it)
             t.join(timeout=180.0)
             if t.is_alive():
                 # stopping the WAL now would reintroduce the dropped-

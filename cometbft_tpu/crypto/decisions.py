@@ -233,7 +233,7 @@ class RouteDecision:
         # must never count as a "road not taken" in regret.
         self.feasible = feasible
         # which router produced the taken route: "priced" | "threshold"
-        # | "rolled-back" | "pinned" (None = pre-router record)
+        # | "rolled-back" | "pinned" | "floor" (None = pre-router record)
         self.router: Optional[str] = None
         self.taken: Optional[str] = None
         self.final: Optional[str] = None
@@ -322,6 +322,7 @@ class DecisionLedger:
         self._seq = 0
         self._stats: Dict[tuple, _RouteStat] = {}
         self._counts: Dict[str, int] = {}
+        self._lanes: Dict[str, int] = {}
         self._fallbacks: Dict[str, int] = {}
         self._recent: deque = deque(maxlen=_MAX_RECENT)
         # rolling windows behind MAPE / regret rate (undiverted only)
@@ -432,6 +433,7 @@ class DecisionLedger:
         a = 2.0 / (self.window + 1.0)
         with self._lock:
             self._counts[taken] = self._counts.get(taken, 0) + 1
+            self._lanes[taken] = self._lanes.get(taken, 0) + dec.n
             if dec.diverted:
                 self._fallbacks[taken] = self._fallbacks.get(taken, 0) + 1
             key = (taken, dec.bucket)
@@ -616,6 +618,13 @@ class DecisionLedger:
         with self._lock:
             return dict(self._counts)
 
+    def lanes(self) -> Dict[str, int]:
+        """Per-taken-route signature lanes — what reconciles against
+        the wire ledger's per-route lanes (every lane a device route
+        took must have reached the device, and no other)."""
+        with self._lock:
+            return dict(self._lanes)
+
     def watchdog_state(self) -> Dict[str, Any]:
         with self._lock:
             return {
@@ -646,6 +655,7 @@ class DecisionLedger:
                 for k, st in sorted(self._stats.items())
             ]
             counts = dict(self._counts)
+            lanes = dict(self._lanes)
             fallbacks = dict(self._fallbacks)
             recent = list(self._recent)
             ring = list(self._ring)
@@ -653,6 +663,7 @@ class DecisionLedger:
         return {
             "window": self.window,
             "counts": counts,
+            "lanes": lanes,
             "fallbacks": fallbacks,
             "profiles": profiles,
             "windowed": win,
@@ -707,7 +718,8 @@ def note_taken(route: str) -> None:
 
 def note_router(router: str) -> None:
     """Tag the current decision with the router that produced it
-    ("priced" | "threshold" | "rolled-back" | "pinned"); no-op without
+    ("priced" | "threshold" | "rolled-back" | "pinned" | "floor" — the
+    backend's routing floor kept the flush on the host); no-op without
     a decision. route_audit --assert-live judges only "priced"-tagged
     records against the argmin."""
     dec = current()

@@ -99,6 +99,7 @@ from cometbft_tpu.crypto.batch import (
     Backend,
     BackendSpec,
     CPUBatchVerifier,
+    clears_device_floor,
     new_batch_verifier,
 )
 from cometbft_tpu.libs import trace as tracelib
@@ -203,6 +204,14 @@ def shard_min_batch_default(config_value: Optional[int] = None) -> int:
     if learned:
         return int(learned)
     return DEFAULT_SHARD_MIN_BATCH
+
+
+def _built_s() -> float:
+    """Seconds this thread's dispatches have spent building executables
+    (crypto/tpu/aot.py BuildClock; the supervisor carries its worker's
+    over). 0.0 on a node that never loaded the device plane."""
+    aot = sys.modules.get("cometbft_tpu.crypto.tpu.aot")
+    return aot.build_clock().total() if aot is not None else 0.0
 
 
 class Metrics:
@@ -1311,6 +1320,7 @@ class VerifyScheduler(BaseService):
                 feasible=self._decision_feasible(items, breakers),
             )
         t_verify = time.perf_counter()
+        built = _built_s()
         try:
             with tracelib.use(dspan), declib.use(dec):
                 if has_rows:
@@ -1325,7 +1335,11 @@ class VerifyScheduler(BaseService):
             # finish whenever the route ladder ran (taken was noted) so
             # ledger counts reconcile with _routes even on a raise
             if dec is not None and dec.taken is not None:
-                declgr.finish(dec, time.perf_counter() - t_verify)
+                # a cold bucket's compile is not what the route costs
+                declgr.finish(
+                    dec,
+                    time.perf_counter() - t_verify - (_built_s() - built),
+                )
         # flush-level ledger tag: which wire route served this dispatch
         # rides on the dispatch span, and the verdict-demux loop below is
         # the ledger's fifth phase (host-side fan-out back to futures)
@@ -1371,7 +1385,16 @@ class VerifyScheduler(BaseService):
                 self.spec
             )
         self._note_route("service")
-        return servicelib.verify_mixed_flush(batch, verifier)
+        return servicelib.verify_mixed_flush(
+            batch, verifier, self._note_row_fallback
+        )
+
+    def _note_row_fallback(self, exc: BaseException, n: int) -> None:
+        self.metrics.cpu_fallbacks.add()
+        self.logger.error(
+            "service row dispatch failed; falling back to the host "
+            "verifier", err=repr(exc), n=n, backend=self.spec.name,
+        )
 
     # decision-plane input gathering — each best-effort and only run
     # when a decision ledger is installed
@@ -1466,7 +1489,10 @@ class VerifyScheduler(BaseService):
         nor counted as a cheaper road not taken.
 
         * cpu — always feasible (the ground truth never goes away); a
-          cpu backend spec makes it the ONLY feasible rung.
+          cpu backend spec makes it the ONLY feasible rung, and so does
+          a tpu-backend flush none of whose curve partitions reaches its
+          routing floor (batch.curve_floors): the backend would verify
+          it on the host whatever route it was handed.
         * single — feasible unless every supervised breaker is BROKEN
           (the supervisor would cpu-route the dispatch anyway).
         * sharded — single's gate AND a supervised healthy ≥2-device
@@ -1482,7 +1508,7 @@ class VerifyScheduler(BaseService):
             "cpu": True, "single": False, "sharded": False,
             "indexed": False, "device_hash": False,
         }
-        if self.spec.name == "cpu":
+        if self.spec.name == "cpu" or self._floor_keeps_on_host(items):
             return feasible
         all_broken = bool(breakers) and all(
             s == "broken" for s in breakers.values()
@@ -1509,6 +1535,17 @@ class VerifyScheduler(BaseService):
             except Exception:  # noqa: BLE001 - feasibility is advisory
                 pass
         return feasible
+
+    def _floor_keeps_on_host(self, items: Sequence[Item]) -> bool:
+        """True when the tpu backend's own per-curve floors would verify
+        this whole flush on the host. Both routers stop here first, so
+        such a flush is routed, counted and metered as ``cpu`` instead
+        of travelling to the backend under a device route's name. Other
+        backend names (fault-injection doubles, embedders' own) have no
+        floor."""
+        return self.spec.name == "tpu" and not clears_device_floor(
+            (pk for pk, _, _ in items), self.spec
+        )
 
     def _router_guard(self, declgr) -> bool:
         """Hysteretic rollback guard for the priced router — the qos
@@ -1597,9 +1634,13 @@ class VerifyScheduler(BaseService):
         (counted label, supervisor route, router tag). Precedence:
         CBFT_MESH_ROUTE pin > priced argmin over feasible candidates
         (router mode "priced", rollback guard cold, every feasible
-        primary priced) > the threshold ladder."""
+        primary priced) > the threshold ladder. The backend's routing
+        floor stands above all three: a pin chooses between device
+        routes, it does not lift a flush over the floor."""
         if self.spec.name == "cpu":
             return "cpu", None, ROUTER_THRESHOLD
+        if self._floor_keeps_on_host(items):
+            return "cpu", None, "floor"
         pin = self._pin_route()
         if pin is not None:
             label = "sharded" if pin == "sharded" else "single"
@@ -1646,10 +1687,17 @@ class VerifyScheduler(BaseService):
             label if label in ("cpu", "sharded", "indexed") else "single"
         )
         if label == "cpu" and self.spec.name != "cpu":
-            # the priced argmin chose the host rung for a device spec
-            # (small flush under the transfer floor): dispatch straight
-            # on the ground truth — no supervisor round-trip to lose
-            return self._cpu_ground_truth(items), "cpu"
+            # the floor or the priced argmin chose the host rung for a
+            # device spec (small flush under the transfer floor):
+            # dispatch straight on the ground truth — no supervisor
+            # round-trip to lose — and meter it on the host pool
+            t0 = time.monotonic()
+            mask = self._cpu_ground_truth(items)
+            if self._telemetry is not None:
+                self._telemetry.note_device_busy(
+                    "cpu", t0, time.monotonic(), len(items)
+                )
+            return mask, "cpu"
         if self._supervisor is not None:
             # supervised path: watchdog, circuit breaker, retry/hedge
             # ladder, and corruption audit live in crypto/supervisor.py —

@@ -94,7 +94,11 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from cometbft_tpu.crypto import qos as qoslib, wire as wirelib
-from cometbft_tpu.crypto.batch import BackendSpec, CPUBatchVerifier
+from cometbft_tpu.crypto.batch import (
+    BackendSpec,
+    CPUBatchVerifier,
+    resolved_device_plane,
+)
 from cometbft_tpu.crypto.scheduler import Item, VerifyFuture
 from cometbft_tpu.libs.log import Logger
 from cometbft_tpu.libs.metrics import Registry
@@ -740,31 +744,34 @@ def host_row_verifier() -> CachingRowVerifier:
 
 def resolve_row_verifier(spec=None) -> Callable[[np.ndarray], np.ndarray]:
     """Pick the row verifier for a scheduler that received row payloads:
-    the device kernel when the node runs a real accelerator plane, the
-    host ground truth otherwise. (The CPU-jax compact kernel pays a
-    multi-second compile for no batching win — the host path is both
-    faster and exact for CPU-only deployments.)"""
+    the device kernel when the backend spec asks for one and jax runs an
+    accelerator, the host ground truth otherwise. (The CPU-jax compact
+    kernel pays a multi-second compile for no batching win — the host
+    path is both faster and exact on the CPU platform.) A non-cpu spec
+    on a machine where jax cannot start RAISES here: handing a ``tpu``
+    daemon the host verifier would be a CPU service behind a device
+    name."""
     name = getattr(spec, "name", None) or os.environ.get(
         "CMT_CRYPTO_BACKEND", "cpu"
     )
     if name != "cpu":
-        try:
-            import jax
+        import jax
 
-            if jax.default_backend() != "cpu":
-                return dispatch_rows
-        except Exception:  # noqa: BLE001 - no device plane, host rung
-            pass
+        if jax.default_backend() != "cpu":
+            return dispatch_rows
     return host_row_verifier()
 
 
-def verify_mixed_flush(batch, row_verifier) -> List[bool]:
+def verify_mixed_flush(batch, row_verifier, on_fallback=None) -> List[bool]:
     """Verdict mask for one coalesced flush that contains at least one
     row-payload request. Triple requests pack ONCE into the same compact
     layout; row requests contribute their exact socket bytes (indexed
     frames host-gather their key rows unless the whole flush stays on
     the device path); the concatenated u8[128, N] block verifies in one
-    shot — this is the cross-client megabatch."""
+    shot — this is the cross-client megabatch. A verifier that raises
+    (the device died mid-flight) is reported through
+    ``on_fallback(exc, n_lanes)`` — the scheduler counts it under
+    cpu_fallbacks — before the host rung re-verifies the block."""
     blocks: List[np.ndarray] = []
     valids: List[np.ndarray] = []
     for req in batch:
@@ -779,7 +786,9 @@ def verify_mixed_flush(batch, row_verifier) -> List[bool]:
     valid = valids[0] if len(valids) == 1 else np.concatenate(valids)
     try:
         mask = np.asarray(row_verifier(full), dtype=bool)[: full.shape[1]]
-    except Exception:  # noqa: BLE001 - device died mid-flight: host rung
+    except Exception as exc:  # noqa: BLE001 - device died mid-flight: host rung
+        if on_fallback is not None:
+            on_fallback(exc, int(full.shape[1]))
         mask = np.asarray(
             host_row_verifier()(full), dtype=bool
         )[: full.shape[1]]
@@ -1608,6 +1617,8 @@ class VerifyService(BaseService):
                 "tenants_panel": panel,
             }
         out["pending"] = self.pending_requests()
+        out["backend"] = getattr(self._sched.spec, "name", None)
+        out["device_plane"] = resolved_device_plane()
         out["bytes_per_lane"] = {
             kind: payload_bytes[kind] / lanes[kind]
             for kind in ("compact", "indexed")

@@ -3,7 +3,7 @@ precompilation (ROADMAP item 2).
 
 First dispatch used to pay the whole XLA pipeline in-line: ~17 s of
 trace+compile on one chip (BENCH_onchip_probe ``compile_and_run_s``) and
-~103 s for the 8-way sharded program (SHARDED_MEGACOMMIT) — again on
+~103 s for the 8-way sharded program on the virtual CPU mesh — again on
 every restart, every new pow2 shape bucket, and every topology change.
 A validator that must vote within a round cannot absorb that. This
 module makes every executable the verify path can need exist BEFORE
@@ -28,9 +28,10 @@ traffic arrives:
   mismatched entry is discarded and recompiled, never run.
 
 * Warm boot — ``run_warm_boot`` pre-lowers and compiles the pow2
-  bucket ladder (min_pad…max_chunk; single-device and sharded variants
-  for the current topology) in priority order: the commit-p50 bucket
-  first, the megabatch cap last, refined by measured per-bucket compile
+  bucket ladder a routed flush can pad to ([crypto] min_batch's bucket
+  … max_chunk; single-device and sharded variants for the current
+  topology) in priority order: the canary's bucket, the commit-p50
+  bucket, the megabatch cap last, refined by measured per-bucket compile
   seconds from the calibration table when available. ``start_warm_boot``
   runs it on a background thread the supervisor's warmup canary joins
   before declaring HEALTHY; ``[crypto] warm_boot = eager|background|off``
@@ -58,9 +59,9 @@ from cometbft_tpu.libs.metrics import Registry
 
 SUBSYSTEM = "verify_aot"
 
-# the CPU fallback platform can't honor buffer donation and warns per
-# compile; same process-wide filter mesh.py installs (registry compiles
-# can happen before mesh is imported — the warm subprocess entry)
+# the CPU platform can't honor buffer donation and warns per compile;
+# same process-wide filter mesh.py installs (registry compiles can
+# happen before mesh is imported — tools/warm_cache.py)
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )
@@ -95,16 +96,21 @@ def unwrap_kernel(kernel) -> Any:
 
 class _KernelReg:
     """One explicitly-registered kernel: its stable name, the warmup
-    shape template (bucket -> arg (shape, dtype) list), and the default
-    donation spec the dispatch layer uses for it."""
+    shape template (bucket -> arg (shape, dtype) list), the default
+    donation spec the dispatch layer uses for it, and whether the
+    process's current routing can dispatch it at all."""
 
-    __slots__ = ("name", "kernel", "bucket_shapes", "donate_from")
+    __slots__ = ("name", "kernel", "bucket_shapes", "donate_from",
+                 "reachable", "forced")
 
-    def __init__(self, name, kernel, bucket_shapes, donate_from):
+    def __init__(self, name, kernel, bucket_shapes, donate_from, reachable,
+                 forced):
         self.name = name
         self.kernel = kernel
         self.bucket_shapes = bucket_shapes
         self.donate_from = donate_from
+        self.reachable = reachable
+        self.forced = forced
 
 
 def register_kernel(
@@ -112,15 +118,25 @@ def register_kernel(
     kernel,
     bucket_shapes: Optional[Callable[[int], List[Tuple[tuple, Any]]]] = None,
     donate_from: int = 0,
+    reachable: Optional[Callable[[], bool]] = None,
+    forced: bool = False,
 ) -> None:
     """Bind ``kernel`` to a stable ``name`` and (optionally) a warmup
     shape template: ``bucket_shapes(bucket)`` returns the kernel's arg
     (shape, dtype) list for a padded batch bucket. Registered kernels
     are what ``warmup_plan`` pre-compiles; registration holds a strong
-    reference, so the name can never be re-assigned by id reuse."""
+    reference, so the name can never be re-assigned by id reuse.
+    ``reachable()`` says whether the routing in force can dispatch the
+    kernel (a wire format or hash placement nothing selects is not
+    worth a compile per bucket — ~45 s each on a v5e); omitted = yes.
+    ``forced`` marks the kernel the supervisor's canary and triage
+    dispatch BELOW the routing floor (force_device): its smallest bucket
+    is warmed first, ahead of the ladder."""
     inner = unwrap_kernel(kernel)
     with _name_mtx:
-        _registered[name] = _KernelReg(name, kernel, bucket_shapes, donate_from)
+        _registered[name] = _KernelReg(
+            name, kernel, bucket_shapes, donate_from, reachable, forced
+        )
         _name_by_id[id(inner)] = (name, None, inner)
 
 
@@ -157,9 +173,11 @@ def stable_kernel_name(kernel) -> str:
 
 
 def registered_kernels() -> List[_KernelReg]:
-    """Warmup-eligible registrations (those with a shape template)."""
+    """Warmup-eligible registrations: those with a shape template that
+    the routing in force can reach."""
     with _name_mtx:
-        return [r for r in _registered.values() if r.bucket_shapes]
+        regs = [r for r in _registered.values() if r.bucket_shapes]
+    return [r for r in regs if r.reachable is None or r.reachable()]
 
 
 # --------------------------------------------------------------------------
@@ -233,6 +251,17 @@ class Metrics:
             "Registry misses with no usable disk-persisted executable "
             "(absent, corrupt, or store disabled) — a fresh compile.",
         )
+        self.exec_store_save_failures = r.counter(
+            SUBSYSTEM, "exec_store_save_failures",
+            "Fresh compiles whose executable could not be serialized to "
+            "the disk store (the next boot recompiles them).",
+        )
+        self.exec_store_discards = r.counter(
+            SUBSYSTEM, "exec_store_discards",
+            "Disk-persisted executables thrown away on load: unreadable, "
+            "not deserializable, or loaded but failing their first run "
+            "(each one then costs a fresh compile).",
+        )
         self.compile_fallbacks = r.counter(
             SUBSYSTEM, "compile_fallbacks",
             "Compiles that failed once (corrupt/truncated persistent-"
@@ -283,19 +312,40 @@ class Metrics:
 # executable.
 
 
-class ExecutableStore:
-    """Disk persistence of serialized compiled executables."""
+# Bumped when what a stored blob means changes; older files are then
+# never looked up. 2: entries are loaded onto their own device assignment
+# and proven to run when loaded (ExecutableStore.load).
+_STORE_FORMAT = 2
 
-    def __init__(self, root: str):
+
+class ExecutableStore:
+    """Disk persistence of serialized compiled executables.
+    ``on_discard`` is called once per entry thrown away on load."""
+
+    def __init__(self, root: str,
+                 on_discard: Optional[Callable[[], None]] = None):
         self.root = root
+        self._on_discard = on_discard
 
     def _path(self, key: tuple) -> str:
-        digest = _hashlib.sha256(repr(key).encode()).hexdigest()
+        digest = _hashlib.sha256(
+            repr((_STORE_FORMAT, key)).encode()
+        ).hexdigest()
         return os.path.join(self.root, digest + ".aotexe")
 
-    def load(self, key: tuple):
-        """The deserialized executable for ``key``, or None (absent,
-        corrupt — with a warning —, or incompatible)."""
+    def load(self, key: tuple, devices: Sequence[Any], args: Sequence[Any]):
+        """The deserialized executable for ``key`` loaded onto
+        ``devices`` — the executable's own device assignment (one device
+        for a single-device program, the mesh's for a sharded one);
+        deserialize_and_load otherwise spreads it over every device of
+        the backend — and PROVEN: run once on zeros of ``args``' shapes.
+        On XLA:CPU (jax 0.9.0) an executable the persistent compilation
+        cache handed back serializes into a blob that loads and then
+        fails its first call ("Function ... not found"); trusted, it
+        would turn a warm boot into dispatch failures the supervisor
+        answers from the CPU. None when absent; an entry that is
+        unreadable, does not deserialize or does not run is discarded
+        with a warning and None is returned — the caller compiles."""
         path = self._path(key)
         try:
             with open(path, "rb") as fh:
@@ -303,33 +353,38 @@ class ExecutableStore:
         except FileNotFoundError:
             return None
         except Exception as exc:  # noqa: BLE001 - corrupt/truncated entry
-            warnings.warn(
-                f"aot executable store entry for {key[0]} is unreadable "
-                f"({exc!r}); recompiling fresh",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            self._discard(path)
-            return None
+            return self._discard(path, key, f"is unreadable ({exc!r})")
         try:
             from jax.experimental import serialize_executable as _se
 
-            return _se.deserialize_and_load(payload, in_tree, out_tree)
-        except Exception as exc:  # noqa: BLE001 - stale/incompatible blob
-            warnings.warn(
-                f"aot executable store entry for {key[0]} failed to "
-                f"deserialize ({exc!r}); recompiling fresh",
-                RuntimeWarning,
-                stacklevel=2,
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=list(devices),
             )
-            self._discard(path)
-            return None
+        except Exception as exc:  # noqa: BLE001 - stale/incompatible blob
+            return self._discard(
+                path, key, f"failed to deserialize ({exc!r})"
+            )
+        try:
+            import jax
+            import numpy as np
+
+            zeros = [
+                jax.device_put(np.zeros(a.shape, a.dtype), sh)
+                for a, sh in zip(args, loaded.input_shardings[0])
+            ]
+            jax.block_until_ready(loaded(*zeros))
+        except Exception as exc:  # noqa: BLE001 - loads, does not run
+            return self._discard(path, key, f"does not run ({exc!r})")
+        return loaded
 
     def save(self, key: tuple, compiled) -> bool:
         """Serialize ``compiled`` under ``key``, atomically (tmp +
-        rename — readers never see a torn entry). Best-effort: a full
-        disk or an unserializable executable only costs the NEXT boot
-        a compile."""
+        rename — readers never see a torn entry). → False when the
+        executable does not serialize or the disk refuses it: that only
+        costs the NEXT boot a compile, but the caller counts it
+        (``verify_aot_exec_store_save_failures``) so a backend whose
+        executables never persist is visible."""
         path = self._path(key)
         try:
             from jax.experimental import serialize_executable as _se
@@ -341,15 +396,28 @@ class ExecutableStore:
                 fh.write(blob)
             os.replace(tmp, path)
             return True
-        except Exception:  # noqa: BLE001
+        except Exception as exc:  # noqa: BLE001 - persistence is optional
+            warnings.warn(
+                f"aot executable for {key[0]} was not persisted ({exc!r})",
+                RuntimeWarning,
+                stacklevel=2,
+            )
             return False
 
-    @staticmethod
-    def _discard(path: str) -> None:
+    def _discard(self, path: str, key: tuple, why: str) -> None:
+        warnings.warn(
+            f"aot executable store entry for {key[0]} {why}; "
+            "recompiling fresh",
+            RuntimeWarning,
+            stacklevel=3,
+        )
         try:
             os.remove(path)
         except OSError:
             pass
+        if self._on_discard is not None:
+            self._on_discard()
+        return None
 
 
 _store_mtx = threading.Lock()
@@ -357,37 +425,90 @@ _configured_store_root: Optional[str] = None
 
 
 def configure_exec_store(root: Optional[str]) -> None:
-    """Pin the executable store location (tools, tests). None reverts
-    to the default resolution."""
+    """Pin the executable store location (tools, tests); "" disables
+    persistence. None reverts to the default resolution."""
     global _configured_store_root
     with _store_mtx:
         _configured_store_root = root
 
 
-def exec_store_root() -> Optional[str]:
-    """Where serialized executables live: the configured root, else an
-    ``aot_exec`` sibling inside the jax persistent compile cache
-    (jax config or JAX_COMPILATION_CACHE_DIR env), else None — no
-    persistence, the registry still works purely in-memory."""
+# the directory that holds the cometbft_tpu package: a fixed path for a
+# given installation, which is what a cache key needs (a node home is a
+# fresh temp directory in every test and smoke run, so a cache kept
+# there never hits)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))
+)))
+
+
+def compile_cache_dir() -> str:
+    """THE placement of the jax persistent compilation cache, and the
+    only code that touches it: with JAX_COMPILATION_CACHE_DIR set the
+    operator has placed it and jax reads the variable itself — nothing
+    is set here; otherwise ``<checkout>/.jax_cache``. Idempotent; every
+    process that compiles verify kernels (node, verifyd, tools, tests)
+    calls this before its first compile and sets no cache of its own."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def exec_store_root() -> str:
+    """Where serialized executables live: the configured root (tools,
+    tests), else an ``aot_exec`` sibling inside the compile cache."""
     with _store_mtx:
         if _configured_store_root is not None:
             return _configured_store_root
-    cache_dir = None
-    try:
-        import jax
-
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except Exception:  # noqa: BLE001 - jax not importable yet
-        pass
-    cache_dir = cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return None
-    return os.path.join(cache_dir, "aot_exec")
+    return os.path.join(compile_cache_dir(), "aot_exec")
 
 
-def _current_store() -> Optional[ExecutableStore]:
+def _current_store(on_discard=None) -> Optional[ExecutableStore]:
     root = exec_store_root()
-    return ExecutableStore(root) if root else None
+    return ExecutableStore(root, on_discard) if root else None
+
+
+# --------------------------------------------------------------------------
+# Build time is host time.
+#
+# A registry miss traces, lowers and compiles (45-85 s an executable on
+# a v5e) or loads a stored one, on the thread that dispatches. None of it
+# is the device's doing, so whoever times a dispatch — the supervisor's
+# watchdog, the wire ledger's compute phase — reads the dispatching
+# thread's clock here and leaves those seconds out.
+
+
+class BuildClock:
+    """Seconds one thread has spent inside registry misses (building,
+    loading, or waiting on a racing build), the one in progress
+    included. Written by its own thread, read by any."""
+
+    __slots__ = ("seconds", "since")
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+        self.since: Optional[float] = None  # monotonic start, while inside
+
+    def total(self) -> float:
+        since = self.since
+        running = time.monotonic() - since if since is not None else 0.0
+        return self.seconds + running
+
+
+_build_tls = threading.local()
+
+
+def build_clock() -> BuildClock:
+    """The calling thread's BuildClock."""
+    clock = getattr(_build_tls, "clock", None)
+    if clock is None:
+        clock = _build_tls.clock = BuildClock()
+    return clock
 
 
 # --------------------------------------------------------------------------
@@ -429,6 +550,9 @@ class ExecutableRegistry:
         # plain int alongside the labeled verify_aot_compiles series —
         # labeled children don't roll up into the parent counter
         self._compile_count = 0
+        # one record per executable this registry built or loaded —
+        # what a boot cost, bucket by bucket (stats()["builds"])
+        self._builds: List[dict] = []
 
     # -- introspection -------------------------------------------------------
 
@@ -443,6 +567,7 @@ class ExecutableRegistry:
             "hits": self.metrics.registry_hits.value(),
             "misses": self.metrics.registry_misses.value(),
             "compiles": self._compile_count,
+            "builds": list(self._builds),
             "invalidations": self.metrics.invalidations.value(),
             "evictions": self.metrics.evictions.value(),
         }
@@ -467,7 +592,8 @@ class ExecutableRegistry:
             (tuple(int(d) for d in a.shape), str(a.dtype)) for a in args
         )
 
-    def _key(self, kernel, shape_key, donate_from, sharded, mesh=None):
+    def _key(self, kernel, shape_key, donate_from, sharded, mesh=None,
+             device=None):
         bfp = backend_fingerprint()
         tfp = topology_fingerprint()
         self._note_fps(bfp, tfp)
@@ -484,6 +610,11 @@ class ExecutableRegistry:
                 int(getattr(d, "id", i))
                 for i, d in enumerate(mesh.devices.flat)
             )
+        elif device is not None:
+            # a single-device program PLACED on one chip of a mesh (a
+            # fault domain's own dispatch): compiled for that device's
+            # assignment, so it keys apart from the default placement
+            mkey = (int(device.id),)
         else:
             mkey = None
         return (
@@ -523,14 +654,18 @@ class ExecutableRegistry:
         sharded: bool = False,
         trigger: str = "dispatch",
         mesh=None,
+        device=None,
     ):
         """The compiled executable for ``args``' exact shapes, compiling
         on miss. ``args`` may be concrete arrays or ShapeDtypeStructs.
         ``mesh`` names the device mesh a sharded executable runs over
-        (default: the full batch_mesh) — part of the cache key."""
+        (default: the full batch_mesh); ``device`` the jax device a
+        single-device executable is placed on (default: jax's default
+        placement) — both part of the cache key."""
         shape_key = self._shape_key(args)
         key, bfp, tfp = self._key(
-            kernel, shape_key, donate_from, sharded, mesh=mesh
+            kernel, shape_key, donate_from, sharded, mesh=mesh,
+            device=device,
         )
         with self._mtx:
             ent = self._entries.get(key)
@@ -547,6 +682,21 @@ class ExecutableRegistry:
             self.metrics.registry_hits.add()
             return ent[0]
         self.metrics.registry_misses.add()
+        clock = build_clock()
+        clock.since = time.monotonic()
+        try:
+            return self._serve_miss(
+                kernel, key, bfp, tfp, fut, leader, args, donate_from,
+                sharded, trigger, mesh, device,
+            )
+        finally:
+            clock.seconds += time.monotonic() - clock.since
+            clock.since = None
+
+    def _serve_miss(self, kernel, key, bfp, tfp, fut, leader, args,
+                    donate_from, sharded, trigger, mesh, device):
+        """The slow half of lookup: wait on the racing build, or build
+        (load from the store, else compile) and publish the entry."""
         if not leader:
             fut.event.wait()
             if fut.error is not None:
@@ -557,7 +707,8 @@ class ExecutableRegistry:
             return fut.compiled
         try:
             compiled = self._load_or_compile(
-                kernel, key, args, donate_from, sharded, trigger, mesh=mesh
+                kernel, key, args, donate_from, sharded, trigger,
+                mesh=mesh, device=device,
             )
             fut.compiled = compiled
         except BaseException as exc:
@@ -585,13 +736,14 @@ class ExecutableRegistry:
         donate_from: int = 0,
         sharded: bool = False,
         mesh=None,
+        device=None,
     ):
         """Run ``kernel`` on ``args`` through the registry (the
         dispatch-layer entry — mesh.run_single / mesh.sharded_verify /
         mesh.dispatch_sharded)."""
         compiled = self.lookup(
             kernel, args, donate_from=donate_from, sharded=sharded,
-            mesh=mesh,
+            mesh=mesh, device=device,
         )
         return compiled(*args)
 
@@ -619,39 +771,42 @@ class ExecutableRegistry:
         return time.perf_counter() - t0
 
     def _load_or_compile(
-        self, kernel, key, args, donate_from, sharded, trigger, mesh=None
+        self, kernel, key, args, donate_from, sharded, trigger, mesh=None,
+        device=None,
     ):
         """Serve a registry miss: deserialize from the disk executable
         store when a fingerprint-matched entry exists (no trace, no
         compile), else compile fresh and persist for the next boot."""
-        store = _current_store()
+        store = _current_store(self.metrics.exec_store_discards.add)
         if store is not None:
             span = _trace.child_of_current(
                 "aot_load", kernel=key[0], bucket=_bucket_of(args),
                 sharded=sharded, topology=key[4], trigger=trigger,
             )
             t0 = time.perf_counter()
-            compiled = store.load(key)
+            compiled = store.load(
+                key, _execution_devices(sharded, mesh, device), args
+            )
             if compiled is not None:
-                span.end(
-                    cache_hit=True,
-                    seconds=round(time.perf_counter() - t0, 3),
-                )
+                secs = round(time.perf_counter() - t0, 3)
+                span.end(cache_hit=True, seconds=secs)
                 self.metrics.exec_store_hits.add()
+                self._note_build(key, args, sharded, "store", secs)
                 return compiled
             span.end(cache_hit=False)
             self.metrics.exec_store_misses.add()
         else:
             self.metrics.exec_store_misses.add()
         compiled = self._compile(
-            kernel, key, args, donate_from, sharded, trigger, mesh=mesh
+            kernel, key, args, donate_from, sharded, trigger, mesh=mesh,
+            device=device,
         )
-        if store is not None:
-            store.save(key, compiled)
+        if store is not None and not store.save(key, compiled):
+            self.metrics.exec_store_save_failures.add()
         return compiled
 
     def _compile(self, kernel, key, args, donate_from, sharded, trigger,
-                 mesh=None):
+                 mesh=None, device=None):
         """Explicit jit(...).lower(shapes).compile() with one fresh-
         compile retry: a corrupted or truncated persistent-cache entry
         (or a transient backend hiccup) must degrade to a fresh compile
@@ -666,7 +821,8 @@ class ExecutableRegistry:
         try:
             try:
                 compiled = self._build(
-                    kernel, args, donate_from, sharded, mesh=mesh
+                    kernel, args, donate_from, sharded, mesh=mesh,
+                    device=device,
                 )
             except Exception as exc:  # noqa: BLE001 - retry fresh once
                 warnings.warn(
@@ -681,7 +837,8 @@ class ExecutableRegistry:
                         kernel=name, bucket=bucket, err=str(exc),
                     )
                 compiled = self._build(
-                    kernel, args, donate_from, sharded, mesh=mesh
+                    kernel, args, donate_from, sharded, mesh=mesh,
+                    device=device,
                 )
                 self.metrics.compile_fallbacks.add()
         except Exception as exc:  # noqa: BLE001
@@ -693,9 +850,18 @@ class ExecutableRegistry:
             self._compile_count += 1
         self.metrics.compiles.with_labels(trigger=trigger).add()
         self.metrics.compile_seconds.add(secs)
+        self._note_build(key, args, sharded, trigger, round(secs, 3))
         return compiled
 
-    def _build(self, kernel, args, donate_from, sharded, mesh=None):
+    def _note_build(self, key, args, sharded, source, secs) -> None:
+        with self._mtx:
+            self._builds.append({
+                "kernel": key[0], "bucket": _bucket_of(args),
+                "sharded": bool(sharded), "source": source, "seconds": secs,
+            })
+
+    def _build(self, kernel, args, donate_from, sharded, mesh=None,
+               device=None):
         import jax
 
         inner = unwrap_kernel(kernel)
@@ -720,15 +886,42 @@ class ExecutableRegistry:
                 out_shardings=NamedSharding(m, PS("batch")),
                 donate_argnums=donate,
             )
+        elif device is not None:
+            from jax.sharding import SingleDeviceSharding
+
+            placed = SingleDeviceSharding(device)
+            jitted = jax.jit(
+                inner,
+                in_shardings=tuple(placed for _ in sds),
+                out_shardings=placed,
+                donate_argnums=donate,
+            )
         else:
             jitted = jax.jit(inner, donate_argnums=donate)
         return jitted.lower(*sds).compile()
 
 
+def _execution_devices(sharded: bool, mesh, device) -> List[Any]:
+    """The device assignment an executable under this key was compiled
+    for — what the disk store must load it back onto."""
+    if sharded:
+        if mesh is None:
+            from cometbft_tpu.crypto.tpu import mesh as mesh_mod
+
+            mesh = mesh_mod.batch_mesh()
+        return list(mesh.devices.flat)
+    if device is not None:
+        return [device]
+    import jax
+
+    return [jax.local_devices()[0]]
+
+
 def _bucket_of(args) -> int:
-    """The batch bucket of an arg list = the trailing axis of arg 0."""
+    """The batch bucket of an arg list = the trailing axis of its last
+    arg (arg 0 of the indexed kernel is the valset table)."""
     try:
-        return int(args[0].shape[-1])
+        return int(args[-1].shape[-1])
     except Exception:  # noqa: BLE001 - scalar/odd kernels
         return 0
 
@@ -775,13 +968,22 @@ def bucket_ladder(
     cap: Optional[int] = None,
     min_pad: int = _MIN_PAD,
 ) -> List[int]:
-    """The pow2 buckets the dispatch layer can pad to, in warm-boot
-    priority order: the commit-p50 bucket (the routing floor's bucket)
-    first, then the rest of the ladder up to the chunk cap — cheapest
-    measured compile first when the calibration table has per-bucket
-    compile seconds, ascending size otherwise — with megabatch (the
-    cap) last, then the sub-floor buckets (reachable only via coalesced
-    flushes, least urgent)."""
+    """The pow2 buckets a ROUTED flush can pad to, in warm-boot priority
+    order: from the bucket of the routing floor ([crypto] min_batch —
+    below it the router keeps a flush on the host) up to the chunk cap
+    ([crypto] max_chunk). The commit-p50 bucket (the floor's) first,
+    then the rest — cheapest measured compile first when the calibration
+    table has per-bucket compile seconds, ascending size otherwise —
+    with megabatch (the cap) last.
+
+    The two settings that bound routing bound the ladder. Buckets below
+    the floor are reached only by the canary and small triage passes
+    (warmup_plan adds that one bucket for the kernels they dispatch), by
+    the remainder chunk of a flush larger than the cap and by triage
+    over many lanes; the last two compile on first use — outside the
+    dispatch watchdog (BuildClock) — and persist in the executable
+    store. At the defaults that is 9 executables on one chip instead of
+    16, at ~50 s apiece cold on a v5e."""
     from cometbft_tpu.crypto.tpu import calibrate
 
     if cap is None:
@@ -795,19 +997,17 @@ def bucket_ladder(
         floor = cryptobatch.ed25519_routing_floor()
     p50 = min(_pow2_at_least(int(floor), min_pad), cap)
 
-    ladder, size = [], min_pad
+    above, size = [], p50 * 2
     while size <= cap:
-        ladder.append(size)
+        above.append(size)
         size *= 2
-    above = [b for b in ladder if b >= p50 and b != p50]
-    below = [b for b in ladder if b < p50]
     measured = calibrate.compile_seconds()
     if measured:
         # warm the cheap buckets first so more of the ladder is covered
         # early; the megabatch cap is the most expensive compile and
         # lands last either way
         above.sort(key=lambda b: (measured.get(b, float(b)), b))
-    return [p50] + above + list(reversed(below))
+    return [p50] + above
 
 
 class WarmTarget:
@@ -830,9 +1030,10 @@ def warmup_plan(
     sizes: Optional[Sequence[int]] = None,
     include_single: Optional[bool] = None,
 ) -> List[WarmTarget]:
-    """Every executable the current topology's dispatch path can need,
-    in priority order. For each ladder bucket and each registered
-    kernel with a shape template: the sharded variant when >1 device is
+    """Every executable the current topology's ROUTED dispatches need,
+    in priority order: the canary's bucket of the ``forced`` kernels,
+    then for each ladder bucket and each registered kernel with a shape
+    template: the sharded variant when >1 device is
     visible (what dispatch_batch actually runs there — warmed first),
     plus the single-device variant (``include_single``, default on so a
     mesh that degrades to one visible device still boots warm)."""
@@ -843,8 +1044,21 @@ def warmup_plan(
     ndev = mesh_mod.n_devices()
     if include_single is None:
         include_single = True
-    buckets = list(sizes) if sizes is not None else bucket_ladder(floor=floor)
     targets: List[WarmTarget] = []
+    if sizes is not None:
+        buckets = list(sizes)
+    else:
+        buckets = bucket_ladder(floor=floor)
+        if _MIN_PAD not in buckets:
+            # the canary gates HEALTHY and dispatches 8 lanes, single-
+            # device, whatever the floor: its bucket comes first
+            targets.extend(
+                WarmTarget(
+                    reg.name, reg.kernel, reg.bucket_shapes(_MIN_PAD),
+                    reg.donate_from, False, _MIN_PAD,
+                )
+                for reg in registered_kernels() if reg.forced
+            )
     seen_sharded = set()
     for bucket in buckets:
         for reg in registered_kernels():
@@ -958,8 +1172,7 @@ class WarmBoot:
     """Handle on one warm-boot run: the supervisor's warmup canary
     joins it before declaring HEALTHY; node stop() stops it with a
     bounded join. ``body(stop_event)`` does the work — the default is
-    ``run_warm_boot``; node.py wraps it with the device-plane probe and
-    the disk-cache-filling subprocess."""
+    ``run_warm_boot``; node.py follows it with the calibration sweep."""
 
     def __init__(
         self,

@@ -292,11 +292,13 @@ class MemoryPlane:
         if not self._stats_enabled:
             return None
         try:
-            import jax
+            from cometbft_tpu.crypto.tpu import mesh
 
-            devs = jax.devices()
-            if handle.index >= len(devs):
-                return None  # virtual domain beyond the physical plane
+            # only devices this process already holds: polling must
+            # never be what takes the accelerator (one process per chip)
+            devs = mesh.live_devices()
+            if devs is None or handle.index >= len(devs):
+                return None  # not dispatching yet / virtual domain
             stats = devs[handle.index].memory_stats()
         except Exception:  # noqa: BLE001 - no backend / no stats support
             self._stats_enabled = False

@@ -131,19 +131,7 @@ def _sha256_blocks_xla(blocks: jnp.ndarray) -> jnp.ndarray:
 
 def sha256_blocks(blocks: jnp.ndarray) -> jnp.ndarray:
     """blocks u32[B, n_blocks, 16] (BE words of pre-padded messages)
-    → digests u32[B, 8]. CBFT_TPU_SHA=pallas selects the hand-written
-    Pallas kernel (sha256_pallas.py); default is the fused XLA program."""
-    import os
-
-    impl = os.environ.get("CBFT_TPU_SHA", "xla")
-    if impl == "pallas":
-        from cometbft_tpu.crypto.tpu import sha256_pallas
-
-        return sha256_pallas.sha256_blocks(blocks)
-    if impl != "xla":
-        raise ValueError(
-            f"unknown CBFT_TPU_SHA={impl!r}; choose from ['pallas', 'xla']"
-        )
+    → digests u32[B, 8], as one fused XLA program."""
     return _sha256_blocks_xla(blocks)
 
 

@@ -252,6 +252,7 @@ class WireLedger:
         self.chunks = 0
         self.n_dispatches = 0
         self.demux_notes = 0
+        self._lanes: Dict[str, int] = {}
         self._link = dict(link) if link else None
 
     # --- cold-boot link seed -------------------------------------------------
@@ -299,6 +300,9 @@ class WireLedger:
             bw = wire_bytes / h2d_s / 1e6
         with self._lock:
             self.chunks += 1
+            self._lanes[route] = self._lanes.get(route, 0) + max(
+                0, int(lanes)
+            )
             key = (route, bucket, device)
             p = self._profiles.get(key)
             if p is None:
@@ -503,6 +507,13 @@ class WireLedger:
                 and (device is None or k[2] == device)
             )
 
+    def lanes_by_route(self) -> Dict[str, int]:
+        """Real signature lanes that reached the device, per wire route
+        — every chunk any dispatch loop attributed. The decision
+        ledger's per-route lanes reconcile against this."""
+        with self._lock:
+            return dict(self._lanes)
+
     def bytes_per_lane(self, route: str) -> Optional[float]:
         """Steady-state wire bytes per real signature lane for
         ``route`` — the EWMA over every attributed chunk, weighted
@@ -586,6 +597,7 @@ class WireLedger:
         return {
             "window": self.window,
             "chunks": counters[0],
+            "lanes": self.lanes_by_route(),
             "dispatches": counters[1],
             "demux_notes": counters[2],
             "link": link,

@@ -118,7 +118,7 @@ class TestClassification:
     def test_transient_markers(self):
         for msg in (
             "UNAVAILABLE: socket closed",
-            "DEADLINE_EXCEEDED waiting for tunnel",
+            "DEADLINE_EXCEEDED waiting for the runtime",
             "connection reset by peer",
             "temporarily unreachable, try again",
         ):
@@ -167,6 +167,13 @@ class TestLatencyModel:
         # 4096 bucket is cold: the 1024 one answers for it
         assert lm.predict_p99(4096) == pytest.approx(
             lm.predict_p99(1024))
+        # but not for a flush three orders of magnitude away: what the
+        # canary's 8 lanes cost says nothing about 10,000
+        for _ in range(4):
+            lm.observe(8, 0.006)
+        assert lm.predict_p99(13) == pytest.approx(lm.predict_p99(8))
+        assert lm.predict_p99(1_000_000) is None
+        assert LatencyModel.NEIGHBORS == 2
 
     def test_below_min_samples_stays_cold(self):
         lm = LatencyModel()

@@ -228,6 +228,47 @@ class TestWatchdog:
         assert not zombies, "abandoned dispatch thread still alive"
         sup.stop()
 
+    def test_building_an_executable_does_not_run_the_watchdog(self):
+        """A dispatch that first has to build its executable (a cold
+        bucket compiles for 45-85 s on a v5e) is not a hung device: the
+        seconds its worker spends inside a registry miss are left out of
+        dispatch_timeout_ms; the same seconds spent any other way are a
+        watchdog kill."""
+        from cometbft_tpu.crypto.tpu import aot
+
+        class _Slow(CPUBatchVerifier):
+            building = True
+
+            def verify(self):
+                clock = aot.build_clock()
+                if self.building:
+                    clock.since = time.monotonic()
+                time.sleep(0.6)
+                if self.building:
+                    clock.seconds += time.monotonic() - clock.since
+                    clock.since = None
+                return super().verify()
+
+        cryptobatch.register_backend("test-slow-build", _Slow)
+        sup = BackendSupervisor(
+            spec=cryptobatch.BackendSpec("test-slow-build"),
+            dispatch_timeout_ms=200, audit_pct=0, hedge_pct=0,
+        )
+        items = _make_items(4, poison_at=1)
+        try:
+            assert sup.verify_items(items) == _cpu_mask(items)
+            assert sup.metrics.watchdog_kills.value() == 0
+            assert sup.state() == HEALTHY
+            # what the latency model learned is the dispatch, not the build
+            seen = sup._domains[0].latency_model._buckets
+            assert all(ewma_s < 0.3 for _, ewma_s, _ in seen.values()), seen
+            assert sup.metrics.failures.value() == 0
+            _Slow.building = False
+            assert sup.verify_items(items) == _cpu_mask(items)
+            assert sup.metrics.watchdog_kills.value() == 1
+        finally:
+            sup.stop()
+
     def test_watchdog_timeout_type(self):
         plan, sup = _faulty(dispatch_timeout_ms=100)
         plan.hang_rate = 1.0

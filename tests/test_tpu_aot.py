@@ -238,6 +238,29 @@ class TestExecutableStore:
         with open(path2, "rb") as fh:
             assert fh.read(20) != b"\x00garbage not a pick"
 
+    def test_entry_that_loads_but_does_not_run_is_discarded(self, tmp_store):
+        """The store proves an entry when it loads it (one run on zeros):
+        one that deserializes and then fails its first call is thrown
+        away, counted, and compiled fresh — never handed to dispatch."""
+        import shutil
+
+        import jax
+
+        k = _toy_kernel()
+        reg1 = aot.ExecutableRegistry()
+        reg1.warm(k, [((3, 64), np.int32)])
+        (path,) = glob.glob(os.path.join(tmp_store, "*.aotexe"))
+        # the 64-lane executable filed under the 128-lane key
+        sds = [jax.ShapeDtypeStruct((3, 128), np.int32)]
+        key, _, _ = reg1._key(k, reg1._shape_key(sds), 0, False)
+        shutil.copy(path, aot.ExecutableStore(tmp_store)._path(key))
+        reg = aot.ExecutableRegistry()
+        with pytest.warns(RuntimeWarning, match="does not run"):
+            assert reg.warm(k, [((3, 128), np.int32)]) > 0.0
+        assert reg.compile_count == 1
+        assert reg.metrics.exec_store_discards.value() == 1
+        assert reg.metrics.exec_store_hits.value() == 0
+
     def test_truncated_entry_warns_and_recompiles(self, tmp_store):
         k = _toy_kernel()
         aot.ExecutableRegistry().warm(k, [((3, 64), np.int32)])
@@ -264,11 +287,31 @@ class TestExecutableStore:
 
 
 class TestBucketLadder:
-    def test_p50_first_cap_last_subfloor_reversed(self, monkeypatch):
+    def test_p50_first_cap_last_nothing_below_the_floor(self, monkeypatch):
+        """The ladder is what routing can pad a flush to: min_batch's
+        bucket up to max_chunk."""
         monkeypatch.setattr(calibrate, "compile_seconds", lambda *a: {})
         assert aot.bucket_ladder(floor=1024, cap=8192) == [
-            1024, 2048, 4096, 8192, 512, 256, 128, 64,
+            1024, 2048, 4096, 8192,
         ]
+        assert aot.bucket_ladder(floor=1, cap=256) == [64, 128, 256]
+
+    def test_plan_warms_the_canary_bucket_first(self, monkeypatch):
+        """Below the floor only the canary's bucket is warmed, first, for
+        the kernel the canary dispatches — and not for the others."""
+        monkeypatch.setattr(calibrate, "compile_seconds", lambda *a: {})
+        plan = [
+            (t.name, t.bucket) for t in
+            aot.warmup_plan(floor=1024, include_single=True)
+            if not t.sharded
+        ]
+        assert plan[0] == ("ed25519.verify_compact", 64)
+        assert [b for _, b in plan].count(64) == 1
+        assert {b for _, b in plan[1:]} == {1024, 2048, 4096, 8192}
+        assert ("ed25519.verify_resident", 8192) in plan
+        # an explicit size list is taken as given
+        sized = aot.warmup_plan(sizes=[2048])
+        assert {t.bucket for t in sized if not t.sharded} == {2048}
 
     def test_measured_compile_cost_reorders_above_floor(self, monkeypatch):
         monkeypatch.setattr(
@@ -279,14 +322,12 @@ class TestBucketLadder:
         # cheap measured buckets warm first; unmeasured 8192 keys by
         # size and stays last
         assert aot.bucket_ladder(floor=1024, cap=8192) == [
-            1024, 4096, 2048, 8192, 512, 256, 128, 64,
+            1024, 4096, 2048, 8192,
         ]
 
     def test_floor_above_cap_clamps(self, monkeypatch):
         monkeypatch.setattr(calibrate, "compile_seconds", lambda *a: {})
-        ladder = aot.bucket_ladder(floor=100_000, cap=256)
-        assert ladder[0] == 256
-        assert sorted(ladder) == [64, 128, 256]
+        assert aot.bucket_ladder(floor=100_000, cap=256) == [256]
 
 
 class TestWarmBootLifecycle:
@@ -511,8 +552,8 @@ class TestZeroCompileDispatch:
         compiles = reg.compile_count
         misses = reg.metrics.registry_misses.value()
         reg.lookup(
-            ed25519_batch.verify_kernel,
-            [jax.ShapeDtypeStruct((32, 64), np.uint32)],
+            ed25519_batch.verify_kernel_compact,
+            [jax.ShapeDtypeStruct((128, 64), np.uint8)],
             sharded=False,
         )
         assert reg.compile_count == compiles

@@ -69,51 +69,3 @@ class TestMerkleParity:
         finally:
             cpu_merkle.enable_parallel(False)
         assert got == want
-
-
-class TestPallasSha256:
-    """The Pallas kernel (CBFT_TPU_SHA=pallas) must match hashlib and the
-    XLA path bit for bit. Interpret mode runs the kernel eagerly (a few
-    seconds per distinct shape), so the parity matrix below — single
-    block, the 65-byte merkle inner-node shape, a multi-tile batch, and a
-    multi-block message — is marked slow; real-hardware runs go through
-    CBFT_TPU_SHA=pallas against the merkle suite."""
-
-    @pytest.mark.slow
-    def test_interpret_mode_parity(self):
-        import hashlib
-
-        import numpy as np
-
-        from cometbft_tpu.crypto.tpu import sha256 as tpu_sha
-        from cometbft_tpu.crypto.tpu import sha256_pallas
-
-        rng = np.random.default_rng(11)
-        # one block, the merkle inner-node shape (2 blocks), a multi-tile
-        # batch (grid > 1), and a longer multi-block message
-        for n, msg_len in ((3, 55), (5, 65), (130, 65), (4, 200)):
-            msgs = rng.integers(0, 256, size=(n, msg_len), dtype=np.uint8)
-            blocks = tpu_sha.pad_messages_np(msgs, msg_len)
-            got = np.asarray(
-                sha256_pallas.sha256_blocks(blocks, interpret=True)
-            )
-            got_bytes = tpu_sha.digests_to_bytes_np(got)
-            for i in range(n):
-                want = hashlib.sha256(msgs[i].tobytes()).digest()
-                assert got_bytes[i].tobytes() == want, f"n={n} i={i}"
-
-    def test_env_dispatch(self, monkeypatch):
-        import numpy as np
-
-        from cometbft_tpu.crypto.tpu import sha256 as tpu_sha
-
-        msgs = np.zeros((4, 65), np.uint8)
-        blocks = tpu_sha.pad_messages_np(msgs, 65)
-        want = np.asarray(tpu_sha.sha256_blocks(blocks))
-        monkeypatch.setenv("CBFT_TPU_SHA", "nonsense")
-        import pytest as _pytest
-
-        with _pytest.raises(ValueError):
-            tpu_sha.sha256_blocks(blocks)
-        monkeypatch.delenv("CBFT_TPU_SHA")
-        assert (np.asarray(tpu_sha.sha256_blocks(blocks)) == want).all()

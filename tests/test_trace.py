@@ -780,12 +780,12 @@ class TestMinBatchThreading:
         assert cryptobatch.resident_commit_eligible(10, lo) is True
         assert cryptobatch.resident_commit_eligible(10, hi) is False
         # the add()/verify() path sees the identical floor
-        assert cryptobatch.new_batch_verifier(lo)._min_batch == 5
-        assert cryptobatch.new_batch_verifier(hi)._min_batch == 50
+        assert cryptobatch.new_batch_verifier(lo)._floors["ed25519"] == 5
+        assert cryptobatch.new_batch_verifier(hi)._floors["ed25519"] == 50
         # env still wins for operator A/B overrides, on BOTH paths
         monkeypatch.setenv("CBFT_TPU_MIN_BATCH", "7")
         assert cryptobatch.resident_commit_eligible(10, hi) is True
-        assert cryptobatch.new_batch_verifier(hi)._min_batch == 7
+        assert cryptobatch.new_batch_verifier(hi)._floors["ed25519"] == 7
 
     def test_node_does_not_mutate_min_batch_env(self, monkeypatch):
         """Two in-process nodes with different [crypto] min_batch must

@@ -72,7 +72,6 @@ def main() -> int:
     args = ap.parse_args()
 
     # self-contained: no device plane required
-    os.environ.setdefault("CBFT_TPU_PROBE", "0")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from cometbft_tpu.crypto.adversary import (

@@ -242,7 +242,6 @@ def main() -> int:
 
     if args.inner == "cpu":
         # self-contained: no device plane required
-        os.environ.setdefault("CBFT_TPU_PROBE", "0")
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # env-driven fault knobs: picked up by any FaultPlan.from_env() in
     # this process (e.g. a faulty node backend installed elsewhere)

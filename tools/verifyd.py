@@ -75,6 +75,13 @@ class Daemon:
         from cometbft_tpu.libs.metrics import MetricsServer, Registry
 
         self.logger = logger if logger is not None else new_tm_logger()
+        if (backend or os.environ.get("CMT_CRYPTO_BACKEND")) == "tpu":
+            # the daemon is the process that will dispatch: refuse to
+            # serve behind a device name when jax found no TPU (unless
+            # JAX_PLATFORMS asks for the CPU platform on purpose)
+            from cometbft_tpu.crypto.tpu import mesh as tpu_mesh
+
+            tpu_mesh.require_accelerator("verifyd --backend tpu")
         self.registry = Registry(namespace="cometbft")
         self.tracer = tracelib.Tracer(sample=trace_sample, dump_dir=dump_dir)
         tracelib.attach_stage_metrics(self.tracer, self.registry)
@@ -189,6 +196,7 @@ class Daemon:
 
 def main(argv: Optional[List[str]] = None) -> int:
     from cometbft_tpu.crypto import service as servicelib
+    from cometbft_tpu.crypto.batch import resolved_device_plane
 
     ap = argparse.ArgumentParser(
         description="Shared verify-as-a-service daemon (one device pool, "
@@ -278,20 +286,24 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"error: cannot load --auth-key: {exc}", file=sys.stderr)
             return 2
 
-    daemon = Daemon(
-        args.address,
-        backend=args.backend,
-        flush_us=args.flush_us,
-        max_chunk=args.max_chunk,
-        qos=args.qos,
-        tenant_rate=args.tenant_rate,
-        coalesce=not args.no_coalesce,
-        auth_key=auth_key,
-        drain_timeout_ms=args.drain_timeout_ms,
-        metrics_addr=args.metrics_addr,
-        trace_sample=args.trace_sample,
-        dump_dir=args.dump_dir,
-    )
+    try:
+        daemon = Daemon(
+            args.address,
+            backend=args.backend,
+            flush_us=args.flush_us,
+            max_chunk=args.max_chunk,
+            qos=args.qos,
+            tenant_rate=args.tenant_rate,
+            coalesce=not args.no_coalesce,
+            auth_key=auth_key,
+            drain_timeout_ms=args.drain_timeout_ms,
+            metrics_addr=args.metrics_addr,
+            trace_sample=args.trace_sample,
+            dump_dir=args.dump_dir,
+        )
+    except RuntimeError as exc:  # --backend tpu and jax found no TPU
+        print(f"error: {exc}", file=sys.stderr)
+        return 1
     try:
         daemon.start()
     except Exception as exc:  # noqa: BLE001 - CLI surface
@@ -302,6 +314,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     line = (
         f"verifyd listening on {daemon.service.address()}  "
         f"backend={daemon.scheduler.spec.name}  "
+        f"platform={(resolved_device_plane() or {}).get('platform', 'host')}  "
         f"coalesce={'on' if not args.no_coalesce else 'OFF'}  "
         f"qos={args.qos}  "
         f"auth={'on' if auth_key else 'off'}"

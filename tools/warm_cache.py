@@ -10,7 +10,7 @@ warmed by RUNNING batches (paying dispatch) instead of compiling
 explicitly.
 
 Usage:
-  python tools/warm_cache.py                        # full ladder, repo cache
+  python tools/warm_cache.py                        # full ladder, default cache
   python tools/warm_cache.py --buckets 64,128       # specific buckets
   python tools/warm_cache.py --platform cpu --devices 8
   python tools/warm_cache.py --cache ~/.cbft/jax_cache \
@@ -23,10 +23,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-REPO_CACHE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-)
 
 
 def main() -> int:
@@ -41,8 +37,11 @@ def main() -> int:
              "(XLA_FLAGS --xla_force_host_platform_device_count)",
     )
     ap.add_argument(
-        "--cache", default=REPO_CACHE,
-        help=f"persistent cache directory (default {REPO_CACHE})",
+        "--cache", default=None,
+        help="persistent cache directory: exported as "
+             "JAX_COMPILATION_CACHE_DIR for this run (default: that "
+             "variable if set, else <checkout>/.jax_cache — "
+             "aot.compile_cache_dir)",
     )
     ap.add_argument(
         "--buckets", default=None,
@@ -73,14 +72,16 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={args.devices}"
         ).strip()
-    os.environ.setdefault("CBFT_TPU_PROBE", "0")
+    if args.cache:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.expanduser(
+            args.cache
+        )
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", args.cache)
-
     from cometbft_tpu.crypto.tpu import aot, calibrate
 
+    cache = aot.compile_cache_dir()
     if args.calibration:
         calibrate.set_table_path(args.calibration)
     jax.config.update(
@@ -94,7 +95,7 @@ def main() -> int:
     print(
         f"warming {jax.devices()[0].platform} x{len(jax.devices())} "
         f"(topology {aot.topology_fingerprint()}, backend "
-        f"{aot.backend_fingerprint()}) -> {args.cache}",
+        f"{aot.backend_fingerprint()}) -> {cache}",
         flush=True,
     )
     obs = aot.run_warm_boot(
