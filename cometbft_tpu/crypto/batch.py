@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional, Tuple, Union
 
 from cometbft_tpu.crypto import PubKey
 from cometbft_tpu.crypto import ed25519 as ed
+from cometbft_tpu.libs import trace as tracelib
 
 
 @dataclass(frozen=True)
@@ -337,16 +338,17 @@ def verify_commit_valset(
     False and skipped by the caller."""
     if backend_name(backend) != "tpu":
         return None
-    present = sum(1 for m in msgs if m is not None)
-    spec = unwrap_backend(backend)
-    spec_floor = spec.min_batch if isinstance(spec, BackendSpec) else None
-    if present < ed25519_routing_floor(spec_floor):
-        return None
     import hashlib
 
+    with tracelib.stage("commit.valset_id"):
+        present = sum(1 for m in msgs if m is not None)
+        spec = unwrap_backend(backend)
+        spec_floor = spec.min_batch if isinstance(spec, BackendSpec) else None
+        if present < ed25519_routing_floor(spec_floor):
+            return None
+        valset_id = hashlib.sha256(b"".join(pub_keys)).digest()
     from cometbft_tpu.crypto.tpu import ed25519_batch
 
-    valset_id = hashlib.sha256(b"".join(pub_keys)).digest()
     return ed25519_batch.verify_valset_resident(valset_id, pub_keys, msgs, sigs)
 
 

@@ -812,24 +812,27 @@ class VerifyScheduler(BaseService):
         default) and, with ``height``, tags the request's trace span and
         lets supervisor triage attribute offending signatures back to
         the submitting subsystem/block in metrics and logs."""
-        triples = [(pk, bytes(m), bytes(s)) for pk, m, s in items]
-        qclass = qoslib.resolve_class(subsystem, self._class_names)
-        span = self._tracer.start_span("request", n_sigs=len(triples))
-        if not span.noop:
-            if subsystem:
-                span.set_tag("subsystem", subsystem)
-            if height is not None:
-                span.set_tag("height", int(height))
-            if self._qos_enabled:
-                span.set_tag("qos_class", qclass)
-        req = _Request(triples, span, subsystem, height, qclass)
-        self.metrics.requests.add()
-        self.metrics.signatures.add(len(req.items))
-        if not req.items:
-            req.future._set((True, []))
-            span.end(outcome="empty")
-            return req.future
-        return self._submit_req(req, subsystem or qoslib.TENANT_UNTAGGED)
+        with tracelib.stage("sched.submit"):
+            triples = [(pk, bytes(m), bytes(s)) for pk, m, s in items]
+            qclass = qoslib.resolve_class(subsystem, self._class_names)
+            span = self._tracer.start_span("request", n_sigs=len(triples))
+            if not span.noop:
+                if subsystem:
+                    span.set_tag("subsystem", subsystem)
+                if height is not None:
+                    span.set_tag("height", int(height))
+                if self._qos_enabled:
+                    span.set_tag("qos_class", qclass)
+            req = _Request(triples, span, subsystem, height, qclass)
+            self.metrics.requests.add()
+            self.metrics.signatures.add(len(req.items))
+            if not req.items:
+                req.future._set((True, []))
+                span.end(outcome="empty")
+                return req.future
+            return self._submit_req(
+                req, subsystem or qoslib.TENANT_UNTAGGED
+            )
 
     def submit_rows(
         self,
@@ -1230,95 +1233,97 @@ class VerifyScheduler(BaseService):
     def _dispatch(self, batch: List[_Request], reason: str) -> None:
         """ONE backend verify over the coalesced items, demultiplexed back
         into per-request verdict slices."""
-        t0 = time.monotonic()
-        # memory-plane freshness ride-along: the flush threads are the
-        # natural pollers — no background thread needed. The sys.modules
-        # guard keeps CPU-only schedulers from ever importing the TPU
-        # package; with a plane installed the off-edge cost is one clock
-        # compare (bench_micro's memory section bounds it under 1%).
-        memlib = sys.modules.get("cometbft_tpu.crypto.tpu.memory")
-        if memlib is not None:
-            plane = memlib.default_plane()
-            if plane is not None:
-                try:
-                    plane.poll()
-                except Exception:  # noqa: BLE001 - never gates a verify
-                    pass
-        items: List[Item] = []
-        parent = None
-        waits: List[float] = []
-        by_class: Dict[str, List[int]] = {}
-        n_total = 0
-        has_rows = False
-        for req in batch:
-            wait_s = t0 - req.t_submit
-            waits.append(wait_s)
-            self.metrics.request_wait_seconds.observe(wait_s)
-            items.extend(req.items)
-            n_total += req.n_lanes
-            if req.rows is not None:
-                has_rows = True
-            counts = by_class.setdefault(req.qclass, [0, 0])
-            counts[0] += 1
-            counts[1] += req.n_lanes
-            if not req.span.noop:
-                req.span.set_tag("wait_us", int(wait_s * 1e6))
-                if parent is None:
-                    # the OLDEST sampled request hosts the dispatch span
-                    # (spans form a tree; coalesced siblings link by tag)
-                    parent = req.span
-        self.n_dispatches += 1
-        self.metrics.flushes.with_labels(reason=reason).add()
-        with self._cond:
-            self._flush_reasons[reason] = (
-                self._flush_reasons.get(reason, 0) + 1
-            )
-        lane_fill = min(1.0, n_total / self._lane_budget)
-        self.metrics.lane_fill_ratio.observe(lane_fill)
-        dspan = self._tracer.start_span(
-            "dispatch",
-            parent=parent,
-            reason=reason,
-            n_requests=len(batch),
-            n_sigs=n_total,
-            lane_fill=round(lane_fill, 4),
-        )
-        if not dspan.noop:
-            did = format(dspan.span_id, "x")
+        with tracelib.stage("sched.assemble"):
+            t0 = time.monotonic()
+            # memory-plane freshness ride-along: the flush threads are the
+            # natural pollers — no background thread needed. The sys.modules
+            # guard keeps CPU-only schedulers from ever importing the TPU
+            # package; with a plane installed the off-edge cost is one clock
+            # compare (bench_micro's memory section bounds it under 1%).
+            memlib = sys.modules.get("cometbft_tpu.crypto.tpu.memory")
+            if memlib is not None:
+                plane = memlib.default_plane()
+                if plane is not None:
+                    try:
+                        plane.poll()
+                    except Exception:  # noqa: BLE001 - never gates a verify
+                        pass
+            items: List[Item] = []
+            parent = None
+            waits: List[float] = []
+            by_class: Dict[str, List[int]] = {}
+            n_total = 0
+            has_rows = False
             for req in batch:
-                if req.span is not parent and not req.span.noop:
-                    req.span.set_tag("dispatch_span", did)
-            if self._qos_enabled:
-                # per-class composition of this flush, e.g.
-                # "consensus=3r/48s,mempool=1r/16s"
-                dspan.set_tag("qos_classes", ",".join(
-                    f"{name}={c[0]}r/{c[1]}s"
-                    for name, c in by_class.items()
-                ))
-        # demux shape for supervisor triage attribution: one
-        # (n_items, subsystem, height) per coalesced request, item order
-        origins = [
-            (req.n_lanes, req.subsystem, req.height) for req in batch
-        ]
-        # decision plane ride-along: one RouteDecision per flush, input
-        # gathering gated on an installed ledger so the off-edge is a
-        # single attribute read (bench_micro's decisions section bounds
-        # the on-edge under 1%). Row flushes skip it: their rows are
-        # already committed to the compact wire, so there is no route
-        # choice to price.
-        declgr = declib.default_ledger()
-        dec = None
-        if declgr is not None and not has_rows:
-            breakers = self._decision_breakers()
-            dec = declgr.open(
-                n=len(items),
+                wait_s = t0 - req.t_submit
+                waits.append(wait_s)
+                self.metrics.request_wait_seconds.observe(wait_s)
+                items.extend(req.items)
+                n_total += req.n_lanes
+                if req.rows is not None:
+                    has_rows = True
+                counts = by_class.setdefault(req.qclass, [0, 0])
+                counts[0] += 1
+                counts[1] += req.n_lanes
+                if not req.span.noop:
+                    req.span.set_tag("wait_us", int(wait_s * 1e6))
+                    if parent is None:
+                        # the OLDEST sampled request hosts the dispatch span
+                        # (spans form a tree; coalesced siblings link by tag)
+                        parent = req.span
+            self.n_dispatches += 1
+            self.metrics.flushes.with_labels(reason=reason).add()
+            with self._cond:
+                self._flush_reasons[reason] = (
+                    self._flush_reasons.get(reason, 0) + 1
+                )
+            lane_fill = min(1.0, n_total / self._lane_budget)
+            self.metrics.lane_fill_ratio.observe(lane_fill)
+            dspan = self._tracer.start_span(
+                "dispatch",
+                parent=parent,
                 reason=reason,
-                capacity=self._decision_capacity(),
-                breakers=breakers,
-                keystore=self._decision_keystore(),
-                qos={name: c[1] for name, c in by_class.items()} or None,
-                feasible=self._decision_feasible(items, breakers),
+                n_requests=len(batch),
+                n_sigs=n_total,
+                lane_fill=round(lane_fill, 4),
             )
+            if not dspan.noop:
+                did = format(dspan.span_id, "x")
+                for req in batch:
+                    if req.span is not parent and not req.span.noop:
+                        req.span.set_tag("dispatch_span", did)
+                if self._qos_enabled:
+                    # per-class composition of this flush, e.g.
+                    # "consensus=3r/48s,mempool=1r/16s"
+                    dspan.set_tag("qos_classes", ",".join(
+                        f"{name}={c[0]}r/{c[1]}s"
+                        for name, c in by_class.items()
+                    ))
+            # demux shape for supervisor triage attribution: one
+            # (n_items, subsystem, height) per coalesced request, item order
+            origins = [
+                (req.n_lanes, req.subsystem, req.height) for req in batch
+            ]
+        with tracelib.stage("sched.route"):
+            # decision plane ride-along: one RouteDecision per flush, input
+            # gathering gated on an installed ledger so the off-edge is a
+            # single attribute read (bench_micro's decisions section bounds
+            # the on-edge under 1%). Row flushes skip it: their rows are
+            # already committed to the compact wire, so there is no route
+            # choice to price.
+            declgr = declib.default_ledger()
+            dec = None
+            if declgr is not None and not has_rows:
+                breakers = self._decision_breakers()
+                dec = declgr.open(
+                    n=len(items),
+                    reason=reason,
+                    capacity=self._decision_capacity(),
+                    breakers=breakers,
+                    keystore=self._decision_keystore(),
+                    qos={name: c[1] for name, c in by_class.items()} or None,
+                    feasible=self._decision_feasible(items, breakers),
+                )
         t_verify = time.perf_counter()
         built = _built_s()
         try:
@@ -1345,30 +1350,31 @@ class VerifyScheduler(BaseService):
         # the ledger's fifth phase (host-side fan-out back to futures)
         dspan.end(route=wire_route)
         service_s = time.monotonic() - t0
-        t_demux = time.perf_counter()
-        pos = 0
-        for i, req in enumerate(batch):
-            sub = mask[pos : pos + req.n_lanes]
-            pos += req.n_lanes
-            ok = all(sub)
-            req.future._set((ok, sub))
-            req.span.end(ok=ok)
-            if self._telemetry is not None:
-                # the coalesced dispatch's service time is every rider's
-                # service time — they all waited on the same flush
-                self._telemetry.note_request(
-                    n_sigs=req.n_lanes,
-                    wait_s=waits[i],
-                    service_s=service_s,
-                    ok=ok,
-                    subsystem=req.subsystem,
-                    height=req.height,
+        with tracelib.stage("sched.demux"):
+            t_demux = time.perf_counter()
+            pos = 0
+            for i, req in enumerate(batch):
+                sub = mask[pos : pos + req.n_lanes]
+                pos += req.n_lanes
+                ok = all(sub)
+                req.future._set((ok, sub))
+                req.span.end(ok=ok)
+                if self._telemetry is not None:
+                    # the coalesced dispatch's service time is every rider's
+                    # service time — they all waited on the same flush
+                    self._telemetry.note_request(
+                        n_sigs=req.n_lanes,
+                        wait_s=waits[i],
+                        service_s=service_s,
+                        ok=ok,
+                        subsystem=req.subsystem,
+                        height=req.height,
+                    )
+            ledger = wirelib.default_ledger()
+            if ledger is not None:
+                ledger.note_demux(
+                    wire_route, n_total, time.perf_counter() - t_demux
                 )
-        ledger = wirelib.default_ledger()
-        if ledger is not None:
-            ledger.note_demux(
-                wire_route, n_total, time.perf_counter() - t_demux
-            )
 
     def _verify_rows(self, batch: List[_Request]) -> List[bool]:
         """Verify a coalesced flush carrying row payloads: the requests'
@@ -1679,13 +1685,14 @@ class VerifyScheduler(BaseService):
         """Returns (verdict mask, wire-route label). The label is the
         ledger key for demux attribution: "cpu" for host dispatches,
         "sharded"/"indexed"/"single" mirroring _note_route's ladder."""
-        label, route, router = self._route(len(items), items)
-        self._note_route(label)
-        declib.note_router(router)
-        self._router_last = router
-        wire_route = (
-            label if label in ("cpu", "sharded", "indexed") else "single"
-        )
+        with tracelib.stage("sched.route"):
+            label, route, router = self._route(len(items), items)
+            self._note_route(label)
+            declib.note_router(router)
+            self._router_last = router
+            wire_route = (
+                label if label in ("cpu", "sharded", "indexed") else "single"
+            )
         if label == "cpu" and self.spec.name != "cpu":
             # the floor or the priced argmin chose the host rung for a
             # device spec (small flush under the transfer floor):
@@ -1733,7 +1740,7 @@ class VerifyScheduler(BaseService):
 
     @staticmethod
     def _cpu_ground_truth(items: Sequence[Item]) -> List[bool]:
-        with tracelib.child_of_current("cpu", n_sigs=len(items)):
+        with tracelib.stage("host.verify", n_sigs=len(items)):
             bv = CPUBatchVerifier()
             for pk, m, s in items:
                 bv.add(pk, m, s)

@@ -849,7 +849,7 @@ class BackendSupervisor:
             "supervise", state=state, n_sigs=len(items), reason=reason,
             route=route or "auto",
         )
-        with tracelib.use(span):
+        with tracelib.stage("sup.supervise", span=span):
             if route == "sharded":
                 out = self._verify_mesh(items, reason, origins)
                 if out is not None:
@@ -1408,7 +1408,8 @@ class BackendSupervisor:
         items = self._canary_items()
         err = None
         try:
-            mask = self._device_verify(dom, items)
+            with tracelib.background():
+                mask = self._device_verify(dom, items)
             ok = len(mask) == len(items) and all(mask)
         except WatchdogTimeout as exc:
             self.metrics.watchdog_kills.add()
@@ -1569,10 +1570,16 @@ class BackendSupervisor:
             device=dom.handle.label, route=route or "auto",
         )
 
+        # a probe's or the canary's dispatch stays background on the worker
+        quiet = tracelib.in_background()
+
         def run():
             h.build = aot.build_clock()
             try:
-                with tracelib.use(h.span), mesh.cancel_scope(h.cancel), \
+                # annotation only: h.span is ended on the calling thread
+                with tracelib.background(quiet), tracelib.use(h.span), \
+                        tracelib.stage("sup.device", tracelib.NOOP_SPAN), \
+                        mesh.cancel_scope(h.cancel), \
                         topology.device_scope(dom.handle), \
                         mesh.route_scope(route):
                     bv = new_batch_verifier(
@@ -1821,7 +1828,7 @@ class BackendSupervisor:
             pass  # malformed CBFT_TPU_MAX_CHUNK surfaces at dispatch
 
     def _cpu_verify(self, items: List[Item]) -> List[bool]:
-        with tracelib.child_of_current("cpu", n_sigs=len(items)):
+        with tracelib.stage("host.verify", n_sigs=len(items)):
             t0 = time.monotonic()
             bv: BatchVerifier = CPUBatchVerifier()
             for pk, m, s in items:
@@ -2062,7 +2069,7 @@ class BackendSupervisor:
             "audit", sync=False, n_sigs=len(items)
         )
         try:
-            with tracelib.use(span):
+            with tracelib.background(), tracelib.use(span):
                 cpu_mask = self._cpu_verify(items)
         except Exception as exc:  # noqa: BLE001 - audit must not die
             span.end(error=repr(exc))

@@ -10,9 +10,10 @@ that layer, one ``TelemetryHub`` threaded through the existing pipeline:
 * **per-device utilization** — the supervisor reports every completed
   device call as a busy interval (``note_device_busy``); the hub keeps a
   bounded window of intervals per fault domain and computes a windowed
-  duty cycle (busy seconds over wall seconds, overlap-clipped), i.e. how
-  loaded each ``DeviceHandle`` actually is, not how many dispatches it
-  saw.
+  duty cycle (busy seconds over wall seconds, overlap-clipped). "Busy"
+  is host-clock dispatch occupancy, the time a device call was
+  outstanding, pack and issue included: not device busy share (that is
+  ``device.busy_s`` of a traced benchmark run).
 * **lane-fill efficiency** — the mesh chunk loop reports real signature
   lanes vs the padded pow2-bucket capacity it dispatched
   (``note_chunk``), so the lanes wasted to AOT shape buckets become a
@@ -96,12 +97,15 @@ class Metrics:
         r = registry if registry is not None else Registry()
         self.device_utilization = r.gauge(
             SUBSYSTEM, "device_utilization",
-            "Windowed duty cycle per fault domain: busy seconds over "
-            "wall seconds in the rolling window, by device label.",
+            "Windowed host-clock dispatch occupancy per fault domain: "
+            "seconds a device call was outstanding over wall seconds in "
+            "the rolling window, by device label. Not device busy share "
+            "(that is device.busy_s of a traced benchmark run).",
         )
         self.device_busy_seconds = r.counter(
             SUBSYSTEM, "device_busy_seconds",
-            "Cumulative device-busy wall time, by device label.",
+            "Cumulative host-clock wall time of device calls, by device "
+            "label.",
         )
         self.device_sigs = r.counter(
             SUBSYSTEM, "device_sigs",

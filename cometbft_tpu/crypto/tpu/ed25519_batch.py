@@ -965,8 +965,6 @@ def verify_valset_resident(
         return []
     if len(msgs) != n or len(sigs) != n:
         raise ValueError("msgs/sigs must have one entry per validator")
-    rv = _get_resident(valset_id, pub_keys)
-
     from collections import deque
 
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
@@ -974,6 +972,10 @@ def verify_valset_resident(
     import time
 
     from cometbft_tpu.crypto import wire as wirelib
+    from cometbft_tpu.libs import trace as tracelib
+
+    with tracelib.stage("commit.valset_id"):
+        rv = _get_resident(valset_id, pub_keys)
 
     ndev = mesh_mod.n_devices()
     depth = mesh_mod.pipeline_depth()
@@ -989,9 +991,11 @@ def verify_valset_resident(
     def retire(slot):
         start, end, mask, valid, winfo = slot
         t_d2h = time.perf_counter()
-        out[start:end] = (
-            np.asarray(mask)[: end - start] & valid & rv.pk_ok[start:end]
-        )
+        # np.asarray blocks until the device finishes this chunk
+        with tracelib.stage("resident.retire"):
+            out[start:end] = (
+                np.asarray(mask)[: end - start] & valid & rv.pk_ok[start:end]
+            )
         if ledger is not None:
             size, wire_bytes, pack_s, launch_s = winfo
             ledger.note_chunk(
@@ -1006,22 +1010,25 @@ def verify_valset_resident(
     # the resident pubkey rows must survive across commits
     for start, end, size, a_dev in rv.chunks:
         t_pack = time.perf_counter()
-        rsh, valid = _prepare_rsh(
-            rv.pk_arr[start:end], msgs[start:end], sigs[start:end]
-        )
-        rsh_pad = np.zeros((24, size), np.uint32)
-        rsh_pad[:, : end - start] = rsh
+        with tracelib.stage("resident.pack"):
+            rsh, valid = _prepare_rsh(
+                rv.pk_arr[start:end], msgs[start:end], sigs[start:end]
+            )
+            rsh_pad = np.zeros((24, size), np.uint32)
+            rsh_pad[:, : end - start] = rsh
         t_launch = time.perf_counter()
         built = build.total()
-        if ndev > 1:
-            mask = mesh_mod.sharded_verify(
-                verify_kernel_resident, [a_dev, rsh_pad], donate_from=1
-            )
-        else:
-            rsh_dev = jax.device_put(jnp.asarray(rsh_pad))
-            mask = mesh_mod.run_single(
-                verify_kernel_resident, [a_dev, rsh_dev], donate_from=1
-            )
+        # the issue cost: both calls return before the device is done
+        with tracelib.stage("resident.launch"):
+            if ndev > 1:
+                mask = mesh_mod.sharded_verify(
+                    verify_kernel_resident, [a_dev, rsh_pad], donate_from=1
+                )
+            else:
+                rsh_dev = jax.device_put(jnp.asarray(rsh_pad))
+                mask = mesh_mod.run_single(
+                    verify_kernel_resident, [a_dev, rsh_dev], donate_from=1
+                )
         launch_s = time.perf_counter() - t_launch - (build.total() - built)
         winfo = (size, rsh_pad.nbytes, t_launch - t_pack,
                  max(0.0, launch_s))

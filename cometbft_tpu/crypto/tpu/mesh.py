@@ -606,23 +606,20 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
         # np.asarray blocks until the device finishes this chunk — the
         # wait measured here IS the device-time attribution for the span
         # (host work for the chunk already happened before dispatch).
-        rspan = span.child("wire_d2h")
         t_dev = time.perf_counter_ns()
         try:
-            out[start:end] = np.asarray(mask)[: end - start]
+            with _trace.stage("mesh.retire", span=span.child("mesh.retire")):
+                out[start:end] = np.asarray(mask)[: end - start]
         except DispatchCancelled:
-            rspan.end(error="cancelled")
             span.end(error="cancelled")
             raise
         except Exception as exc:  # noqa: BLE001 - device died mid-retire
-            rspan.end(error=repr(exc))
             span.end(error=repr(exc))
             raise RuntimeError(
                 f"retire of chunk {chunk_idx} (sigs [{start}:{end}]) "
                 f"failed: {exc}"
             ) from exc
         wait_ns = time.perf_counter_ns() - t_dev
-        rspan.end()
         d2h_s = wait_ns / 1e9
         _tot["d2h"] += d2h_s
         if _ledger is not None and winfo is not None:
@@ -656,25 +653,24 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
         overlapped = len(inflight) > 0 or len(staged) > 0
         t_host = time.perf_counter_ns()
         try:
-            pspan = span.child("wire_pack")
-            if callable(packed):
-                chunk = packed(start, end)
-            else:
-                chunk = [a[..., start:end] for a in packed]
-            size = min_pad
-            while size < end - start:
-                size *= 2
-            if ndev > 1:
-                size = -(-size // ndev) * ndev
+            with _trace.stage("mesh.pack", span=span.child("mesh.pack")):
+                if callable(packed):
+                    chunk = packed(start, end)
+                else:
+                    chunk = [a[..., start:end] for a in packed]
+                size = min_pad
+                while size < end - start:
+                    size *= 2
+                if ndev > 1:
+                    size = -(-size // ndev) * ndev
 
-            def pad(a):
-                padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
-                padded[..., : end - start] = a
-                return padded
+                def pad(a):
+                    padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
+                    padded[..., : end - start] = a
+                    return padded
 
-            padded_args = [pad(a) for a in chunk]
+                padded_args = [pad(a) for a in chunk]
             t_pack = time.perf_counter_ns()
-            pspan.end()
             wire_bytes = sum(int(a.nbytes) for a in padded_args)
             if ndev > 1:
                 # legacy auto-shard path: the device_put happens inside
@@ -690,13 +686,14 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
                 # chunk is issued before earlier chunks' compute has
                 # drained; the launch then consumes already-placed
                 # (donated) buffers
-                hspan = span.child("wire_h2d")
-                placed = [
-                    jax.device_put(jnp.asarray(a), jax_dev)
-                    for a in padded_args
-                ]
+                with _trace.stage(
+                    "mesh.launch", span=span.child("mesh.launch")
+                ):
+                    placed = [
+                        jax.device_put(jnp.asarray(a), jax_dev)
+                        for a in padded_args
+                    ]
                 t_h2d = time.perf_counter_ns()
-                hspan.end()
         except DispatchCancelled:
             span.end(error="cancelled")
             raise
@@ -717,13 +714,14 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
         t_launch = time.perf_counter_ns()
         built = _build.total()
         try:
-            cspan = span.child("wire_compute")
-            if ndev > 1:
-                mask = sharded_verify(kernel, placed)
-            else:
-                mask = run_single(kernel, placed, device=jax_dev)
+            with _trace.stage(
+                "mesh.launch", span=span.child("mesh.launch")
+            ):
+                if ndev > 1:
+                    mask = sharded_verify(kernel, placed)
+                else:
+                    mask = run_single(kernel, placed, device=jax_dev)
             t_compute = time.perf_counter_ns()
-            cspan.end()
         except DispatchCancelled:
             span.end(error="cancelled")
             raise
@@ -970,18 +968,16 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
 
     def retire(slot):
         chunk_idx, start, end, mask, span, shard_spans, winfo = slot
-        rspan = span.child("wire_d2h")
         t_dev = time.perf_counter_ns()
         try:
-            out[start:end] = np.asarray(mask)[: end - start]
+            with _trace.stage("mesh.retire", span=span.child("mesh.retire")):
+                out[start:end] = np.asarray(mask)[: end - start]
         except DispatchCancelled:
-            rspan.end(error="cancelled")
             for s in shard_spans:
                 s.end(error="cancelled")
             span.end(error="cancelled")
             raise
         except Exception as exc:  # noqa: BLE001 - device died mid-retire
-            rspan.end(error=repr(exc))
             for s in shard_spans:
                 s.end(error=repr(exc))
             span.end(error=repr(exc))
@@ -990,7 +986,6 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
                 f"failed: {exc}"
             ) from exc
         wait = time.perf_counter_ns() - t_dev
-        rspan.end()
         d2h_s = wait / 1e9
         _tot["d2h"] += d2h_s
         if _ledger is not None and winfo is not None:
@@ -1026,25 +1021,24 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         overlapped = len(inflight) > 0 or len(staged) > 0
         t_host = time.perf_counter_ns()
         try:
-            pspan = span.child("wire_pack")
-            if callable(packed):
-                chunk = packed(start, end)
-            else:
-                chunk = [a[..., start:end] for a in packed]
-            # pow2 per-shard bucket; end-start <= per_shard_cap * nsh
-            # and the cap is pow2-derived, so per <= per_shard_cap
-            per = _pow2(-(-(end - start) // nsh), min_pad)
-            size = per * nsh
-            max_bucket = max(max_bucket, per)
+            with _trace.stage("mesh.pack", span=span.child("mesh.pack")):
+                if callable(packed):
+                    chunk = packed(start, end)
+                else:
+                    chunk = [a[..., start:end] for a in packed]
+                # pow2 per-shard bucket; end-start <= per_shard_cap * nsh
+                # and the cap is pow2-derived, so per <= per_shard_cap
+                per = _pow2(-(-(end - start) // nsh), min_pad)
+                size = per * nsh
+                max_bucket = max(max_bucket, per)
 
-            def pad(a):
-                padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
-                padded[..., : end - start] = a
-                return padded
+                def pad(a):
+                    padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
+                    padded[..., : end - start] = a
+                    return padded
 
-            padded_args = [pad(a) for a in chunk]
+                padded_args = [pad(a) for a in chunk]
             t_pack = time.perf_counter_ns()
-            pspan.end()
             wire_bytes = sum(int(a.nbytes) for a in padded_args)
             shardings = tuple(
                 NamedSharding(
@@ -1052,13 +1046,14 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
                 )
                 for a in padded_args
             )
-            hspan = span.child("wire_h2d")
-            placed = [
-                jax.device_put(jnp.asarray(a), s)
-                for a, s in zip(padded_args, shardings)
-            ]
+            with _trace.stage(
+                "mesh.launch", span=span.child("mesh.launch")
+            ):
+                placed = [
+                    jax.device_put(jnp.asarray(a), s)
+                    for a, s in zip(padded_args, shardings)
+                ]
             t_h2d = time.perf_counter_ns()
-            hspan.end()
         except DispatchCancelled:
             span.end(error="cancelled")
             raise
@@ -1090,13 +1085,14 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
                 )
                 if _hub is not None:
                     _hub.note_chunk(h.label, lanes, per)
-            cspan = span.child("wire_compute")
-            mask = registry.call(
-                kernel, placed, donate_from=donate_from, sharded=True,
-                mesh=plan.mesh,
-            )
+            with _trace.stage(
+                "mesh.launch", span=span.child("mesh.launch")
+            ):
+                mask = registry.call(
+                    kernel, placed, donate_from=donate_from, sharded=True,
+                    mesh=plan.mesh,
+                )
             t_compute = time.perf_counter_ns()
-            cspan.end()
         except DispatchCancelled:
             span.end(error="cancelled")
             raise

@@ -5,6 +5,25 @@ BackendSupervisor -> mesh.dispatch_batch) and several threads.  Aggregate
 counters cannot attribute a slow commit verification to queue wait vs.
 flush deadline vs. device dispatch vs. CPU fallback; spans can.
 
+Two recorders, one entry point (``stage``):
+
+- The *flight recorder* (``Tracer``/``Span``) is SAMPLED
+  (``trace_sample``, 0 by default) and keeps whole request trees with
+  their tags on ``time.perf_counter_ns``.  It is for incidents: the
+  watchdog and the breakers dump it, ``/debug/traces`` serves it,
+  ``tools/trace_report.py`` renders it.  Its clock is not the
+  profiler's, so it cannot be laid over a device trace.
+- The *profiler annotation* (``jax.profiler.TraceAnnotation`` named
+  ``cbft:<layer>.<stage>``) is recorded only while a profiler session
+  runs (a benchmark's ``--trace 1`` sub-window, an operator's
+  ``libs/profiling.ProfilerCapture``) and carries no tags.  The
+  profiler stamps it and the device's operations on one timeline, so it
+  is what says which program stage the host was in while the device
+  sat idle.  It exists for request-path stages only: the idle-gap
+  attribution gives a gap to the span opened last on ANY thread, so
+  work that no request waits for runs under ``background()`` and writes
+  none.
+
 Design:
 
 - ``Span`` carries (trace_id, span_id, parent_id, name, tags) and
@@ -43,6 +62,7 @@ from __future__ import annotations
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -101,12 +121,20 @@ def trace_dump_keep_default(config_value: Optional[int] = None) -> int:
 # --------------------------------------------------------------------------
 # Module-level current-span propagation (shared across tracers/threads).
 
-_ctx = threading.local()
+
+class _Ctx(threading.local):
+    # class-level defaults: a thread that never set one reads it without
+    # the AttributeError a bare threading.local raises (and getattr hides)
+    stack: Optional[List["Span"]] = None
+    background = False
+
+
+_ctx = _Ctx()
 
 
 def current_span() -> Optional["Span"]:
     """The innermost span installed via ``use`` on this thread, or None."""
-    stack = getattr(_ctx, "stack", None)
+    stack = _ctx.stack
     if stack:
         return stack[-1]
     return None
@@ -124,6 +152,25 @@ def child_of_current(name: str, **tags: Any) -> "Span":
     return cur.child(name, **tags)
 
 
+def _install(span: "Span") -> None:
+    stack = _ctx.stack
+    if stack is None:
+        stack = _ctx.stack = []
+    stack.append(span)
+
+
+def _uninstall(span: "Span") -> None:
+    stack = _ctx.stack
+    if stack:
+        try:
+            if stack[-1] is span:
+                stack.pop()
+            else:  # unbalanced exit; remove wherever it sits
+                stack.remove(span)
+        except ValueError:
+            pass
+
+
 class use:
     """Context manager installing ``span`` as this thread's current span."""
 
@@ -133,23 +180,118 @@ class use:
         self._span = span
 
     def __enter__(self) -> "Span":
-        stack = getattr(_ctx, "stack", None)
-        if stack is None:
-            stack = _ctx.stack = []
-        stack.append(self._span)
+        _install(self._span)
         return self._span
 
     def __exit__(self, *exc: Any) -> bool:
-        stack = getattr(_ctx, "stack", None)
-        if stack:
-            try:
-                if stack[-1] is self._span:
-                    stack.pop()
-                else:  # unbalanced exit; remove wherever it sits
-                    stack.remove(self._span)
-            except ValueError:
-                pass
+        _uninstall(self._span)
         return False
+
+
+# --------------------------------------------------------------------------
+# Request-path stages: flight-recorder span + profiler annotation.
+
+STAGE_PREFIX = "cbft:"
+
+_annotation_cls: Any = None
+
+
+def _annotation(label: str) -> Any:
+    """An open ``jax.profiler.TraceAnnotation``, or None where jax is
+    not loaded (a CPU-only node must not import it for this) or this
+    thread's work is background.  Inactive (one small object, one check)
+    unless a profiler session is running."""
+    global _annotation_cls
+    if _ctx.background:
+        return None
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        # raises (and is tried again) while another thread is still
+        # importing jax: the module is there before its profiler is
+        cls = _annotation_cls = jax.profiler.TraceAnnotation
+    return cls(label)
+
+
+class stage:
+    """One lexical, same-thread stage between a request's submit and its
+    verdict: ``with stage("sched.route") as span:``.
+
+    Opens the flight recorder's child span exactly as
+    ``child_of_current(name, **tags)`` does (``NOOP_SPAN`` when the
+    request is unsampled), installs it as the thread's current span and
+    yields it, and opens the profiler annotation ``cbft:<name>``; closes
+    both on exit.  Names are fixed strings: sizes and ids go in tags,
+    which only the flight-recorder span carries.
+
+    ``span`` replaces the new child where the caller makes the
+    flight-recorder span itself (a root-capable ``Tracer.span``, a
+    chunk's child); ``NOOP_SPAN`` there means annotation only, for a
+    span that is made on one thread and ended on another.  Ending is
+    first-wins, so a body that ends the span with its outcome tags
+    keeps them.  Nothing the tracing itself raises reaches the body."""
+
+    __slots__ = ("_label", "_span", "_ann")
+
+    def __init__(self, name: str, span: Optional["Span"] = None, **tags: Any):
+        self._label = STAGE_PREFIX + name
+        self._span = (
+            child_of_current(name, **tags) if span is None else span
+        )
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        span = self._span
+        if not span.noop:
+            _install(span)
+        try:
+            self._ann = _annotation(self._label)
+        except Exception:  # noqa: BLE001 - tracing never fails a verify
+            pass
+        return span
+
+    def __exit__(self, etype: Any, exc: Any, tb: Any) -> bool:
+        ann = self._ann
+        if ann is not None:
+            try:
+                ann.__exit__(etype, exc, tb)
+            except Exception:  # noqa: BLE001
+                pass
+        span = self._span
+        if not span.noop:
+            _uninstall(span)
+            span.__exit__(etype, exc, tb)
+        return False
+
+
+class background:
+    """Marks this thread's work as nothing a request waits for (audit,
+    probe, canary): stages opened inside keep their flight-recorder span
+    and write no profiler annotation.  ``background(False)`` is a no-op,
+    so a worker can re-apply what its spawner's thread had
+    (``in_background()``)."""
+
+    __slots__ = ("_on", "_prev")
+
+    def __init__(self, on: bool = True):
+        self._on = on
+        self._prev = False
+
+    def __enter__(self) -> "background":
+        self._prev = in_background()
+        if self._on:
+            _ctx.background = True
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        _ctx.background = self._prev
+        return False
+
+
+def in_background() -> bool:
+    return _ctx.background
 
 
 # --------------------------------------------------------------------------
@@ -309,15 +451,10 @@ class Tracer:
 
     # -- construction ------------------------------------------------------
 
-    def set_on_span_end(self, fn: Optional[Callable[[Span], None]]) -> None:
-        self._on_span_end = fn
-
     def add_span_end_listener(self, fn: Callable[[Span], None]) -> None:
         """Chain ``fn`` onto the span-end hook without displacing the
         current listener (both run; listener exceptions are swallowed at
-        the call site as before). Lets several consumers — the stage
-        histogram, the supervisor's dispatch latency model, tests —
-        observe finished spans independently."""
+        the call site)."""
         prev = self._on_span_end
         if prev is None:
             self._on_span_end = fn
@@ -587,12 +724,6 @@ def default_tracer() -> Tracer:
         if _default is None:
             _default = Tracer()
         return _default
-
-
-def set_default_tracer(tracer: Optional[Tracer]) -> None:
-    global _default
-    with _default_mtx:
-        _default = tracer
 
 
 # --------------------------------------------------------------------------

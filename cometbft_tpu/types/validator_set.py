@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Tuple
 from cometbft_tpu.crypto import batch as cryptobatch
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.libs import protoio
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.types.block import BlockID, Commit
 from cometbft_tpu.types.validator import MAX_TOTAL_VOTING_POWER, Validator
 
@@ -286,17 +287,22 @@ class ValidatorSet:
             return []
         from cometbft_tpu.crypto import ed25519 as ed
 
-        if cryptobatch.resident_commit_eligible(len(entries), backend) and all(
-            isinstance(v.pub_key, ed.PubKeyEd25519) for v in self.validators
-        ):
-            full = cryptobatch.verify_commit_valset(
-                [v.pub_key.bytes() for v in self.validators],
-                lane_msgs,
-                lane_sigs,
-                backend,
-            )
-            if full is not None:
-                return [bool(full[e[0]]) for e in entries]
+        if cryptobatch.resident_commit_eligible(len(entries), backend):
+            with tracelib.stage("commit.valset_id"):
+                pub_keys = (
+                    [v.pub_key.bytes() for v in self.validators]
+                    if all(
+                        isinstance(v.pub_key, ed.PubKeyEd25519)
+                        for v in self.validators
+                    )
+                    else None
+                )
+            if pub_keys is not None:
+                full = cryptobatch.verify_commit_valset(
+                    pub_keys, lane_msgs, lane_sigs, backend
+                )
+                if full is not None:
+                    return [bool(full[e[0]]) for e in entries]
         bv = cryptobatch.new_batch_verifier(backend)
         for e in entries:
             idx = e[0]
@@ -327,23 +333,25 @@ class ValidatorSet:
         entries = []  # (idx, val, for_block)
         lane_msgs: list = [None] * self.size()
         lane_sigs: list = [None] * self.size()
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            val = self.validators[idx]
-            lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
-            lane_sigs[idx] = cs.signature
-            entries.append((idx, val, cs.for_block()))
+        with tracelib.stage("commit.sign_bytes"):
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                val = self.validators[idx]
+                lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
+                lane_sigs[idx] = cs.signature
+                entries.append((idx, val, cs.for_block()))
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
-        tallied = 0
-        needed = self.total_voting_power() * 2 // 3
-        for (idx, val, for_block), ok in zip(entries, mask):
-            if not ok:
-                raise ValueError(
-                    f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
-                )
-            if for_block:
-                tallied += val.voting_power
+        with tracelib.stage("commit.tally"):
+            tallied = 0
+            needed = self.total_voting_power() * 2 // 3
+            for (idx, val, for_block), ok in zip(entries, mask):
+                if not ok:
+                    raise ValueError(
+                        f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
+                    )
+                if for_block:
+                    tallied += val.voting_power
         if tallied <= needed:
             raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
@@ -372,26 +380,28 @@ class ValidatorSet:
         speculative = 0
         lane_msgs: list = [None] * self.size()
         lane_sigs: list = [None] * self.size()
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val = self.validators[idx]
-            entries.append((idx, val))
-            lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
-            lane_sigs[idx] = cs_sig(commit, idx)
-            speculative += val.voting_power
-            if speculative > needed:
-                break
+        with tracelib.stage("commit.sign_bytes"):
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                val = self.validators[idx]
+                entries.append((idx, val))
+                lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
+                lane_sigs[idx] = cs_sig(commit, idx)
+                speculative += val.voting_power
+                if speculative > needed:
+                    break
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
-        tallied = 0
-        for (idx, val), ok in zip(entries, mask):
-            if not ok:
-                raise ValueError(
-                    f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
-                )
-            tallied += val.voting_power
-            if tallied > needed:
-                return
+        with tracelib.stage("commit.tally"):
+            tallied = 0
+            for (idx, val), ok in zip(entries, mask):
+                if not ok:
+                    raise ValueError(
+                        f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
+                    )
+                tallied += val.voting_power
+                if tallied > needed:
+                    return
         raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
     def verify_commit_light_trusting(
@@ -420,34 +430,36 @@ class ValidatorSet:
         lane_sigs: list = [None] * self.size()
         speculative = 0
         double_vote: Optional[Tuple[Validator, int, int]] = None
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen_vals:
-                # double vote: reference errors here *after* verifying all
-                # prior sigs; record and stop collecting
-                double_vote = (val, seen_vals[val_idx], idx)
-                break
-            seen_vals[val_idx] = idx
-            lane_msgs[val_idx] = commit.vote_sign_bytes(chain_id, idx)
-            lane_sigs[val_idx] = cs_sig(commit, idx)
-            entries.append((val_idx, idx, val))
-            speculative += val.voting_power
-            if speculative > needed:
-                break
+        with tracelib.stage("commit.sign_bytes"):
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                val_idx, val = self.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    # double vote: reference errors here *after* verifying
+                    # all prior sigs; record and stop collecting
+                    double_vote = (val, seen_vals[val_idx], idx)
+                    break
+                seen_vals[val_idx] = idx
+                lane_msgs[val_idx] = commit.vote_sign_bytes(chain_id, idx)
+                lane_sigs[val_idx] = cs_sig(commit, idx)
+                entries.append((val_idx, idx, val))
+                speculative += val.voting_power
+                if speculative > needed:
+                    break
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
-        tallied = 0
-        for (val_idx, idx, val), ok in zip(entries, mask):
-            if not ok:
-                raise ValueError(
-                    f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
-                )
-            tallied += val.voting_power
-            if tallied > needed:
-                return
+        with tracelib.stage("commit.tally"):
+            tallied = 0
+            for (val_idx, idx, val), ok in zip(entries, mask):
+                if not ok:
+                    raise ValueError(
+                        f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex().upper()}"
+                    )
+                tallied += val.voting_power
+                if tallied > needed:
+                    return
         if double_vote is not None:
             val, first, second = double_vote
             raise ValueError(f"double vote from {val} ({first} and {second})")
