@@ -291,11 +291,15 @@ class BlocksyncReactor(Reactor):
                         continue
                     val = state.validators.validators[idx]
                     entries.append((idx, val))
-                    lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
                     lane_sigs[idx] = cs_sig(commit, idx)
                     speculative += val.voting_power
                     if speculative > needed:
                         break
+                idxs = [e[0] for e in entries]
+                for idx, msg in zip(
+                    idxs, commit.vote_sign_bytes_many(chain_id, idxs)
+                ):
+                    lane_msgs[idx] = msg
             except Exception:
                 # malformed commit in the window — single-block path will
                 # attribute and redo it

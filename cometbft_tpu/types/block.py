@@ -9,7 +9,7 @@ Data.Hash (block.go:1004), EvidenceList hashing (evidence.go).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Iterable, List, Optional
 
 from cometbft_tpu.crypto import merkle, tmhash
 from cometbft_tpu.libs import protoio
@@ -272,6 +272,39 @@ class Commit:
         from cometbft_tpu.types.vote import vote_sign_bytes
 
         return vote_sign_bytes(chain_id, self.get_vote(val_idx))
+
+    def vote_sign_bytes_many(
+        self, chain_id: str, val_idxs: Iterable[int]
+    ) -> List[bytes]:
+        """``vote_sign_bytes`` of each of ``val_idxs``, in their order,
+        from one template per block id a precommit of this commit can
+        carry (the commit's own under BLOCK_ID_FLAG_COMMIT, else the
+        zero one, built when the first such lane is met) and no Vote."""
+        from cometbft_tpu.types.canonical import CanonicalVoteTemplate
+        from cometbft_tpu.types.vote import SIGNED_MSG_TYPE_PRECOMMIT
+
+        def template(block_id: BlockID):
+            return CanonicalVoteTemplate(
+                SIGNED_MSG_TYPE_PRECOMMIT,
+                self.height,
+                self.round,
+                block_id,
+                chain_id,
+            ).sign_bytes
+
+        for_block = template(self.block_id)
+        for_nil = None
+        signatures = self.signatures
+        out = []
+        for val_idx in val_idxs:
+            cs = signatures[val_idx]
+            if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT:
+                out.append(for_block(cs.timestamp))
+            else:
+                if for_nil is None:
+                    for_nil = template(BlockID())
+                out.append(for_nil(cs.timestamp))
+        return out
 
     def validate_basic(self) -> None:
         if self.height < 0:

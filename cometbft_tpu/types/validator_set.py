@@ -333,14 +333,16 @@ class ValidatorSet:
         entries = []  # (idx, val, for_block)
         lane_msgs: list = [None] * self.size()
         lane_sigs: list = [None] * self.size()
-        with tracelib.stage("commit.sign_bytes"):
+        with tracelib.stage("commit.sign_bytes") as span:
+            idxs = []
             for idx, cs in enumerate(commit.signatures):
                 if cs.is_absent():
                     continue
                 val = self.validators[idx]
-                lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
+                idxs.append(idx)
                 lane_sigs[idx] = cs.signature
                 entries.append((idx, val, cs.for_block()))
+            _fill_lane_msgs(span, commit, chain_id, idxs, lane_msgs, idxs)
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
         with tracelib.stage("commit.tally"):
             tallied = 0
@@ -380,17 +382,19 @@ class ValidatorSet:
         speculative = 0
         lane_msgs: list = [None] * self.size()
         lane_sigs: list = [None] * self.size()
-        with tracelib.stage("commit.sign_bytes"):
+        with tracelib.stage("commit.sign_bytes") as span:
+            idxs = []
             for idx, cs in enumerate(commit.signatures):
                 if not cs.for_block():
                     continue
                 val = self.validators[idx]
                 entries.append((idx, val))
-                lane_msgs[idx] = commit.vote_sign_bytes(chain_id, idx)
+                idxs.append(idx)
                 lane_sigs[idx] = cs_sig(commit, idx)
                 speculative += val.voting_power
                 if speculative > needed:
                     break
+            _fill_lane_msgs(span, commit, chain_id, idxs, lane_msgs, idxs)
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
         with tracelib.stage("commit.tally"):
             tallied = 0
@@ -430,7 +434,7 @@ class ValidatorSet:
         lane_sigs: list = [None] * self.size()
         speculative = 0
         double_vote: Optional[Tuple[Validator, int, int]] = None
-        with tracelib.stage("commit.sign_bytes"):
+        with tracelib.stage("commit.sign_bytes") as span:
             for idx, cs in enumerate(commit.signatures):
                 if not cs.for_block():
                     continue
@@ -443,12 +447,19 @@ class ValidatorSet:
                     double_vote = (val, seen_vals[val_idx], idx)
                     break
                 seen_vals[val_idx] = idx
-                lane_msgs[val_idx] = commit.vote_sign_bytes(chain_id, idx)
                 lane_sigs[val_idx] = cs_sig(commit, idx)
                 entries.append((val_idx, idx, val))
                 speculative += val.voting_power
                 if speculative > needed:
                     break
+            _fill_lane_msgs(
+                span,
+                commit,
+                chain_id,
+                [e[1] for e in entries],
+                lane_msgs,
+                [e[0] for e in entries],
+            )
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
         with tracelib.stage("commit.tally"):
             tallied = 0
@@ -515,3 +526,18 @@ class ValidatorSet:
 
 def cs_sig(commit: Commit, idx: int) -> bytes:
     return commit.signatures[idx].signature
+
+
+def _fill_lane_msgs(
+    span, commit: Commit, chain_id: str, idxs: List[int], lane_msgs: list,
+    lanes: List[int],
+) -> None:
+    """lane_msgs[lanes[k]] = the sign-bytes of commit signature idxs[k],
+    all built in one pass (Commit.vote_sign_bytes_many); ``span``, the
+    ``commit.sign_bytes`` stage's, is tagged with what was built."""
+    for lane, msg in zip(lanes, commit.vote_sign_bytes_many(chain_id, idxs)):
+        lane_msgs[lane] = msg
+    if not span.noop:
+        all_for_block = all(commit.signatures[i].for_block() for i in idxs)
+        span.set_tag("lanes", len(idxs))
+        span.set_tag("templates", 1 if all_for_block else 2)

@@ -170,6 +170,53 @@ def test_each_stage_is_on_the_host_plane_inside_its_caller_on_its_thread(
         assert line not in others
 
 
+def test_commit_sign_bytes_opens_once_a_commit(capture):
+    """The batch build stays ONE stage a commit (not one a template or a
+    lane): its count is the per-commit divisor of resident_wait_ms."""
+    found = [e for e in _named(capture, PREFIX + "commit.sign_bytes")
+             if _inside(capture, "bench:commit", e)]
+    assert len(found) == 1
+
+
+@pytest.mark.parametrize("entry", ["verify_commit", "verify_commit_light",
+                                   "verify_commit_light_trusting"])
+@pytest.mark.parametrize("nil_lane,templates", [(None, 1), (4, 2)])
+def test_commit_sign_bytes_is_tagged_with_lanes_and_templates(
+        entry, nil_lane, templates):
+    """What a commit built, on the flight-recorder span only: lanes, and
+    1 template (the commit's block id) or 2 (a nil precommit among the
+    lanes, which only the full verify_commit selects)."""
+    from cometbft_tpu.types import test_util
+    from cometbft_tpu.types.block import BlockID
+    from cometbft_tpu.types.validator_set import Fraction
+    from cometbft_tpu.types.vote import SIGNED_MSG_TYPE_PRECOMMIT
+
+    vals, bid, commit, _ = _fixture_commit()
+    if nil_lane is not None:
+        _, privs = test_util.deterministic_validator_set(6, 10)
+        commit.signatures[nil_lane] = test_util.make_vote(
+            privs[nil_lane], CHAIN_ID, nil_lane, 5, 0,
+            SIGNED_MSG_TYPE_PRECOMMIT, BlockID()).to_commit_sig()
+    tracer = tracelib.Tracer(sample=1.0)
+    root = tracer.start_span("request")
+    with tracelib.use(root):
+        if entry == "verify_commit":
+            vals.verify_commit(CHAIN_ID, bid, 5, commit, backend="cpu")
+        elif entry == "verify_commit_light":
+            vals.verify_commit_light(CHAIN_ID, bid, 5, commit, backend="cpu")
+        else:
+            vals.verify_commit_light_trusting(
+                CHAIN_ID, commit, Fraction(1, 3), backend="cpu")
+    root.end()
+    (stage,) = [s for s in tracer.recent()[0]["spans"]
+                if s["name"] == "commit.sign_bytes"]
+    if entry == "verify_commit":
+        assert stage["tags"] == {"lanes": 6, "templates": templates}
+    else:  # for-block lanes only, up to the speculative quorum
+        assert stage["tags"]["templates"] == 1
+        assert 1 <= stage["tags"]["lanes"] <= 5
+
+
 def test_the_traced_stages_are_the_closed_list(capture):
     names = {e[0] for e in capture["events"] if e[0].startswith(PREFIX)}
     assert names == {PREFIX + s for s in STAGES}
