@@ -1063,19 +1063,12 @@ def warmup_plan(
     for bucket in buckets:
         for reg in registered_kernels():
             if ndev > 1:
-                # two sharded roundings can be in play: the legacy
-                # dispatch_batch auto-shard (pow2 rounded up to a
-                # multiple of ndev) and dispatch_sharded's pow2
-                # PER-SHARD bucket (shard_bucket). They coincide except
-                # at the smallest buckets; warm both, deduplicated, so
-                # either path finds its executable resident.
-                sharded_sizes = {
-                    -(-bucket // ndev) * ndev,
-                    mesh_mod.shard_bucket(bucket, ndev, _MIN_PAD),
-                }
-                for size in sorted(sharded_sizes):
-                    if (reg.name, size) in seen_sharded:
-                        continue
+                # the one rounding rule of every sharded launch
+                # (mesh.shard_chunks): a launch never carries more than
+                # the chunk cap in total, so the ladder's buckets,
+                # rounded as it rounds them, are every sharded shape
+                size = mesh_mod.shard_bucket(bucket, ndev, _MIN_PAD)
+                if (reg.name, size) not in seen_sharded:
                     seen_sharded.add((reg.name, size))
                     targets.append(WarmTarget(
                         reg.name, reg.kernel, reg.bucket_shapes(size),

@@ -835,10 +835,13 @@ _register_aot_kernels()
 
 def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
     """Pad the valset's pubkey rows into the dispatch chunk layout and
-    place them on device (sharded over the mesh when >1 device). Also
-    builds the indexed-dispatch view (single-device only): a u8[n_pad,
-    32] gather table plus a pubkey→row index, so steady-state flushes
-    against this valset ship an index vector instead of the keys."""
+    place them on device: sharded over the current shard plan's mesh
+    (mesh.shard_plan: the healthy fault domains that own a chip) where
+    there is one, else on the default chip. Chunks and padding are the
+    one rounding rule's (mesh.shard_chunks). Also builds the
+    indexed-dispatch view (single-device only): a u8[n_pad, 32] gather
+    table plus a pubkey→row index, so steady-state flushes against this
+    valset ship an index vector instead of the keys."""
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
@@ -853,31 +856,28 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
             parts.append(bytes(pk))
     pk_arr = np.frombuffer(b"".join(parts), np.uint8).reshape(n, 32)
 
-    max_chunk = mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD)
-    ndev = mesh_mod.n_devices()
+    plan = mesh_mod.shard_plan()
+    nsh = plan.n_shards if plan is not None else 1
     chunks = []
-    for start in range(0, n, max_chunk):
-        end = min(start + max_chunk, n)
-        size = _MIN_PAD
-        while size < end - start:
-            size *= 2
-        if ndev > 1:
-            size = -(-size // ndev) * ndev
+    for start, end, size in mesh_mod.shard_chunks(
+        n, nsh, mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD), _MIN_PAD
+    ):
         a_words = np.zeros((8, size), np.uint32)
         a_words[:, : end - start] = _le_words(pk_arr[start:end])
-        if ndev > 1:
-            sh = NamedSharding(mesh_mod.batch_mesh(), PS(None, "batch"))
-            a_dev = jax.device_put(jnp.asarray(a_words), sh)
+        if plan is not None:
+            sh = NamedSharding(plan.mesh, PS(None, "batch"))
+            a_dev = jax.device_put(a_words, sh)
         else:
             a_dev = jax.device_put(jnp.asarray(a_words))
         chunks.append((start, end, size, a_dev))
 
     rv = _ResidentValset()
     rv.chunks = chunks
+    rv.plan = plan
     rv.pk_arr = pk_arr
     rv.pk_ok = pk_ok
     rv.n = n
-    if ndev == 1 and n > 0:
+    if plan is None and n > 0:
         # indexed gather table: pow2-padded rows so successive valsets
         # of similar size reuse the compiled executable. Multi-device
         # meshes skip it — the gather would need the full table
@@ -977,7 +977,9 @@ def verify_valset_resident(
     with tracelib.stage("commit.valset_id"):
         rv = _get_resident(valset_id, pub_keys)
 
-    ndev = mesh_mod.n_devices()
+    # the mesh the rows were placed on: a launch runs where they live
+    plan = rv.plan
+    nsh = plan.n_shards if plan is not None else 1
     depth = mesh_mod.pipeline_depth()
     out = np.zeros(n, bool)
     inflight: "deque" = deque()
@@ -990,16 +992,18 @@ def verify_valset_resident(
 
     def retire(slot):
         start, end, mask, valid, winfo = slot
+        size, wire_bytes, pack_s, launch_s = winfo
         t_d2h = time.perf_counter()
-        # np.asarray blocks until the device finishes this chunk
-        with tracelib.stage("resident.retire"):
+        # np.asarray blocks until the device finishes this chunk (and,
+        # sharded, gathers the mask's slices from the chips)
+        with tracelib.stage("resident.retire", shards=nsh,
+                            lanes_per_shard=size // nsh):
             out[start:end] = (
                 np.asarray(mask)[: end - start] & valid & rv.pk_ok[start:end]
             )
         if ledger is not None:
-            size, wire_bytes, pack_s, launch_s = winfo
             ledger.note_chunk(
-                "resident", f"mesh:{ndev}" if ndev > 1 else "dev0", size,
+                "resident", f"mesh:{nsh}" if nsh > 1 else "dev0", size,
                 end - start, wire_bytes, pack_s, 0.0, launch_s,
                 time.perf_counter() - t_d2h,
             )
@@ -1019,10 +1023,12 @@ def verify_valset_resident(
         t_launch = time.perf_counter()
         built = build.total()
         # the issue cost: both calls return before the device is done
-        with tracelib.stage("resident.launch"):
-            if ndev > 1:
+        with tracelib.stage("resident.launch", shards=nsh,
+                            lanes_per_shard=size // nsh):
+            if plan is not None:
                 mask = mesh_mod.sharded_verify(
-                    verify_kernel_resident, [a_dev, rsh_pad], donate_from=1
+                    verify_kernel_resident, [a_dev, rsh_pad], donate_from=1,
+                    mesh=plan.mesh,
                 )
             else:
                 rsh_dev = jax.device_put(jnp.asarray(rsh_pad))

@@ -50,6 +50,7 @@ class KeyStoreEntry:
         "generation",      # store generation at upload (monotonic)
         "topo_generation",  # device-topology generation at build
         "chunks",          # list[(start, end, size, a_dev)] — resident rows
+        "plan",            # mesh.ShardPlan the rows are sharded over, or None
         "pk_arr",          # np.uint8[n, 32] host copy of the key rows
         "pk_ok",           # np.bool_[n] — False for malformed keys
         "index",           # dict: pubkey bytes -> row in table_dev
@@ -302,6 +303,7 @@ class DeviceKeyStore:
         e.valset_id = vid
         e.topo_generation = _topo_generation()
         e.chunks = []
+        e.plan = None
         e.pk_arr = np.zeros((n, 32), np.uint8)
         e.pk_ok = np.zeros(n, bool)
         e.index = {}

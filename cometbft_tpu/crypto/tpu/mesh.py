@@ -658,11 +658,7 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
                     chunk = packed(start, end)
                 else:
                     chunk = [a[..., start:end] for a in packed]
-                size = min_pad
-                while size < end - start:
-                    size *= 2
-                if ndev > 1:
-                    size = -(-size // ndev) * ndev
+                size = shard_bucket(end - start, ndev, min_pad)
 
                 def pad(a):
                     padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
@@ -806,16 +802,37 @@ def _pow2(n: int, floor: int) -> int:
     return size
 
 
-def shard_bucket(n: int, n_shards: int, min_pad: int) -> int:
-    """Total padded lanes for ``n`` real lanes sharded over ``n_shards``
-    devices: each device's shard is padded to a power of two (floored at
-    min_pad) so every per-device program runs a warmable pow2 bucket;
-    the total is that bucket × n_shards. Warm boot (aot.warmup_plan)
-    uses the SAME arithmetic, so a warmed sharded ladder covers every
-    shape dispatch_sharded can produce — the zero-compiles-after-warm
-    guarantee depends on these two staying in lockstep."""
+def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int):
+    """THE rounding rule of a batch launched over ``n_shards`` chips:
+    → [(start, end, size)], one entry a launch, lanes [start, end) of
+    the batch padded to ``size`` lanes, ``size // n_shards`` on each
+    chip. ``cap`` bounds the REAL lanes of one launch IN TOTAL, whatever
+    the shard count; a launch pads to a power of two (floored at
+    min_pad), rounded up to a multiple of n_shards so the shards are
+    equal. A 10,000-lane commit on four chips under the 8,192 cap is
+    8,192 + 2,048 padded lanes in two launches, 2,048 and 512 a chip.
+
+    The resident commit (ed25519_batch._build_resident), dispatch_batch,
+    dispatch_sharded and warm boot (aot.warmup_plan, through
+    shard_bucket) all round here, so a warmed ladder covers every
+    shape a dispatch can produce: the zero-compiles-after-warm
+    guarantee rests on there being one rule. With one shard it is the
+    single-device rule (pow2 bucket, chunks of ``cap``)."""
     n_shards = max(1, int(n_shards))
-    return _pow2(-(-max(1, int(n)) // n_shards), min_pad) * n_shards
+    cap = max(1, int(cap))
+    out = []
+    for start in range(0, max(0, int(n)), cap):
+        end = min(start + cap, n)
+        size = -(-_pow2(end - start, min_pad) // n_shards) * n_shards
+        out.append((start, end, size))
+    return out
+
+
+def shard_bucket(n: int, n_shards: int, min_pad: int) -> int:
+    """Total padded lanes of ONE launch of ``n`` real lanes over
+    ``n_shards`` chips (shard_chunks with the cap out of the way)."""
+    n = max(1, int(n))
+    return shard_chunks(n, n_shards, n, min_pad)[0][2]
 
 
 # --- sharded dispatch plan ---------------------------------------------------
@@ -906,12 +923,13 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
     checked at every chunk boundary, chunks are double-buffered
     (pipeline_depth) with staging prefetched ahead of compute
     (prefetch_depth), staging buffers are donated — plus the sharded
-    specifics: the per-shard lane count is the MINIMUM chunk cap over
-    the participating devices (each device's OOM-shrink ladder and
-    memory-plane guard clamp it), each chunk pads to a pow2 per-shard
-    bucket (shard_bucket), and per-shard child spans attribute the work
-    to each fault domain. Quarantined domains are excluded by the
-    ShardPlan; a topology generation bump re-slices on the next call."""
+    specifics: a launch's lane count IN TOTAL is capped by the MINIMUM
+    chunk cap over the participating devices (each device's OOM-shrink
+    ladder and memory-plane guard clamp it), chunks and padding follow
+    the one rounding rule (shard_chunks), and per-shard child spans
+    attribute the work to each fault domain. Quarantined domains are
+    excluded by the ShardPlan; a topology generation bump re-slices on
+    the next call."""
     from collections import deque
 
     import numpy as np
@@ -924,7 +942,6 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         with route_scope(ROUTE_SINGLE):
             return dispatch_batch(kernel, packed, n, max_chunk, min_pad)
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
     from cometbft_tpu.crypto import telemetry as _telemetry
@@ -935,15 +952,14 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
     _kernel_name = getattr(kernel, "__name__", "kernel")
     _plane = _memory.default_plane()
     _baselines = {}
-    per_shard_cap = None
+    launch_cap = None
     for h in plan.handles:
         if _plane is not None:
             _plane.refresh_guard(h, max_chunk, min_pad, kernel=_kernel_name)
             _baselines[h.label] = _plane.device_view(h).get("bytes_in_use")
         cap = h.chunk_cap(max_chunk, min_pad)
-        per_shard_cap = cap if per_shard_cap is None else min(
-            per_shard_cap, cap)
-    mega = per_shard_cap * nsh
+        launch_cap = cap if launch_cap is None else min(launch_cap, cap)
+    chunks = shard_chunks(n, nsh, launch_cap, min_pad)
     _hub = _telemetry.default_hub()
     from cometbft_tpu.crypto import wire as _wirelib
 
@@ -993,6 +1009,7 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
             _ledger.note_chunk(
                 ROUTE_SHARDED, _wire_dev, per_b, end - start, wire_bytes,
                 pack_s, h2d_s, compute_s, d2h_s, hidden_s=hidden_s,
+                padded_lanes=per_b * nsh,
             )
         for s in shard_spans:
             s.end(device_wait_ns=wait)
@@ -1003,7 +1020,7 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
     # shard) run ahead of the compute pointer, so the next megachunk's
     # transfer is in flight across the whole mesh while the current one
     # computes.
-    total_chunks = -(-n // mega) if n > 0 else 0
+    total_chunks = len(chunks)
     prefetch = prefetch_depth()
     staged: "deque" = deque()
     next_stage = 0
@@ -1012,8 +1029,8 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         nonlocal next_stage, max_bucket
         chunk_idx = next_stage
         next_stage += 1
-        start = chunk_idx * mega
-        end = min(start + mega, n)
+        start, end, size = chunks[chunk_idx]
+        per = size // nsh
         span = _trace.child_of_current(
             "sharded_chunk", chunk=chunk_idx, n_sigs=end - start,
             shards=nsh, generation=plan.generation,
@@ -1026,10 +1043,6 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
                     chunk = packed(start, end)
                 else:
                     chunk = [a[..., start:end] for a in packed]
-                # pow2 per-shard bucket; end-start <= per_shard_cap * nsh
-                # and the cap is pow2-derived, so per <= per_shard_cap
-                per = _pow2(-(-(end - start) // nsh), min_pad)
-                size = per * nsh
                 max_bucket = max(max_bucket, per)
 
                 def pad(a):
@@ -1049,8 +1062,11 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
             with _trace.stage(
                 "mesh.launch", span=span.child("mesh.launch")
             ):
+                # numpy rows go host -> each shard's chip; jnp.asarray
+                # first would land the whole chunk on chip 0 and make
+                # the placement a chip-to-chip copy
                 placed = [
-                    jax.device_put(jnp.asarray(a), s)
+                    jax.device_put(a, s)
                     for a, s in zip(padded_args, shardings)
                 ]
             t_h2d = time.perf_counter_ns()
@@ -1134,7 +1150,7 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         if cancel is not None and cancel.is_set():
             raise DispatchCancelled(
                 f"sharded dispatch cancelled before chunk {chunk_idx} "
-                f"(sigs [{chunk_idx * mega}:{n}] undone)"
+                f"(sigs [{chunks[chunk_idx][0]}:{n}] undone)"
             )
         while (next_stage < total_chunks
                and next_stage <= chunk_idx + prefetch):
@@ -1168,11 +1184,13 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
     return out
 
 
-def sharded_verify(kernel, args, donate_from: int = 0):
+def sharded_verify(kernel, args, donate_from: int = 0, mesh=None):
     """Run a verify kernel with every input's trailing (batch) axis
-    sharded over the FULL mesh. args are numpy arrays (or already-placed
-    jax arrays) whose trailing dim is the (padded) batch — the caller
-    pads to a multiple of the device count × lane tile already.
+    sharded over ``mesh``: a ShardPlan's (the resident commit hands in
+    the mesh its rows were placed on), else the FULL mesh (dispatch_batch's
+    legacy auto-shard). args are numpy arrays (or already-placed jax
+    arrays) whose trailing dim is the (padded) batch — the caller pads
+    to a multiple of the shard count already (shard_chunks).
 
     donate_from: index of the first argument eligible for buffer
     donation. Single-use staging buffers are donated so XLA reuses the
@@ -1186,18 +1204,17 @@ def sharded_verify(kernel, args, donate_from: int = 0):
     dispatch emits a trace span, and a batch axis wider than the
     resolved chunk cap × device count is split into capped sub-dispatches
     whose masks are concatenated. Megabatch callers should prefer
-    dispatch_sharded, which additionally honors the topology's
-    quarantine set and per-device memory guards; this entry serves
-    pre-placed/resident buffers (verify_valset_resident) against the
-    full mesh."""
+    dispatch_sharded, which additionally honors per-device memory
+    guards; this entry serves pre-placed/resident buffers
+    (verify_valset_resident)."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
     from cometbft_tpu.crypto.tpu import aot
 
-    mesh = batch_mesh()
+    if mesh is None:
+        mesh = batch_mesh()
     ndev = int(mesh.devices.size)
     batch = int(args[0].shape[-1])
     # chunk-cap contract: cap × ndev lanes per dispatch, using the
@@ -1220,8 +1237,10 @@ def sharded_verify(kernel, args, donate_from: int = 0):
                 NamedSharding(mesh, PS(*([None] * (a.ndim - 1) + ["batch"])))
                 for a in chunk_args
             )
+            # host rows go straight to each shard's chip (see
+            # dispatch_sharded); rows already placed there stay put
             placed = [
-                jax.device_put(jnp.asarray(a), s)
+                jax.device_put(a, s)
                 for a, s in zip(chunk_args, shardings)
             ]
             mask = registry.call(

@@ -142,6 +142,12 @@ class Metrics:
             "Real signature lanes carried by attributed chunks, by "
             "route.",
         )
+        self.padded_lanes = r.counter(
+            SUBSYSTEM, "padded_lanes",
+            "Lanes the attributed chunks were padded to (what the "
+            "device ran, every shard's added up), by route; beside "
+            "verify_wire_lanes it says what padding costs.",
+        )
         self.overlap_ratio = r.gauge(
             SUBSYSTEM, "overlap_ratio",
             "Pipeline overlap efficiency of the latest attributed "
@@ -253,6 +259,7 @@ class WireLedger:
         self.n_dispatches = 0
         self.demux_notes = 0
         self._lanes: Dict[str, int] = {}
+        self._padded: Dict[str, int] = {}
         self._link = dict(link) if link else None
 
     # --- cold-boot link seed -------------------------------------------------
@@ -283,12 +290,17 @@ class WireLedger:
         compute_s: float,
         d2h_s: float,
         hidden_s: float = 0.0,
+        padded_lanes: Optional[int] = None,
     ) -> None:
         """One chunk's phase attribution from the mesh dispatch loop.
         ``hidden_s`` is the portion of ``h2d_s`` spent while an earlier
-        chunk was still in flight (paid no wall time)."""
+        chunk was still in flight (paid no wall time). ``padded_lanes``
+        is what the chunk was padded to over all its shards; left out,
+        it is ``bucket`` (a loop that keys its profiles by the per-shard
+        bucket says the total)."""
         a = self._alpha
         bucket = int(bucket)
+        padded = max(0, int(bucket if padded_lanes is None else padded_lanes))
         phases = (
             ("pack", max(0.0, pack_s)),
             ("h2d", max(0.0, h2d_s)),
@@ -303,6 +315,7 @@ class WireLedger:
             self._lanes[route] = self._lanes.get(route, 0) + max(
                 0, int(lanes)
             )
+            self._padded[route] = self._padded.get(route, 0) + padded
             key = (route, bucket, device)
             p = self._profiles.get(key)
             if p is None:
@@ -334,6 +347,7 @@ class WireLedger:
             m.phase_seconds.with_labels(phase=name, route=route).observe(v)
         m.chunks.with_labels(route=route).add()
         m.lanes.with_labels(route=route).add(max(0, int(lanes)))
+        m.padded_lanes.with_labels(route=route).add(padded)
         m.bytes_on_wire.with_labels(device=device).add(
             max(0, int(wire_bytes))
         )
@@ -514,6 +528,12 @@ class WireLedger:
         with self._lock:
             return dict(self._lanes)
 
+    def padded_lanes_by_route(self) -> Dict[str, int]:
+        """Lanes the device ran for them, padding included, per wire
+        route: lanes_by_route's counterpart."""
+        with self._lock:
+            return dict(self._padded)
+
     def bytes_per_lane(self, route: str) -> Optional[float]:
         """Steady-state wire bytes per real signature lane for
         ``route`` — the EWMA over every attributed chunk, weighted
@@ -598,6 +618,7 @@ class WireLedger:
             "window": self.window,
             "chunks": counters[0],
             "lanes": self.lanes_by_route(),
+            "padded_lanes": self.padded_lanes_by_route(),
             "dispatches": counters[1],
             "demux_notes": counters[2],
             "link": link,

@@ -112,29 +112,83 @@ def _mod3_truth(xs):
     return (np.asarray(xs) % 3) != 1
 
 
-class TestShardBucket:
-    def test_per_shard_bucket_is_minimal_pow2(self):
-        for n in (1, 7, 63, 64, 65, 771, 999, 4097, 10000):
-            for nsh in (2, 3, 7, 8):
-                total = mesh.shard_bucket(n, nsh, 64)
-                per = total // nsh
-                assert total % nsh == 0
-                assert per & (per - 1) == 0, f"per-shard {per} not pow2"
-                assert per >= 64
-                assert total >= n
-                # minimal: halving the per-shard bucket would not fit
-                assert per == 64 or (per // 2) * nsh < n
+class TestShardChunks:
+    """mesh.shard_chunks is THE rounding rule of a sharded batch: the
+    resident commit's rows (_build_resident), shard_bucket (and through
+    it dispatch_batch and warm boot) and dispatch_sharded all round
+    there. The cap bounds a launch's real lanes in total; a launch pads
+    to a power of two, rounded up to a multiple of the shard count."""
+
+    # (lanes, shards) -> (padded size of each launch) at cap 8,192
+    CASES = {
+        (150, 1): [256], (150, 4): [256], (150, 8): [256],
+        (4097, 1): [8192], (4097, 4): [8192], (4097, 8): [8192],
+        (10000, 1): [8192, 2048], (10000, 4): [8192, 2048],
+        (10000, 8): [8192, 2048],
+        (16385, 1): [8192, 8192, 64], (16385, 4): [8192, 8192, 64],
+        (16385, 8): [8192, 8192, 64],
+        # a mesh re-sliced to three chips: equal shards, not pow2 ones
+        (150, 3): [258], (10000, 3): [8193, 2049],
+    }
+
+    @pytest.mark.parametrize("n,nsh", sorted(CASES))
+    def test_padded_lanes_and_launches(self, n, nsh):
+        chunks = mesh.shard_chunks(n, nsh, 8192, 64)
+        assert [size for _, _, size in chunks] == self.CASES[(n, nsh)]
+        # the launches tile [0, n) in order, each within the cap
+        assert chunks[0][0] == 0 and chunks[-1][1] == n
+        for (_, end, _), (start, _, _) in zip(chunks, chunks[1:]):
+            assert end == start
+        for start, end, size in chunks:
+            assert 0 < end - start <= 8192
+            assert size >= end - start and size % nsh == 0
+            # minimal: the next smaller bucket would not hold the lanes
+            assert size // 2 < max(end - start, 64) + nsh
+        # one launch of the same lanes is what shard_bucket answers
+        if len(chunks) == 1:
+            assert mesh.shard_bucket(n, nsh, 64) == chunks[0][2]
+
+    def test_one_function_rounds_rows_bucket_and_warm_plan(self, monkeypatch):
+        """Swap the rule for one no real rule could be and all three
+        follow it: the resident rows' chunks, shard_bucket, and the
+        sharded sizes warm boot compiles."""
+        from cometbft_tpu.crypto.tpu import ed25519_batch as eb
+
+        seen = []
+
+        def odd_rule(n, n_shards, cap, min_pad):
+            seen.append((n, n_shards, cap, min_pad))
+            return [(s, min(s + 40, n), 48 * n_shards)
+                    for s in range(0, n, 40)]
+
+        monkeypatch.setattr(mesh, "shard_chunks", odd_rule)
+        ndev = mesh.n_devices()
+        assert mesh.shard_bucket(100, ndev, 64) == 48 * ndev
+        sharded = {t.bucket for t in aot.warmup_plan(sizes=[64, 128])
+                   if t.sharded}
+        assert sharded == {48 * ndev}
+        topology.set_default_topology(topology.DeviceTopology.detect())
+        pks = [ed.gen_priv_key_from_secret(bytes([i, 9])).pub_key().bytes()
+               for i in range(100)]
+        rv = eb._build_resident(pks)
+        assert [(s, e, z) for s, e, z, _ in rv.chunks] == [
+            (0, 40, 48 * ndev), (40, 80, 48 * ndev), (80, 100, 48 * ndev)]
+        assert rv.plan is not None and rv.plan.n_shards == ndev
+        assert (100, ndev, mesh.chunk_cap(8192, 64), 64) in seen
+        for _, _, _, a_dev in rv.chunks:
+            assert len({s.device for s in a_dev.addressable_shards}) == ndev
 
     def test_warm_plan_and_dispatch_arithmetic_lockstep(self):
-        # the zero-compiles-after-warm guarantee: for every ladder
-        # bucket, the shape dispatch_sharded produces for a chunk of
-        # that many real lanes is one of the totals warmup_plan warms
+        # the zero-compiles-after-warm guarantee: every launch of every
+        # batch size pads to a sharded total the ladder warms
         ndev = mesh.n_devices()
         assert ndev == 8  # conftest forces the 8-way virtual plane
-        for bucket in aot.bucket_ladder(floor=64):
-            warmed = {-(-bucket // ndev) * ndev,
-                      mesh.shard_bucket(bucket, ndev, 64)}
-            assert mesh.shard_bucket(bucket, ndev, 64) in warmed
+        ladder = aot.bucket_ladder(floor=64)
+        warmed = {mesh.shard_bucket(b, ndev, 64) for b in ladder}
+        cap = max(ladder)
+        for n in (64, 65, 150, 771, 4097, 10000, 16385, 40000):
+            for _, _, size in mesh.shard_chunks(n, ndev, cap, 64):
+                assert size in warmed, (n, size)
 
 
 class TestShardPlan:
@@ -232,9 +286,11 @@ class TestDispatchShardedParity:
                     _mod3_kernel, packed, 1500, max_chunk=64, min_pad=64,
                     topology=topo,
                 )
-        # mega-chunk = 64 lanes/shard * 8 shards = 512: chunks 0 and 1
-        # packed, the cancel fired before chunk 2 was ever packed
-        assert packs == [(0, 512), (512, 1024)]
+        # chunks 0 and 1 of the rounding rule's packed, the cancel fired
+        # before chunk 2 was ever packed
+        want = mesh.shard_chunks(1500, 8, 64, 64)
+        assert len(want) > 2
+        assert packs == [(s, e) for s, e, _ in want[:2]]
 
 
 class TestWarmBootZeroMiss:
