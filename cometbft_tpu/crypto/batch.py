@@ -320,7 +320,7 @@ def resident_commit_eligible(
 
 def verify_commit_valset(
     pub_keys: List[bytes],
-    msgs: List[Optional[bytes]],
+    msgs,
     sigs: List[Optional[bytes]],
     backend: Backend = None,
 ) -> Optional[List[bool]]:
@@ -328,7 +328,10 @@ def verify_commit_valset(
     pubkey rows live on device across heights — ed25519_batch's
     verify_valset_resident). Returns a per-lane mask, or None when the
     shape is ineligible and the caller should fall back to the
-    add()/verify() protocol.
+    add()/verify() protocol. ``msgs`` is one entry per validator, or a
+    callable ``(start, end) -> msgs[start:end]`` that builds a launch's
+    messages when that launch is next (then ``sigs`` says which lanes
+    are present).
 
     Eligibility: the tpu backend is selected and the PRESENT lane
     count clears the ed25519 routing floor (below
@@ -341,7 +344,8 @@ def verify_commit_valset(
     import hashlib
 
     with tracelib.stage("commit.valset_id"):
-        present = sum(1 for m in msgs if m is not None)
+        lanes = sigs if callable(msgs) else msgs
+        present = sum(1 for m in lanes if m is not None)
         spec = unwrap_backend(backend)
         spec_floor = spec.min_batch if isinstance(spec, BackendSpec) else None
         if present < ed25519_routing_floor(spec_floor):

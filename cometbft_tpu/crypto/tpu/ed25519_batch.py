@@ -420,17 +420,25 @@ verify_kernel_indexed = jax.jit(_verify_core_indexed)
 # --- host glue -------------------------------------------------------------
 
 _MIN_PAD = 64
-# Per-curve default; CBFT_TPU_MAX_CHUNK overrides it for ALL curve
-# kernels at the shared dispatch layer (mesh.chunk_cap) — the optimum is
-# link-dependent: the round-5 sweep measured 16384 as two 8192 chunks
-# SLOWER than one 8192 dispatch (9,156 vs 10,256 sigs/s), i.e. that
-# link's per-dispatch cost dominated the extra bytes, so a deployment
-# may win by raising the cap to put a mega-commit in one dispatch.
-# Device-memory bound: a 16384-lane chunk's Straus tables are ~70 MB —
-# comfortable in 16 GB HBM.
+# Per-curve default of a launch's lanes IN TOTAL; [crypto] max_chunk and
+# CBFT_TPU_MAX_CHUNK override it for ALL curve kernels at the shared
+# dispatch layer (mesh.chunk_cap). On the v5e the program runs at
+# 7.3-8.5 us a lane whatever the bucket from 512 up and a launch costs
+# 0.75 ms to issue (1.75 ms over four chips), so a larger launch buys no
+# device time: two launches of 8,192 + 2,048 padded lanes beat one of
+# 16,384 by 19 ms a 10,000-lane commit on four chips (PERF.md, PR 26).
+# Device memory is no bound: HBM peaks at 211 MB of 16 GB.
 _MAX_CHUNK = 8192
-
-
+# Lanes a chip of ONE launch of the resident commit
+# (verify_valset_resident): the commit is a stream of launches whose
+# lanes are built while the launches before run, so the size trades the
+# host work in front of the first launch against the fixed cost a
+# launch (0.75 ms to issue, ~3.5 ms of each pack call). Fixed on the v5e
+# from a 10,000-lane verify_commit's median: 4,096 a chip 115.9 ms,
+# 2,048 100.5, 1,024 102.1, against 128.1 with every lane built first
+# (PERF.md, PR 27); a bucket of the warm ladder (aot.bucket_ladder) at
+# the defaults.
+_RESIDENT_LAUNCH = 2048
 
 
 def _le_words(arr_u8: np.ndarray) -> np.ndarray:
@@ -838,7 +846,8 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
     place them on device: sharded over the current shard plan's mesh
     (mesh.shard_plan: the healthy fault domains that own a chip) where
     there is one, else on the default chip. Chunks and padding are the
-    one rounding rule's (mesh.shard_chunks). Also builds the
+    one rounding rule's (mesh.shard_chunks), a chunk a launch of at most
+    _RESIDENT_LAUNCH lanes a chip within the chunk cap. Also builds the
     indexed-dispatch view (single-device only): a u8[n_pad, 32] gather
     table plus a pubkey→row index, so steady-state flushes against this
     valset ship an index vector instead of the keys."""
@@ -858,10 +867,9 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
 
     plan = mesh_mod.shard_plan()
     nsh = plan.n_shards if plan is not None else 1
+    cap = min(mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD), _RESIDENT_LAUNCH * nsh)
     chunks = []
-    for start, end, size in mesh_mod.shard_chunks(
-        n, nsh, mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD), _MIN_PAD
-    ):
+    for start, end, size in mesh_mod.shard_chunks(n, nsh, cap, _MIN_PAD):
         a_words = np.zeros((8, size), np.uint32)
         a_words[:, : end - start] = _le_words(pk_arr[start:end])
         if plan is not None:
@@ -949,21 +957,26 @@ def _prepare_rsh_compact(pk_arr: np.ndarray, msgs, sigs):
 def verify_valset_resident(
     valset_id: bytes,
     pub_keys: Sequence[bytes],
-    msgs: Sequence[Optional[bytes]],
+    msgs,
     sigs: Sequence[Optional[bytes]],
 ) -> List[bool]:
     """Full-lane commit verification against a device-resident valset.
 
     pub_keys: EVERY validator key, in valset order; msgs/sigs: one entry
     per validator, None = absent (False in the result — callers skip
-    absent lanes). valset_id must be a collision-resistant digest of the
-    ordered pub_keys (the caller computes sha256 over their
-    concatenation); the resident rows are trusted to match it.
-    Accept/reject per present lane is bit-identical to verify_batch."""
+    absent lanes). ``msgs`` is that list, or a callable
+    ``(start, end) -> msgs[start:end]`` that BUILDS the slice: it is
+    asked once a launch, in order, when that launch is next, so the
+    build runs while the device works on the launches before it.
+    valset_id must be a collision-resistant digest of the ordered
+    pub_keys (the caller computes sha256 over their concatenation); the
+    resident rows are trusted to match it. Accept/reject per present
+    lane is bit-identical to verify_batch."""
     n = len(pub_keys)
     if n == 0:
         return []
-    if len(msgs) != n or len(sigs) != n:
+    streamed = callable(msgs)
+    if len(sigs) != n or not (streamed or len(msgs) == n):
         raise ValueError("msgs/sigs must have one entry per validator")
     from collections import deque
 
@@ -1008,15 +1021,24 @@ def verify_valset_resident(
                 time.perf_counter() - t_d2h,
             )
 
-    # per-chunk packing, double-buffered like dispatch_batch: the
-    # SHA-512 hashing + async H2D of chunk i+1 overlaps the device's
-    # work on chunk i; only the per-commit rsh staging is donated —
-    # the resident pubkey rows must survive across commits
-    for start, end, size, a_dev in rv.chunks:
+    # the commit is a stream of launches: a launch's messages, SHA-512
+    # hashing and async H2D are built after the launch before it was
+    # issued, so they run behind the device's work on the launches in
+    # flight (``inflight`` on the stages: how many, as the host knows);
+    # only the per-commit rsh staging is donated — the resident pubkey
+    # rows must survive across commits
+    for chunk, (start, end, size, a_dev) in enumerate(rv.chunks):
+        if streamed:
+            with tracelib.stage("commit.msgs_chunk", chunk=chunk,
+                                lanes=end - start, inflight=len(inflight)):
+                chunk_msgs = msgs(start, end)
+        else:
+            chunk_msgs = msgs[start:end]
         t_pack = time.perf_counter()
-        with tracelib.stage("resident.pack"):
+        with tracelib.stage("resident.pack", chunk=chunk,
+                            inflight=len(inflight)):
             rsh, valid = _prepare_rsh(
-                rv.pk_arr[start:end], msgs[start:end], sigs[start:end]
+                rv.pk_arr[start:end], chunk_msgs, sigs[start:end]
             )
             rsh_pad = np.zeros((24, size), np.uint32)
             rsh_pad[:, : end - start] = rsh
@@ -1024,7 +1046,8 @@ def verify_valset_resident(
         built = build.total()
         # the issue cost: both calls return before the device is done
         with tracelib.stage("resident.launch", shards=nsh,
-                            lanes_per_shard=size // nsh):
+                            lanes_per_shard=size // nsh, chunk=chunk,
+                            inflight=len(inflight)):
             if plan is not None:
                 mask = mesh_mod.sharded_verify(
                     verify_kernel_resident, [a_dev, rsh_pad], donate_from=1,

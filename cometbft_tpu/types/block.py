@@ -276,10 +276,17 @@ class Commit:
     def vote_sign_bytes_many(
         self, chain_id: str, val_idxs: Iterable[int]
     ) -> List[bytes]:
-        """``vote_sign_bytes`` of each of ``val_idxs``, in their order,
-        from one template per block id a precommit of this commit can
-        carry (the commit's own under BLOCK_ID_FLAG_COMMIT, else the
-        zero one, built when the first such lane is met) and no Vote."""
+        """``vote_sign_bytes`` of each of ``val_idxs``, in their order
+        (``sign_bytes_builder``, asked once)."""
+        return self.sign_bytes_builder(chain_id)(val_idxs)
+
+    def sign_bytes_builder(self, chain_id: str):
+        """→ ``many(val_idxs)``: ``vote_sign_bytes`` of each of
+        ``val_idxs``, in their order, from one template per block id a
+        precommit of this commit can carry (the commit's own under
+        BLOCK_ID_FLAG_COMMIT, else the zero one, built when the first
+        such lane is met) and no Vote. The templates are built once,
+        however many slices of the commit ``many`` is asked for."""
         from cometbft_tpu.types.canonical import CanonicalVoteTemplate
         from cometbft_tpu.types.vote import SIGNED_MSG_TYPE_PRECOMMIT
 
@@ -295,16 +302,21 @@ class Commit:
         for_block = template(self.block_id)
         for_nil = None
         signatures = self.signatures
-        out = []
-        for val_idx in val_idxs:
-            cs = signatures[val_idx]
-            if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT:
-                out.append(for_block(cs.timestamp))
-            else:
-                if for_nil is None:
-                    for_nil = template(BlockID())
-                out.append(for_nil(cs.timestamp))
-        return out
+
+        def many(val_idxs: Iterable[int]) -> List[bytes]:
+            nonlocal for_nil
+            out = []
+            for val_idx in val_idxs:
+                cs = signatures[val_idx]
+                if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT:
+                    out.append(for_block(cs.timestamp))
+                else:
+                    if for_nil is None:
+                        for_nil = template(BlockID())
+                    out.append(for_nil(cs.timestamp))
+            return out
+
+        return many
 
     def validate_basic(self) -> None:
         if self.height < 0:

@@ -16,6 +16,7 @@ truncation-toward-zero integer division.
 
 from __future__ import annotations
 
+from bisect import bisect_left
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -282,7 +283,9 @@ class ValidatorSet:
         (crypto/batch.py verify_commit_valset — the valset's pubkey rows
         stay on device across heights) when the whole set is ed25519 and
         the backend/shape is eligible; otherwise the add()/verify()
-        protocol. Accept/reject is identical either way."""
+        protocol. Accept/reject is identical either way. ``lane_msgs``
+        is one entry per validator, or verify_commit's per-launch source
+        (_stream_lane_msgs)."""
         if not entries:
             return []
         from cometbft_tpu.crypto import ed25519 as ed
@@ -303,6 +306,8 @@ class ValidatorSet:
                 )
                 if full is not None:
                     return [bool(full[e[0]]) for e in entries]
+        if callable(lane_msgs):  # a streamed commit the device path refused
+            lane_msgs = lane_msgs(0, self.size())
         bv = cryptobatch.new_batch_verifier(backend)
         for e in entries:
             idx = e[0]
@@ -342,7 +347,12 @@ class ValidatorSet:
                 idxs.append(idx)
                 lane_sigs[idx] = cs.signature
                 entries.append((idx, val, cs.for_block()))
-            _fill_lane_msgs(span, commit, chain_id, idxs, lane_msgs, idxs)
+            if cryptobatch.resident_commit_eligible(len(entries), backend):
+                # the resident path builds a launch's messages when that
+                # launch is next, behind the device's work on the one before
+                lane_msgs = _stream_lane_msgs(span, commit, chain_id, idxs)
+            else:
+                _fill_lane_msgs(span, commit, chain_id, idxs, lane_msgs, idxs)
         mask = self._verify_lanes(lane_msgs, lane_sigs, entries, backend)
         with tracelib.stage("commit.tally"):
             tallied = 0
@@ -537,6 +547,33 @@ def _fill_lane_msgs(
     ``commit.sign_bytes`` stage's, is tagged with what was built."""
     for lane, msg in zip(lanes, commit.vote_sign_bytes_many(chain_id, idxs)):
         lane_msgs[lane] = msg
+    _tag_sign_bytes(span, commit, idxs)
+
+
+def _stream_lane_msgs(span, commit: Commit, chain_id: str, idxs: List[int]):
+    """→ ``msgs(start, end)``: the sign-bytes of lanes [start, end) of a
+    full commit (lane = commit signature index; ``idxs``, ascending, the
+    present ones), None where the lane is absent; the slices come from
+    the commit's one or two templates, built once
+    (Commit.sign_bytes_builder). ``span`` is tagged as _fill_lane_msgs
+    tags it, with what the slices will add up to."""
+    many = commit.sign_bytes_builder(chain_id)
+
+    def msgs(start: int, end: int) -> list:
+        present = idxs[bisect_left(idxs, start):bisect_left(idxs, end)]
+        built = many(present)
+        if len(present) == end - start:
+            return built
+        out = [None] * (end - start)
+        for idx, msg in zip(present, built):
+            out[idx - start] = msg
+        return out
+
+    _tag_sign_bytes(span, commit, idxs)
+    return msgs
+
+
+def _tag_sign_bytes(span, commit: Commit, idxs: List[int]) -> None:
     if not span.noop:
         all_for_block = all(commit.signatures[i].for_block() for i in idxs)
         span.set_tag("lanes", len(idxs))

@@ -144,10 +144,14 @@ def test_launch_and_retire_carry_shards_and_lanes_per_shard(plane):
         _verify(pks, msgs, sigs)
     root.end()
     spans = tracer.recent()[0]["spans"]
-    for name in ("resident.launch", "resident.retire"):
-        tags = [s["tags"] for s in spans if s["name"] == name]
-        assert tags == [{"shards": SHARDS, "lanes_per_shard": 32},
-                        {"shards": SHARDS, "lanes_per_shard": 8}], name
+    sharded = [{"shards": SHARDS, "lanes_per_shard": 32},
+               {"shards": SHARDS, "lanes_per_shard": 8}]
+    tags = {name: [s["tags"] for s in spans if s["name"] == name]
+            for name in ("resident.launch", "resident.retire")}
+    assert tags["resident.retire"] == sharded
+    # a launch also says which one it is and how many were in flight
+    assert tags["resident.launch"] == [
+        dict(t, chunk=k, inflight=k) for k, t in enumerate(sharded)]
 
 
 def test_a_second_sharded_commit_misses_no_executable(plane):

@@ -29,6 +29,7 @@ PREFIX = tracelib.STAGE_PREFIX
 STAGES = {
     "commit.sign_bytes": ("bench:commit", "caller"),
     "commit.valset_id": ("bench:commit", "caller"),
+    "commit.msgs_chunk": ("bench:commit", "caller"),
     "resident.pack": ("bench:commit", "caller"),
     "resident.launch": ("bench:commit", "caller"),
     "resident.retire": ("bench:commit", "caller"),
