@@ -93,7 +93,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from cometbft_tpu.crypto import qos as qoslib, wire as wirelib
+from cometbft_tpu.crypto import qos as qoslib
 from cometbft_tpu.crypto.batch import (
     BackendSpec,
     CPUBatchVerifier,
@@ -687,44 +687,22 @@ class CachingRowVerifier:
 def dispatch_rows(rows: np.ndarray) -> np.ndarray:
     """Device dispatch of concatenated compact wire columns — the
     zero-double-marshalling half of the tentpole: the u8[128, B] bytes
-    that crossed the socket are the bytes ``device_put`` here. Chunked
-    and pow2-padded exactly like the keyed single-chip loop, with every
-    chunk attributed into the wire ledger under the "service" route so
-    bytes-per-lane is provable from /debug/verify."""
-    import jax
-    import jax.numpy as jnp
-
+    that crossed the socket are the bytes ``device_put`` here. One
+    launch_stream on jax's default chip, cut and padded as the keyed
+    single-chip path's (mesh.shard_chunks), with every launch attributed
+    into the wire ledger under the "service" route so bytes-per-lane is
+    provable from /debug/verify."""
     from cometbft_tpu.crypto.tpu import ed25519_batch as ed
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
     n = int(rows.shape[1])
-    out = np.zeros(n, dtype=bool)
-    if n == 0:
-        return out
-    max_chunk = mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD)
-    ledger = wirelib.default_ledger()
-    for start in range(0, n, max_chunk):
-        end = min(start + max_chunk, n)
-        t_pack = time.perf_counter()
-        size = ed._MIN_PAD
-        while size < end - start:
-            size *= 2
-        pad = np.zeros((COMPACT_ROW_BYTES, size), np.uint8)
-        pad[:, : end - start] = rows[:, start:end]
-        t_h2d = time.perf_counter()
-        dev = jax.device_put(jnp.asarray(pad))
-        t_compute = time.perf_counter()
-        mask = mesh_mod.run_single(
-            ed.verify_kernel_compact, [dev], donate_from=0
-        )
-        t_done = time.perf_counter()
-        out[start:end] = np.asarray(mask)[: end - start]
-        if ledger is not None:
-            ledger.note_chunk(
-                "service", "dev0", size, end - start, pad.nbytes,
-                t_h2d - t_pack, t_compute - t_h2d, t_done - t_compute,
-                time.perf_counter() - t_done,
-            )
+    cap = mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD)
+    out, _ = mesh_mod.launch_stream(
+        ed.verify_kernel_compact,
+        mesh_mod.shard_chunks(n, 1, cap, ed._MIN_PAD),
+        lambda start, end: [rows[:, start:end]], n, where=None,
+        prefix="mesh", route="service", device_label="dev0",
+    )
     return out
 
 

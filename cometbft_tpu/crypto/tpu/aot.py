@@ -739,8 +739,8 @@ class ExecutableRegistry:
         device=None,
     ):
         """Run ``kernel`` on ``args`` through the registry (the
-        dispatch-layer entry — mesh.run_single / mesh.sharded_verify /
-        mesh.dispatch_sharded)."""
+        dispatch-layer entry — mesh.launch_stream, on one chip through
+        mesh.run_single)."""
         compiled = self.lookup(
             kernel, args, donate_from=donate_from, sharded=sharded,
             mesh=mesh, device=device,

@@ -978,13 +978,7 @@ def verify_valset_resident(
     streamed = callable(msgs)
     if len(sigs) != n or not (streamed or len(msgs) == n):
         raise ValueError("msgs/sigs must have one entry per validator")
-    from collections import deque
-
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
-
-    import time
-
-    from cometbft_tpu.crypto import wire as wirelib
     from cometbft_tpu.libs import trace as tracelib
 
     with tracelib.stage("commit.valset_id"):
@@ -993,77 +987,30 @@ def verify_valset_resident(
     # the mesh the rows were placed on: a launch runs where they live
     plan = rv.plan
     nsh = plan.n_shards if plan is not None else 1
-    depth = mesh_mod.pipeline_depth()
-    out = np.zeros(n, bool)
-    inflight: "deque" = deque()
+    valid = np.ones(n, bool)
+    fetched = []  # the messages of the launch that is next
+
+    def fetch(chunk, start, end, inflight):
+        with tracelib.stage("commit.msgs_chunk", chunk=chunk,
+                            lanes=end - start, inflight=inflight):
+            fetched.append(msgs(start, end))
+
+    def build(start, end):
+        rsh, valid[start:end] = _prepare_rsh(
+            rv.pk_arr[start:end],
+            fetched.pop() if streamed else msgs[start:end],
+            sigs[start:end],
+        )
+        return [rsh]
+
     # this path runs beside the scheduler (no flush, no supervisor), so
-    # the wire ledger is the only place its device lanes are on record
-    ledger = wirelib.default_ledger()
-    from cometbft_tpu.crypto.tpu import aot
-
-    build = aot.build_clock()  # a cold bucket's compile is not launch time
-
-    def retire(slot):
-        start, end, mask, valid, winfo = slot
-        size, wire_bytes, pack_s, launch_s = winfo
-        t_d2h = time.perf_counter()
-        # np.asarray blocks until the device finishes this chunk (and,
-        # sharded, gathers the mask's slices from the chips)
-        with tracelib.stage("resident.retire", shards=nsh,
-                            lanes_per_shard=size // nsh):
-            out[start:end] = (
-                np.asarray(mask)[: end - start] & valid & rv.pk_ok[start:end]
-            )
-        if ledger is not None:
-            ledger.note_chunk(
-                "resident", f"mesh:{nsh}" if nsh > 1 else "dev0", size,
-                end - start, wire_bytes, pack_s, 0.0, launch_s,
-                time.perf_counter() - t_d2h,
-            )
-
-    # the commit is a stream of launches: a launch's messages, SHA-512
-    # hashing and async H2D are built after the launch before it was
-    # issued, so they run behind the device's work on the launches in
-    # flight (``inflight`` on the stages: how many, as the host knows);
+    # the wire ledger is the only place its device lanes are on record;
     # only the per-commit rsh staging is donated — the resident pubkey
-    # rows must survive across commits
-    for chunk, (start, end, size, a_dev) in enumerate(rv.chunks):
-        if streamed:
-            with tracelib.stage("commit.msgs_chunk", chunk=chunk,
-                                lanes=end - start, inflight=len(inflight)):
-                chunk_msgs = msgs(start, end)
-        else:
-            chunk_msgs = msgs[start:end]
-        t_pack = time.perf_counter()
-        with tracelib.stage("resident.pack", chunk=chunk,
-                            inflight=len(inflight)):
-            rsh, valid = _prepare_rsh(
-                rv.pk_arr[start:end], chunk_msgs, sigs[start:end]
-            )
-            rsh_pad = np.zeros((24, size), np.uint32)
-            rsh_pad[:, : end - start] = rsh
-        t_launch = time.perf_counter()
-        built = build.total()
-        # the issue cost: both calls return before the device is done
-        with tracelib.stage("resident.launch", shards=nsh,
-                            lanes_per_shard=size // nsh, chunk=chunk,
-                            inflight=len(inflight)):
-            if plan is not None:
-                mask = mesh_mod.sharded_verify(
-                    verify_kernel_resident, [a_dev, rsh_pad], donate_from=1,
-                    mesh=plan.mesh,
-                )
-            else:
-                rsh_dev = jax.device_put(jnp.asarray(rsh_pad))
-                mask = mesh_mod.run_single(
-                    verify_kernel_resident, [a_dev, rsh_dev], donate_from=1
-                )
-        launch_s = time.perf_counter() - t_launch - (build.total() - built)
-        winfo = (size, rsh_pad.nbytes, t_launch - t_pack,
-                 max(0.0, launch_s))
-        inflight.append((start, end, mask, valid, winfo))
-        while len(inflight) > depth:
-            retire(inflight.popleft())
-    while inflight:
-        retire(inflight.popleft())
-    return list(out)
+    # rows lead the call and must survive across commits
+    out, _ = mesh_mod.launch_stream(
+        verify_kernel_resident, rv.chunks, build, n,
+        where=plan.mesh if plan is not None else None, prefix="resident",
+        route="resident", device_label=f"mesh:{nsh}" if nsh > 1 else "dev0",
+        donate_from=1, fetch=fetch if streamed else None,
+    )
+    return list(out & valid & rv.pk_ok)

@@ -418,91 +418,38 @@ def verify_batch_indexed(
     if entry is None:
         return None
 
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    from collections import deque
-
-    from cometbft_tpu.crypto import wire as wirelib
-
     idx_full = np.fromiter(
         (entry.index[_key_bytes(pk)] for pk in pub_keys),
         dtype=np.int32, count=n,
     )
-    max_chunk = mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD)
-    depth = mesh_mod.pipeline_depth()
-    out = np.zeros(n, bool)
-    inflight: "deque" = deque()
-    # per-chunk phase attribution into the wire ledger under the
-    # "indexed" route key — this is what lets the decision plane PRICE
-    # the 100 B/lane path (and the bytes_per_lane gauge prove it)
-    ledger = wirelib.default_ledger()
-    from cometbft_tpu.crypto.tpu import aot
+    valid = np.ones(n, bool)
 
-    # the indexed program compiles on first use per (table, bucket)
-    # shape; that is host time, not the route's compute
-    build = aot.build_clock()
+    def build(start, end):
+        rsh, valid[start:end] = ed._prepare_rsh_compact(
+            np.stack([
+                np.frombuffer(_key_bytes(pk), np.uint8) for pk in
+                pub_keys[start:end]
+            ]),
+            msgs[start:end], sigs[start:end],
+        )
+        return [idx_full[start:end], rsh]  # 100 B per padded lane
 
-    def retire(slot):
-        start, end, mask, valid, winfo = slot
-        t_d2h = time.perf_counter()
-        out[start:end] = np.asarray(mask)[: end - start] & valid
-        if ledger is not None and winfo is not None:
-            size, wire_bytes, pack_s, h2d_s, compute_s = winfo
-            ledger.note_chunk(
-                "indexed", "dev0", size, end - start, wire_bytes,
-                pack_s, h2d_s, compute_s,
-                time.perf_counter() - t_d2h,
-            )
-
-    # same double-buffered shape as the resident commit loop: pack +
-    # async H2D of chunk i+1 overlaps the device's work on chunk i.
-    # Only the per-flush staging (idx + rsh) is donated — the resident
-    # table must survive across flushes. The entry is PINNED for the
-    # whole chunk loop: per-height valset rotation would otherwise LRU-
-    # evict the incoming table mid-flush (churn thrash) and force the
-    # next flush to re-upload what this one was still gathering from.
+    cap = mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD)
+    # The table leads every launch and must survive across flushes: only
+    # the per-flush staging (idx + rsh) is donated. The wire ledger's
+    # "indexed" route is what lets the decision plane PRICE the
+    # 100 B/lane path (and the bytes_per_lane gauge prove it). The entry
+    # is PINNED for the whole stream: per-height valset rotation would
+    # otherwise LRU-evict the incoming table mid-flush (churn thrash)
+    # and force the next flush to re-upload what this one was still
+    # gathering from.
     with _default.pinned(entry.valset_id):
-        for start in range(0, n, max_chunk):
-            end = min(start + max_chunk, n)
-            t_pack = time.perf_counter()
-            rsh, valid = ed._prepare_rsh_compact(
-                np.stack([
-                    np.frombuffer(_key_bytes(pk), np.uint8) for pk in
-                    pub_keys[start:end]
-                ]),
-                msgs[start:end], sigs[start:end],
-            )
-            size = ed._MIN_PAD
-            while size < end - start:
-                size *= 2
-            rsh_pad = np.zeros((96, size), np.uint8)
-            rsh_pad[:, : end - start] = rsh
-            idx_pad = np.zeros(size, np.int32)
-            idx_pad[: end - start] = idx_full[start:end]
-            t_h2d = time.perf_counter()
-            idx_dev = jax.device_put(jnp.asarray(idx_pad))
-            rsh_dev = jax.device_put(jnp.asarray(rsh_pad))
-            t_compute = time.perf_counter()
-            built = build.total()
-            mask = mesh_mod.run_single(
-                ed.verify_kernel_indexed,
-                [entry.table_dev, idx_dev, rsh_dev],
-                donate_from=1,
-            )
-            t_done = time.perf_counter() - (build.total() - built)
-            winfo = (
-                size,
-                rsh_pad.nbytes + idx_pad.nbytes,  # 100 B per padded lane
-                t_h2d - t_pack,
-                t_compute - t_h2d,
-                max(0.0, t_done - t_compute),
-            )
-            inflight.append((start, end, mask, valid, winfo))
-            while len(inflight) > depth:
-                retire(inflight.popleft())
-        while inflight:
-            retire(inflight.popleft())
+        out, _ = mesh_mod.launch_stream(
+            ed.verify_kernel_indexed,
+            [(start, end, size, entry.table_dev) for start, end, size in
+             mesh_mod.shard_chunks(n, 1, cap, ed._MIN_PAD)],
+            build, n, where=None, prefix="mesh", route="indexed",
+            device_label="dev0", donate_from=1,
+        )
     _default.note_indexed(n)
-    return list(out)
+    return list(out & valid)

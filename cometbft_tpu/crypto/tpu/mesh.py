@@ -13,13 +13,16 @@ input) across whatever devices are visible:
   ICI within a host and DCN across hosts. No NCCL/MPI: collectives are
   compiled into the program.
 
-`sharded_verify` is used by TPUBatchVerifier automatically whenever
-more than one device is visible; on one device it is jit-identical to
-the plain kernel.
+Every device dispatch is one `launch_stream`: `dispatch_batch` (one
+chip, or every visible device when nothing placed the batch),
+`dispatch_sharded` (the healthy fault domains' sub-mesh) and the
+resident, indexed and service entries that call it with rows of their
+own.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -433,31 +436,6 @@ def pipeline_depth() -> int:
     return depth
 
 
-def prefetch_depth() -> int:
-    """How many chunks ahead of the compute pointer the dispatch loops
-    STAGE (pack + async device_put). 1 = designed double-buffering of
-    the wire itself: the next chunk's H2D is issued before the current
-    chunk's compute is even enqueued, so on a transfer-bound link
-    (~181 ms H2D vs ~0.1 ms compute per 16k chunk,
-    BENCH_onchip_probe.json) the transfer of chunk i+1 runs behind the
-    device's work on chunk i by construction, not by dispatch-queue
-    accident. 0 restores the lazy pre-PR-13 behavior (stage only the
-    chunk about to launch); deeper prefetch costs one chunk of staging
-    memory per step and buys nothing once the link is saturated."""
-    raw = os.environ.get("CBFT_TPU_PREFETCH_DEPTH")
-    if raw is None:
-        return 1
-    try:
-        depth = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"CBFT_TPU_PREFETCH_DEPTH={raw!r} is not an integer"
-        ) from None
-    if depth < 0:
-        raise ValueError(f"CBFT_TPU_PREFETCH_DEPTH={depth} must be >= 0")
-    return depth
-
-
 def placement(handle):
     """The jax device a topology.DeviceHandle stands for, or None when
     the handle has no chip of its own: the single-domain topology (jax's
@@ -475,8 +453,7 @@ def run_single(kernel, args, donate_from: int = 0, device=None):
     """Run `kernel` single-device through the AOT executable registry
     with args [donate_from:] donated — the per-chunk staging buffers
     are single-use, so XLA reuses their space instead of holding input
-    + workspace live together (same rationale as sharded_verify's
-    donate_argnums). The registry (crypto/tpu/aot.py) keys by stable
+    + workspace live together (matters at the 8k-lane chunks). The registry (crypto/tpu/aot.py) keys by stable
     kernel name + exact arg shapes + fingerprints — never by id(), which
     CPython reuses after GC — and is what warm boot pre-populates, so a
     warmed bucket never pays trace+compile here. ``device`` (a jax
@@ -490,12 +467,248 @@ def run_single(kernel, args, donate_from: int = 0, device=None):
     )
 
 
+def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
+                  route: str, device_label: str, domains=(),
+                  donate_from: int = 0, fetch=None):
+    """THE loop from "here are lanes" to "here is the mask": every device
+    dispatch of the crypto plane (dispatch_batch, dispatch_sharded, the
+    resident commit, the indexed key store, verifyd's rows) is this
+    stream with its own inputs. → (mask bool[n], the call's phase
+    totals: note_dispatch's keyword arguments).
+
+    ``launches`` is the one rounding rule's [(start, end, size, *lead)]
+    (shard_chunks): lanes [start, end) of the batch padded to ``size``;
+    ``lead`` are arguments already on the device that lead the kernel
+    call and outlive it (the resident pubkey rows, the indexed table), so
+    ``donate_from`` counts past them. ``build(start, end)`` gives the
+    launch's host arrays, trailing axis = its real lanes; it is asked
+    once a launch, in order, when that launch is next, and which lanes it
+    found malformed stays its caller's business. ``fetch(chunk, start,
+    end, inflight)``, where given, gathers what build will pack and is
+    no packing itself (a commit's sign-bytes, under a stage of their
+    own). ``where`` is the placement: a jax Mesh (arguments sharded on
+    the trailing axis, the sharded executable) or one jax device / None
+    for jax's default (run_single). ``domains`` are the labels of the
+    fault domains the lanes are attributed to, in shard order: a shard
+    span and a telemetry-hub row each, and the wire ledger's bucket is
+    the lanes of one (none given: of the launch).
+
+    One order of work: build launch k, issue it (device_put and the call
+    both return before the device is done), retire the oldest beyond
+    pipeline_depth(), then build launch k+1. So a launch's host work and
+    H2D run behind the device's work on the launches in flight; on the
+    v5e this order beat building every lane first 100.48 to 128.12 ms a
+    10,000-lane commit (PERF.md, PR 27). The thread's cancel event is
+    checked at every launch boundary; a launch that fails says which it
+    was and which lanes it held."""
+    from collections import deque
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from cometbft_tpu.crypto import telemetry as _telemetry
+    from cometbft_tpu.crypto import wire as _wirelib
+    from cometbft_tpu.crypto.tpu import aot
+
+    if isinstance(where, Mesh):
+        nsh = int(where.devices.size)
+        registry = aot.default_registry()
+
+        def put(a):
+            # numpy rows go host -> each shard's chip; jnp.asarray first
+            # would land the whole launch on chip 0 and make the
+            # placement a chip-to-chip copy
+            return jax.device_put(a, NamedSharding(
+                where, PS(*([None] * (a.ndim - 1) + ["batch"]))
+            ))
+
+        def call(args):
+            return registry.call(
+                kernel, args, donate_from=donate_from, sharded=True,
+                mesh=where,
+            )
+    else:
+        nsh = 1
+
+        def put(a):
+            return jax.device_put(jnp.asarray(a), where)
+
+        def call(args):
+            return run_single(
+                kernel, args, donate_from=donate_from, device=where
+            )
+
+    hub = _telemetry.default_hub()
+    ledger = _wirelib.default_ledger()
+    depth = pipeline_depth()
+    cancel = current_cancel_event()
+    # an executable a launch has to build first (registry miss) is host
+    # work: its seconds are left out of the compute phase and of the wall
+    # the ledger prices routes with
+    clock = aot.build_clock()
+    built0 = clock.total()
+    t_wall0 = time.perf_counter()
+    tot = {"wall_s": 0.0, "pack_s": 0.0, "h2d_s": 0.0, "compute_s": 0.0,
+           "d2h_s": 0.0, "hidden_s": 0.0, "wire_bytes": 0, "chunks": 0}
+    out = np.zeros(n, bool)
+    inflight: "deque" = deque()
+    n_domains = max(1, len(domains))
+
+    @contextlib.contextmanager
+    def launch_context(what, chunk, start, end, span, shard_spans):
+        """Ends the launch's spans with the error and says which launch
+        it was; a cancellation passes through as it is."""
+        try:
+            yield
+        except Exception as exc:  # noqa: BLE001 - per-launch context for triage
+            cancelled = isinstance(exc, DispatchCancelled)
+            for s in (*shard_spans, span):
+                s.end(error="cancelled" if cancelled else repr(exc))
+            if cancelled:
+                raise
+            raise RuntimeError(
+                f"{route} {what} of chunk {chunk} (sigs [{start}:{end}]) "
+                f"on {device_label} failed: {exc}"
+            ) from exc
+
+    def retire(slot):
+        chunk, start, end, size, mask, span, shard_spans, phases = slot
+        # np.asarray blocks until the device finishes this launch (and,
+        # sharded, gathers the mask's slices from the chips): the wait
+        # measured here IS the device-time attribution of the span
+        t_dev = time.perf_counter_ns()
+        with launch_context("retire", chunk, start, end, span, shard_spans), \
+                _trace.stage(prefix + ".retire", span=span.child(
+                    prefix + ".retire", shards=nsh,
+                    lanes_per_shard=size // nsh,
+                )):
+            out[start:end] = np.asarray(mask)[: end - start]
+        wait_ns = time.perf_counter_ns() - t_dev
+        tot["d2h_s"] += wait_ns / 1e9
+        if ledger is not None:
+            ledger.note_chunk(
+                route, device_label, size // n_domains, end - start,
+                d2h_s=wait_ns / 1e9, padded_lanes=size, **phases,
+            )
+        for s in (*shard_spans, span):
+            s.end(device_wait_ns=wait_ns)
+
+    def drain(keep):
+        while len(inflight) > keep:
+            retire(inflight.popleft())
+
+    def issue(chunk, start, end, size, lead):
+        """Builds and issues one launch -> its in-flight slot. A function
+        of its own, so that the launch's staging (the built and padded
+        host arrays, the donated device buffers) is released when it
+        returns, while the device works, and not after the last retire
+        (kept until then, qa150-blocksync read 3 % slower: PERF.md, PR 28)."""
+        real = end - start
+        flying = len(inflight)
+        span = _trace.child_of_current(
+            "chunk", chunk=chunk, n_sigs=real, shards=nsh
+        )
+        with launch_context("dispatch", chunk, start, end, span, ()):
+            if fetch is not None:
+                fetch(chunk, start, end, flying)
+            t_host = time.perf_counter_ns()
+            with _trace.stage(prefix + ".pack", span=span.child(
+                prefix + ".pack", chunk=chunk, inflight=flying,
+            )):
+                padded = []
+                for a in build(start, end):
+                    p = np.zeros(a.shape[:-1] + (size,), a.dtype)
+                    p[..., :real] = a
+                    padded.append(p)
+            t_pack = time.perf_counter_ns()
+            built = clock.total()
+            # the ISSUE cost: both calls return before the device is done;
+            # only the launch's own staging is donated
+            with _trace.stage(prefix + ".launch", span=span.child(
+                prefix + ".launch", shards=nsh, lanes_per_shard=size // nsh,
+                chunk=chunk, inflight=flying,
+            )):
+                placed = [put(p) for p in padded]
+                t_h2d = time.perf_counter_ns()
+                mask = call(lead + placed)
+            t_call = time.perf_counter_ns()
+        # attribution comes after the issue: nothing the device does not
+        # need stands between a launch's pack and its start
+        per = size // n_domains
+        shard_spans = []
+        for si, label in enumerate(domains):
+            lanes = max(0, min(per, real - si * per))
+            shard_spans.append(span.child(
+                "shard", device=label, shard=si, n_sigs=lanes, pad=per,
+            ))
+            if hub is not None:
+                hub.note_chunk(label, lanes, per)
+        h2d_s = (t_h2d - t_pack) / 1e9
+        phases = {
+            "pack_s": (t_pack - t_host) / 1e9,
+            "h2d_s": h2d_s,
+            "compute_s": max(
+                0.0, (t_call - t_h2d) / 1e9 - (clock.total() - built)
+            ),
+            # a transfer issued while an earlier launch was in flight paid
+            # no wall time of its own (only launch 0's H2D is exposed)
+            "hidden_s": h2d_s if flying else 0.0,
+            "wire_bytes": sum(int(p.nbytes) for p in padded),
+        }
+        for key, v in phases.items():
+            tot[key] += v
+        tot["chunks"] += 1
+        span.set_tag("pad", size)
+        span.set_tag("wire_bytes", phases["wire_bytes"])
+        for key in ("pack_s", "h2d_s", "compute_s", "hidden_s"):
+            span.set_tag(key[:-2] + "_ns", int(phases[key] * 1e9))
+        # host time of the launch: pack + H2D issue + kernel issue
+        span.set_tag("host_ns", int(
+            (phases["pack_s"] + h2d_s + phases["compute_s"]) * 1e9
+        ))
+        return chunk, start, end, size, mask, span, shard_spans, phases
+
+    for chunk, (start, end, size, *lead) in enumerate(launches):
+        if cancel is not None and cancel.is_set():
+            raise DispatchCancelled(
+                f"{route} dispatch cancelled before chunk {chunk} "
+                f"(sigs [{start}:{n}] undone)"
+            )
+        inflight.append(issue(chunk, start, end, size, lead))
+        drain(depth)
+    drain(0)
+    tot["wall_s"] = max(
+        0.0, time.perf_counter() - t_wall0 - (clock.total() - built0)
+    )
+    return out, tot
+
+
+def _slices_of(packed):
+    """dispatch_batch's ``packed`` as the stream's ``build``: the callable
+    itself, or slices of the pre-packed arrays' trailing axis."""
+    if callable(packed):
+        return packed
+    return lambda start, end: [a[..., start:end] for a in packed]
+
+
+def _note_dispatch(route: str, device_label: str, n: int, tot: dict) -> None:
+    """The per-call row of a mesh entry in the wire ledger."""
+    from cometbft_tpu.crypto import wire as _wirelib
+
+    ledger = _wirelib.default_ledger()
+    if ledger is not None and tot["chunks"]:
+        ledger.note_dispatch(route, device_label, n, **tot)
+
+
 def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
                    device=None):
-    """Shared chunk-pad-dispatch loop for batch verify kernels (used by
-    all three curve entries): pads each chunk's trailing batch axis to a
-    power of two (rounded to equal per-device shards), shards over the
-    mesh when >1 device is visible, and gathers the boolean masks.
+    """Chunk-pad-dispatch of a batch verify kernel (used by all three
+    curve entries): cuts the batch into launches of at most the chunk
+    cap, pads each launch's trailing batch axis to a power of two
+    (shard_chunks), runs them as one launch_stream and gathers the
+    boolean masks.
 
     ``device`` is an optional topology.DeviceHandle naming the fault
     domain this dispatch runs against; when omitted the thread's
@@ -505,28 +718,13 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
     of a detected mesh, the chip the chunks are placed and run on
     (placement) — so a fault domain's breaker judges its own chip.
 
-    Double-buffered twice over: at most pipeline_depth() (default 2)
-    chunk dispatches are in flight before the OLDEST is retired
-    (np.asarray blocks only on it), and staging runs prefetch_depth()
-    (default 1) chunks AHEAD of the compute pointer — chunk N+1's pack
-    and async device_put are issued before chunk N's compute is
-    enqueued, so the transfer overlaps compute by construction.
-    Transfer dominated the round-5 shared chip (~180 ms of a ~216 ms 16k
-    dispatch, MAXCHUNK16K.jsonl), so the overlap was the whole win; the two bounds
-    keep staging memory at (depth + prefetch) × chunk wire instead of
-    the full batch. Single-device dispatches donate their staging buffers
-    (donating_kernel); the sharded path already does.
-
     `packed` is either a list of pre-packed arrays (trailing axis = the
     full batch) or a callable ``(start, end) -> list`` producing one
     chunk's arrays on demand — the callable form lets the caller's host
     packing (SHA-512 hashing, merlin transcripts, scalar inversions) for
     chunk i+1 overlap the device's transfer+compute of chunk i, since
-    jax dispatch returns before the result is ready."""
-    from collections import deque
-
-    import numpy as np
-
+    jax dispatch returns before the result is ready. Staging buffers are
+    donated."""
     route = current_route()
     if route == ROUTE_SHARDED:
         plan = shard_plan()
@@ -563,223 +761,21 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
         max_chunk = device.chunk_cap(max_chunk, min_pad)
     else:
         max_chunk = chunk_cap(max_chunk, min_pad)
-    # capacity telemetry: real lanes vs padded pow2-bucket lanes per
-    # chunk feed the hub's lane-fill efficiency (no hub installed =
-    # one attribute read per batch). Device-less dispatches account
-    # against the module shim's device 0, matching the chunk-cap shim.
-    from cometbft_tpu.crypto import telemetry as _telemetry
-    from cometbft_tpu.crypto import wire as _wirelib
-
-    _hub = _telemetry.default_hub()
-    _ledger = _wirelib.default_ledger()
     _dev_label = device.label if device is not None else "dev0"
     # ROUTE_SINGLE pins the program to one chip even when a mesh is
     # visible (the scheduler's below-crossover rung), and so does a fault
-    # domain that owns a chip; otherwise the legacy auto-shard-over-
-    # everything behavior.
-    jax_dev = placement(device)
-    ndev = 1 if (route == ROUTE_SINGLE or jax_dev is not None) \
-        else n_devices()
-    # wire-ledger route key: the legacy auto-shard path (>1 device, no
-    # installed route) keeps its own label because its phase split is
-    # coarser — the device_put happens inside sharded_verify, so h2d
-    # folds into compute there.
-    _wire_route = ROUTE_SINGLE if ndev == 1 else "auto"
-    depth = pipeline_depth()
-    out = np.zeros(n, bool)
-    inflight: "deque" = deque()
-    cancel = current_cancel_event()
-    t_wall0 = time.perf_counter()
-    # an executable this dispatch has to build first (registry miss) is
-    # host work: its seconds are left out of the compute phase and the
-    # wall the ledger prices routes with
-    from cometbft_tpu.crypto.tpu import aot as _aot
-
-    _build = _aot.build_clock()
-    _built0 = _build.total()
-    # per-dispatch phase totals (seconds); d2h accumulates in retire
-    _tot = {"pack": 0.0, "h2d": 0.0, "compute": 0.0, "d2h": 0.0,
-            "hidden": 0.0, "bytes": 0, "chunks": 0}
-
-    def retire(slot):
-        chunk_idx, start, end, mask, span, winfo = slot
-        # np.asarray blocks until the device finishes this chunk — the
-        # wait measured here IS the device-time attribution for the span
-        # (host work for the chunk already happened before dispatch).
-        t_dev = time.perf_counter_ns()
-        try:
-            with _trace.stage("mesh.retire", span=span.child("mesh.retire")):
-                out[start:end] = np.asarray(mask)[: end - start]
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - device died mid-retire
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"retire of chunk {chunk_idx} (sigs [{start}:{end}]) "
-                f"failed: {exc}"
-            ) from exc
-        wait_ns = time.perf_counter_ns() - t_dev
-        d2h_s = wait_ns / 1e9
-        _tot["d2h"] += d2h_s
-        if _ledger is not None and winfo is not None:
-            size, wire_bytes, pack_s, h2d_s, compute_s, hidden_s = winfo
-            _ledger.note_chunk(
-                _wire_route, _dev_label, size, end - start, wire_bytes,
-                pack_s, h2d_s, compute_s, d2h_s, hidden_s=hidden_s,
-            )
-        span.end(device_wait_ns=wait_ns)
-
-    # staged prefetch (PR 13): pack + async device_put run up to
-    # prefetch_depth() chunks AHEAD of the compute pointer, so the next
-    # chunk's H2D is on the wire before the current chunk's compute is
-    # even enqueued — transfer/compute overlap by construction. A staged
-    # chunk's transfer is "hidden" whenever other work was staged or in
-    # flight when it was issued (only chunk 0's H2D is exposed).
-    total_chunks = -(-n // max_chunk) if n > 0 else 0
-    prefetch = prefetch_depth()
-    staged: "deque" = deque()
-    next_stage = 0
-
-    def stage_next():
-        nonlocal next_stage
-        chunk_idx = next_stage
-        next_stage += 1
-        start = chunk_idx * max_chunk
-        end = min(start + max_chunk, n)
-        span = _trace.child_of_current(
-            "chunk", chunk=chunk_idx, n_sigs=end - start
-        )
-        overlapped = len(inflight) > 0 or len(staged) > 0
-        t_host = time.perf_counter_ns()
-        try:
-            with _trace.stage("mesh.pack", span=span.child("mesh.pack")):
-                if callable(packed):
-                    chunk = packed(start, end)
-                else:
-                    chunk = [a[..., start:end] for a in packed]
-                size = shard_bucket(end - start, ndev, min_pad)
-
-                def pad(a):
-                    padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
-                    padded[..., : end - start] = a
-                    return padded
-
-                padded_args = [pad(a) for a in chunk]
-            t_pack = time.perf_counter_ns()
-            wire_bytes = sum(int(a.nbytes) for a in padded_args)
-            if ndev > 1:
-                # legacy auto-shard path: the device_put happens inside
-                # sharded_verify at launch, so there is no separable
-                # h2d window — staging ends at pack
-                placed = padded_args
-                t_h2d = t_pack
-            else:
-                import jax
-                import jax.numpy as jnp
-
-                # explicit async device_put at STAGE time: H2D for this
-                # chunk is issued before earlier chunks' compute has
-                # drained; the launch then consumes already-placed
-                # (donated) buffers
-                with _trace.stage(
-                    "mesh.launch", span=span.child("mesh.launch")
-                ):
-                    placed = [
-                        jax.device_put(jnp.asarray(a), jax_dev)
-                        for a in padded_args
-                    ]
-                t_h2d = time.perf_counter_ns()
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - per-chunk context for triage
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"staging of chunk {chunk_idx} (sigs [{start}:{end}]) "
-                f"failed: {exc}"
-            ) from exc
-        pack_s = (t_pack - t_host) / 1e9
-        h2d_s = (t_h2d - t_pack) / 1e9
-        staged.append((chunk_idx, start, end, size, placed, span,
-                       wire_bytes, pack_s, h2d_s, overlapped))
-
-    def launch(slot):
-        (chunk_idx, start, end, size, placed, span, wire_bytes,
-         pack_s, h2d_s, overlapped) = slot
-        t_launch = time.perf_counter_ns()
-        built = _build.total()
-        try:
-            with _trace.stage(
-                "mesh.launch", span=span.child("mesh.launch")
-            ):
-                if ndev > 1:
-                    mask = sharded_verify(kernel, placed)
-                else:
-                    mask = run_single(kernel, placed, device=jax_dev)
-            t_compute = time.perf_counter_ns()
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - per-chunk context for triage
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"dispatch of chunk {chunk_idx} (sigs [{start}:{end}]) "
-                f"failed: {exc}"
-            ) from exc
-        compute_s = max(
-            0.0, (t_compute - t_launch) / 1e9 - (_build.total() - built)
-        )
-        hidden_s = h2d_s if overlapped else 0.0
-        # host wall time: pack + pad + H2D issue + jit dispatch (returns
-        # before the device result is ready); staged wait time excluded
-        span.set_tag(
-            "host_ns", int((pack_s + h2d_s + compute_s) * 1e9)
-        )
-        span.set_tag("pad", size)
-        span.set_tag("pack_ns", int(pack_s * 1e9))
-        span.set_tag("h2d_ns", int(h2d_s * 1e9))
-        span.set_tag("compute_ns", int(compute_s * 1e9))
-        span.set_tag("hidden_ns", int(hidden_s * 1e9))
-        span.set_tag("wire_bytes", wire_bytes)
-        _tot["pack"] += pack_s
-        _tot["h2d"] += h2d_s
-        _tot["compute"] += compute_s
-        _tot["hidden"] += hidden_s
-        _tot["bytes"] += wire_bytes
-        _tot["chunks"] += 1
-        if _hub is not None:
-            _hub.note_chunk(_dev_label, end - start, size)
-        winfo = (
-            (size, wire_bytes, pack_s, h2d_s, compute_s, hidden_s)
-            if _ledger is not None else None
-        )
-        inflight.append((chunk_idx, start, end, mask, span, winfo))
-
-    for chunk_idx in range(total_chunks):
-        if cancel is not None and cancel.is_set():
-            raise DispatchCancelled(
-                f"dispatch cancelled before chunk {chunk_idx} "
-                f"(sigs [{chunk_idx * max_chunk}:{n}] undone)"
-            )
-        while (next_stage < total_chunks
-               and next_stage <= chunk_idx + prefetch):
-            stage_next()
-        launch(staged.popleft())
-        while len(inflight) > depth:
-            retire(inflight.popleft())
-    while inflight:
-        retire(inflight.popleft())
-    if _ledger is not None and _tot["chunks"]:
-        _ledger.note_dispatch(
-            _wire_route, _dev_label, n,
-            wall_s=max(0.0, time.perf_counter() - t_wall0
-                       - (_build.total() - _built0)),
-            pack_s=_tot["pack"], h2d_s=_tot["h2d"],
-            compute_s=_tot["compute"], d2h_s=_tot["d2h"],
-            hidden_s=_tot["hidden"], wire_bytes=_tot["bytes"],
-            chunks=_tot["chunks"],
-        )
+    # domain that owns a chip. With neither, and more than one device
+    # visible, the batch goes over the full mesh with no shard plan: its
+    # own ledger label, its lanes booked to the one fault domain there is.
+    where, nsh, _wire_route = placement(device), 1, ROUTE_SINGLE
+    if route != ROUTE_SINGLE and where is None and n_devices() > 1:
+        where, nsh, _wire_route = batch_mesh(), n_devices(), "auto"
+    out, tot = launch_stream(
+        kernel, shard_chunks(n, nsh, max_chunk, min_pad), _slices_of(packed),
+        n, where=where, prefix="mesh", route=_wire_route,
+        device_label=_dev_label, domains=(_dev_label,),
+    )
+    _note_dispatch(_wire_route, _dev_label, n, tot)
     if _plane is not None and n > 0:
         # post-dispatch model correction: the observed allocation peak
         # over the pre-dispatch baseline calibrates the per-(kernel,
@@ -813,11 +809,12 @@ def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int):
     8,192 + 2,048 padded lanes in two launches, 2,048 and 512 a chip.
 
     The resident commit (ed25519_batch._build_resident), dispatch_batch,
-    dispatch_sharded and warm boot (aot.warmup_plan, through
-    shard_bucket) all round here, so a warmed ladder covers every
-    shape a dispatch can produce: the zero-compiles-after-warm
-    guarantee rests on there being one rule. With one shard it is the
-    single-device rule (pow2 bucket, chunks of ``cap``)."""
+    dispatch_sharded, the indexed key store, verifyd's rows and warm
+    boot (aot.warmup_plan, through shard_bucket) all round here, so a
+    warmed ladder covers every shape a dispatch can produce: the
+    zero-compiles-after-warm guarantee rests on there being one rule.
+    With one shard it is the single-device rule (pow2 bucket, chunks of
+    ``cap``)."""
     n_shards = max(1, int(n_shards))
     cap = max(1, int(cap))
     out = []
@@ -919,21 +916,17 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
     axis, limbs replicated).
 
     Same contract as dispatch_batch — ``packed`` is pre-packed arrays or
-    a ``(start, end) -> list`` callable, the thread's cancel event is
-    checked at every chunk boundary, chunks are double-buffered
-    (pipeline_depth) with staging prefetched ahead of compute
-    (prefetch_depth), staging buffers are donated — plus the sharded
-    specifics: a launch's lane count IN TOTAL is capped by the MINIMUM
-    chunk cap over the participating devices (each device's OOM-shrink
-    ladder and memory-plane guard clamp it), chunks and padding follow
-    the one rounding rule (shard_chunks), and per-shard child spans
-    attribute the work to each fault domain. Quarantined domains are
-    excluded by the ShardPlan; a topology generation bump re-slices on
-    the next call."""
-    from collections import deque
-
-    import numpy as np
-
+    a ``(start, end) -> list`` callable, one launch_stream, staging
+    buffers donated — plus the sharded specifics: a launch's lane count
+    IN TOTAL is capped by the MINIMUM chunk cap over the participating
+    devices (each device's OOM-shrink ladder and memory-plane guard clamp
+    it), chunks and padding follow the one rounding rule (shard_chunks),
+    and per-shard child spans attribute the work to each fault domain.
+    The wire ledger buckets sharded work by the per-shard lane count and
+    labels the whole mesh as one "device": the link is what the ledger
+    models, and all shards ride the same host egress. Quarantined
+    domains are excluded by the ShardPlan; a topology generation bump
+    re-slices on the next call."""
     if plan is None:
         plan = shard_plan(topology)
     if plan is None:
@@ -941,11 +934,6 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         # device path (route pinned so dispatch_batch cannot bounce back)
         with route_scope(ROUTE_SINGLE):
             return dispatch_batch(kernel, packed, n, max_chunk, min_pad)
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-
-    from cometbft_tpu.crypto import telemetry as _telemetry
-    from cometbft_tpu.crypto.tpu import aot
     from cometbft_tpu.crypto.tpu import memory as _memory
 
     nsh = plan.n_shards
@@ -960,219 +948,17 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
         cap = h.chunk_cap(max_chunk, min_pad)
         launch_cap = cap if launch_cap is None else min(launch_cap, cap)
     chunks = shard_chunks(n, nsh, launch_cap, min_pad)
-    _hub = _telemetry.default_hub()
-    from cometbft_tpu.crypto import wire as _wirelib
-
-    _ledger = _wirelib.default_ledger()
     _wire_dev = f"mesh:{nsh}"
-    registry = aot.default_registry()
-    depth = pipeline_depth()
-    out = np.zeros(n, bool)
-    inflight: "deque" = deque()
-    cancel = current_cancel_event()
-    max_bucket = 0
-    t_wall0 = time.perf_counter()
-    # build seconds stay out of the ledger's phases (see dispatch_batch)
-    _build = aot.build_clock()
-    _built0 = _build.total()
-    # per-dispatch phase totals (seconds); d2h accumulates in retire.
-    # The wire ledger buckets sharded work by the per-shard pow2 lane
-    # count and labels the whole mesh as one "device" — the link is what
-    # the ledger models, and all shards ride the same host egress.
-    _tot = {"pack": 0.0, "h2d": 0.0, "compute": 0.0, "d2h": 0.0,
-            "hidden": 0.0, "bytes": 0, "chunks": 0}
-
-    def retire(slot):
-        chunk_idx, start, end, mask, span, shard_spans, winfo = slot
-        t_dev = time.perf_counter_ns()
-        try:
-            with _trace.stage("mesh.retire", span=span.child("mesh.retire")):
-                out[start:end] = np.asarray(mask)[: end - start]
-        except DispatchCancelled:
-            for s in shard_spans:
-                s.end(error="cancelled")
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - device died mid-retire
-            for s in shard_spans:
-                s.end(error=repr(exc))
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"sharded retire of chunk {chunk_idx} (sigs [{start}:{end}]) "
-                f"failed: {exc}"
-            ) from exc
-        wait = time.perf_counter_ns() - t_dev
-        d2h_s = wait / 1e9
-        _tot["d2h"] += d2h_s
-        if _ledger is not None and winfo is not None:
-            per_b, wire_bytes, pack_s, h2d_s, compute_s, hidden_s = winfo
-            _ledger.note_chunk(
-                ROUTE_SHARDED, _wire_dev, per_b, end - start, wire_bytes,
-                pack_s, h2d_s, compute_s, d2h_s, hidden_s=hidden_s,
-                padded_lanes=per_b * nsh,
-            )
-        for s in shard_spans:
-            s.end(device_wait_ns=wait)
-        span.end(device_wait_ns=wait)
-
-    # staged prefetch, mirroring dispatch_batch: pack + sharded
-    # device_put (NamedSharding placement fans the H2D out to every
-    # shard) run ahead of the compute pointer, so the next megachunk's
-    # transfer is in flight across the whole mesh while the current one
-    # computes.
-    total_chunks = len(chunks)
-    prefetch = prefetch_depth()
-    staged: "deque" = deque()
-    next_stage = 0
-
-    def stage_next():
-        nonlocal next_stage, max_bucket
-        chunk_idx = next_stage
-        next_stage += 1
-        start, end, size = chunks[chunk_idx]
-        per = size // nsh
-        span = _trace.child_of_current(
-            "sharded_chunk", chunk=chunk_idx, n_sigs=end - start,
-            shards=nsh, generation=plan.generation,
-        )
-        overlapped = len(inflight) > 0 or len(staged) > 0
-        t_host = time.perf_counter_ns()
-        try:
-            with _trace.stage("mesh.pack", span=span.child("mesh.pack")):
-                if callable(packed):
-                    chunk = packed(start, end)
-                else:
-                    chunk = [a[..., start:end] for a in packed]
-                max_bucket = max(max_bucket, per)
-
-                def pad(a):
-                    padded = np.zeros(a.shape[:-1] + (size,), a.dtype)
-                    padded[..., : end - start] = a
-                    return padded
-
-                padded_args = [pad(a) for a in chunk]
-            t_pack = time.perf_counter_ns()
-            wire_bytes = sum(int(a.nbytes) for a in padded_args)
-            shardings = tuple(
-                NamedSharding(
-                    plan.mesh, PS(*([None] * (a.ndim - 1) + ["batch"]))
-                )
-                for a in padded_args
-            )
-            with _trace.stage(
-                "mesh.launch", span=span.child("mesh.launch")
-            ):
-                # numpy rows go host -> each shard's chip; jnp.asarray
-                # first would land the whole chunk on chip 0 and make
-                # the placement a chip-to-chip copy
-                placed = [
-                    jax.device_put(a, s)
-                    for a, s in zip(padded_args, shardings)
-                ]
-            t_h2d = time.perf_counter_ns()
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - per-chunk context for triage
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"sharded staging of chunk {chunk_idx} "
-                f"(sigs [{start}:{end}] over {nsh} shards "
-                f"{plan.labels()}) failed: {exc}"
-            ) from exc
-        pack_s = (t_pack - t_host) / 1e9
-        h2d_s = (t_h2d - t_pack) / 1e9
-        staged.append((chunk_idx, start, end, per, size, placed, span,
-                       wire_bytes, pack_s, h2d_s, overlapped))
-
-    def launch(slot):
-        (chunk_idx, start, end, per, size, placed, span, wire_bytes,
-         pack_s, h2d_s, overlapped) = slot
-        t_launch = time.perf_counter_ns()
-        built = _build.total()
-        try:
-            shard_spans = []
-            real = end - start
-            for si, h in enumerate(plan.handles):
-                lanes = max(0, min(per, real - si * per))
-                shard_spans.append(
-                    span.child("shard", device=h.label, shard=si,
-                               n_sigs=lanes, pad=per)
-                )
-                if _hub is not None:
-                    _hub.note_chunk(h.label, lanes, per)
-            with _trace.stage(
-                "mesh.launch", span=span.child("mesh.launch")
-            ):
-                mask = registry.call(
-                    kernel, placed, donate_from=donate_from, sharded=True,
-                    mesh=plan.mesh,
-                )
-            t_compute = time.perf_counter_ns()
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - per-chunk context for triage
-            span.end(error=repr(exc))
-            raise RuntimeError(
-                f"sharded dispatch of chunk {chunk_idx} "
-                f"(sigs [{start}:{end}] over {nsh} shards "
-                f"{plan.labels()}) failed: {exc}"
-            ) from exc
-        compute_s = max(
-            0.0, (t_compute - t_launch) / 1e9 - (_build.total() - built)
-        )
-        hidden_s = h2d_s if overlapped else 0.0
-        span.set_tag(
-            "host_ns", int((pack_s + h2d_s + compute_s) * 1e9)
-        )
-        span.set_tag("pad", size)
-        span.set_tag("pack_ns", int(pack_s * 1e9))
-        span.set_tag("h2d_ns", int(h2d_s * 1e9))
-        span.set_tag("compute_ns", int(compute_s * 1e9))
-        span.set_tag("hidden_ns", int(hidden_s * 1e9))
-        span.set_tag("wire_bytes", wire_bytes)
-        _tot["pack"] += pack_s
-        _tot["h2d"] += h2d_s
-        _tot["compute"] += compute_s
-        _tot["hidden"] += hidden_s
-        _tot["bytes"] += wire_bytes
-        _tot["chunks"] += 1
-        winfo = (
-            (per, wire_bytes, pack_s, h2d_s, compute_s, hidden_s)
-            if _ledger is not None else None
-        )
-        inflight.append(
-            (chunk_idx, start, end, mask, span, shard_spans, winfo)
-        )
-
-    for chunk_idx in range(total_chunks):
-        if cancel is not None and cancel.is_set():
-            raise DispatchCancelled(
-                f"sharded dispatch cancelled before chunk {chunk_idx} "
-                f"(sigs [{chunks[chunk_idx][0]}:{n}] undone)"
-            )
-        while (next_stage < total_chunks
-               and next_stage <= chunk_idx + prefetch):
-            stage_next()
-        launch(staged.popleft())
-        while len(inflight) > depth:
-            retire(inflight.popleft())
-    while inflight:
-        retire(inflight.popleft())
-    if _ledger is not None and _tot["chunks"]:
-        _ledger.note_dispatch(
-            ROUTE_SHARDED, _wire_dev, n,
-            wall_s=max(0.0, time.perf_counter() - t_wall0
-                       - (_build.total() - _built0)),
-            pack_s=_tot["pack"], h2d_s=_tot["h2d"],
-            compute_s=_tot["compute"], d2h_s=_tot["d2h"],
-            hidden_s=_tot["hidden"], wire_bytes=_tot["bytes"],
-            chunks=_tot["chunks"],
-        )
-    if _plane is not None and n > 0 and max_bucket > 0:
-        # per-device model correction: each shard served max_bucket
-        # lanes of this kernel; best-effort, never fails a dispatch
+    out, tot = launch_stream(
+        kernel, chunks, _slices_of(packed), n, where=plan.mesh,
+        prefix="mesh", route=ROUTE_SHARDED, device_label=_wire_dev,
+        domains=plan.labels(), donate_from=donate_from,
+    )
+    _note_dispatch(ROUTE_SHARDED, _wire_dev, n, tot)
+    if _plane is not None and chunks:
+        # per-device model correction: each shard served at most this
+        # many lanes of this kernel; best-effort, never fails a dispatch
+        max_bucket = max(size for _, _, size in chunks) // nsh
         for h in plan.handles:
             try:
                 _plane.observe_dispatch(
@@ -1182,88 +968,3 @@ def dispatch_sharded(kernel, packed, n: int, max_chunk: int, min_pad: int,
             except Exception:  # noqa: BLE001 - observability only
                 pass
     return out
-
-
-def sharded_verify(kernel, args, donate_from: int = 0, mesh=None):
-    """Run a verify kernel with every input's trailing (batch) axis
-    sharded over ``mesh``: a ShardPlan's (the resident commit hands in
-    the mesh its rows were placed on), else the FULL mesh (dispatch_batch's
-    legacy auto-shard). args are numpy arrays (or already-placed jax
-    arrays) whose trailing dim is the (padded) batch — the caller pads
-    to a multiple of the shard count already (shard_chunks).
-
-    donate_from: index of the first argument eligible for buffer
-    donation. Single-use staging buffers are donated so XLA reuses the
-    space instead of holding input + workspace live together (matters
-    at the 8k-lane chunks); RESIDENT buffers (the valset pubkey rows
-    that live across commits) must come before donate_from or donation
-    would free them after one dispatch.
-
-    Same dispatch contract as dispatch_batch: the thread's cancel event
-    is honored (DispatchCancelled before any work is issued), every
-    dispatch emits a trace span, and a batch axis wider than the
-    resolved chunk cap × device count is split into capped sub-dispatches
-    whose masks are concatenated. Megabatch callers should prefer
-    dispatch_sharded, which additionally honors per-device memory
-    guards; this entry serves pre-placed/resident buffers
-    (verify_valset_resident)."""
-    import jax
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-
-    from cometbft_tpu.crypto.tpu import aot
-
-    if mesh is None:
-        mesh = batch_mesh()
-    ndev = int(mesh.devices.size)
-    batch = int(args[0].shape[-1])
-    # chunk-cap contract: cap × ndev lanes per dispatch, using the
-    # default-device ladder (this entry predates per-domain dispatch)
-    limit = chunk_cap(aot._DEFAULT_CAP, aot._MIN_PAD) * ndev
-    cancel = current_cancel_event()
-    registry = aot.default_registry()
-
-    def one(chunk_args, lanes):
-        if cancel is not None and cancel.is_set():
-            raise DispatchCancelled(
-                f"sharded_verify cancelled ({lanes} lanes undone)"
-            )
-        span = _trace.child_of_current(
-            "sharded_verify", n_lanes=lanes, shards=ndev
-        )
-        t_host = time.perf_counter_ns()
-        try:
-            shardings = tuple(
-                NamedSharding(mesh, PS(*([None] * (a.ndim - 1) + ["batch"])))
-                for a in chunk_args
-            )
-            # host rows go straight to each shard's chip (see
-            # dispatch_sharded); rows already placed there stay put
-            placed = [
-                jax.device_put(a, s)
-                for a, s in zip(chunk_args, shardings)
-            ]
-            mask = registry.call(
-                kernel, placed, donate_from=donate_from, sharded=True,
-                mesh=mesh,
-            )
-        except DispatchCancelled:
-            span.end(error="cancelled")
-            raise
-        except Exception as exc:  # noqa: BLE001 - dispatch context
-            span.end(error=repr(exc))
-            raise
-        span.end(host_ns=time.perf_counter_ns() - t_host)
-        return mask
-
-    if batch <= limit:
-        return one(args, batch)
-    # oversize batch: honor the cap by splitting (limit is a multiple of
-    # ndev, and callers pad to a multiple of ndev, so every sub-chunk
-    # still shards evenly)
-    masks = []
-    for start in range(0, batch, limit):
-        end = min(start + limit, batch)
-        chunk = [a[..., start:end] for a in args]
-        masks.append(np.asarray(one(chunk, end - start)))
-    return np.concatenate(masks)

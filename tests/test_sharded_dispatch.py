@@ -178,6 +178,45 @@ class TestShardChunks:
         for _, _, _, a_dev in rv.chunks:
             assert len({s.device for s in a_dev.addressable_shards}) == ndev
 
+    @pytest.mark.parametrize("route", ["indexed", "service"])
+    def test_the_one_chip_entries_round_here_too(self, monkeypatch, route):
+        """The indexed key store and verifyd's rows hand the stream the
+        rule's launches with one shard, and round nothing themselves."""
+        from cometbft_tpu.crypto import service as servicelib
+        from cometbft_tpu.crypto.tpu import ed25519_batch as eb, keystore
+
+        asked = []
+
+        def odd_rule(n, n_shards, cap, min_pad):
+            asked.append((n, n_shards, cap, min_pad))
+            return [(s, min(s + 40, n), 48) for s in range(0, n, 40)]
+
+        handed = {}
+
+        def stream(kernel, launches, build, n, **kw):
+            handed[kw["route"]] = [(s, e, z) for s, e, z, *_ in launches]
+            return np.zeros(n, bool), {}
+
+        monkeypatch.setattr(mesh, "shard_chunks", odd_rule)
+        monkeypatch.setattr(mesh, "launch_stream", stream)
+        monkeypatch.setattr(mesh, "n_devices", lambda: 1)
+        topology.set_default_topology(topology.DeviceTopology.single())
+        pks = [ed.gen_priv_key_from_secret(bytes([i, 7])).pub_key().bytes()
+               for i in range(100)]
+        eb._keystore.invalidate()
+        try:
+            if route == "indexed":
+                eb._get_resident(b"odd rule", pks)
+                assert keystore.verify_batch_indexed(
+                    pks, [b"m"] * 100, [b"s" * 64] * 100) == [False] * 100
+            else:
+                assert not servicelib.dispatch_rows(
+                    np.zeros((128, 100), np.uint8)).any()
+        finally:
+            eb._keystore.invalidate()
+        assert handed == {route: [(0, 40, 48), (40, 80, 48), (80, 100, 48)]}
+        assert asked[-1] == (100, 1, mesh.chunk_cap(8192, 64), 64)
+
     def test_warm_plan_and_dispatch_arithmetic_lockstep(self):
         # the zero-compiles-after-warm guarantee: every launch of every
         # batch size pads to a sharded total the ladder warms
