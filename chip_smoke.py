@@ -587,16 +587,21 @@ def leg_megacommit(node, n_vals: int, seed: int, timeout_s: float = 900.0):
         flushes.append(d)
     check(submits[1]["aot_compiles"] == 0 and submits[3]["aot_compiles"] == 0,
           "a second submit compiled", submits=submits)
-    # wire bytes per real lane of each route's biggest chunk: the same
-    # lanes in the same bucket either way, so indexed : keyed = 100 : 128
+    # wire bytes per real lane in the biggest bucket the indexed route
+    # shares with a keyed one (the keyed flush launches 2,048 lanes a
+    # chip, the indexed one up to the chunk cap): the same bucket either
+    # way, so indexed : keyed = 100 : 128
+    profiles = [r for r in node.wire_ledger.snapshot()["profiles"]
+                if r["route"] in Books.FLUSH_WIRE_ROUTES]
+    keyed_buckets = {r["bucket"] for r in profiles if r["route"] != "indexed"}
     top = max(
-        (r["bucket"] for r in node.wire_ledger.snapshot()["profiles"]
-         if r["route"] == "indexed"), default=None,
+        (r["bucket"] for r in profiles
+         if r["route"] == "indexed" and r["bucket"] in keyed_buckets),
+        default=None,
     )
     wire_bytes = {
-        r["route"]: r["bytes_per_lane"]
-        for r in node.wire_ledger.snapshot()["profiles"]
-        if r["bucket"] == top and r["route"] in Books.FLUSH_WIRE_ROUTES
+        r["route"]: r["bytes_per_lane"] for r in profiles
+        if r["bucket"] == top
     }
     if indexed:
         check(any(x["device_lanes_by_wire_route"].get("indexed", 0) >= n_vals

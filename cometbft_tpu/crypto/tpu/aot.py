@@ -101,16 +101,17 @@ class _KernelReg:
     process's current routing can dispatch it at all."""
 
     __slots__ = ("name", "kernel", "bucket_shapes", "donate_from",
-                 "reachable", "forced")
+                 "reachable", "forced", "launch")
 
     def __init__(self, name, kernel, bucket_shapes, donate_from, reachable,
-                 forced):
+                 forced, launch):
         self.name = name
         self.kernel = kernel
         self.bucket_shapes = bucket_shapes
         self.donate_from = donate_from
         self.reachable = reachable
         self.forced = forced
+        self.launch = launch
 
 
 def register_kernel(
@@ -120,6 +121,7 @@ def register_kernel(
     donate_from: int = 0,
     reachable: Optional[Callable[[], bool]] = None,
     forced: bool = False,
+    launch: Optional[int] = None,
 ) -> None:
     """Bind ``kernel`` to a stable ``name`` and (optionally) a warmup
     shape template: ``bucket_shapes(bucket)`` returns the kernel's arg
@@ -131,11 +133,16 @@ def register_kernel(
     worth a compile per bucket — ~45 s each on a v5e); omitted = yes.
     ``forced`` marks the kernel the supervisor's canary and triage
     dispatch BELOW the routing floor (force_device): its smallest bucket
-    is warmed first, ahead of the ladder."""
+    is warmed first, ahead of the ladder. ``launch`` is the size, in
+    lanes, of the largest launch the kernel's entry issues on one chip
+    where that is below the chunk cap (the entry streams a larger batch
+    as launches of that size): the warm ladder ends there for the
+    kernel's single-device executables."""
     inner = unwrap_kernel(kernel)
     with _name_mtx:
         _registered[name] = _KernelReg(
-            name, kernel, bucket_shapes, donate_from, reachable, forced
+            name, kernel, bucket_shapes, donate_from, reachable, forced,
+            launch,
         )
         _name_by_id[id(inner)] = (name, None, inner)
 
@@ -982,8 +989,11 @@ def bucket_ladder(
     the remainder chunk of a flush larger than the cap and by triage
     over many lanes; the last two compile on first use — outside the
     dispatch watchdog (BuildClock) — and persist in the executable
-    store. At the defaults that is 9 executables on one chip instead of
-    16, at ~50 s apiece cold on a v5e."""
+    store. A kernel whose entry launches below the cap (register_kernel's
+    ``launch``) is warmed on one chip only up to that launch
+    (warmup_plan): at the defaults 1,024 and 2,048 of the ladder's four
+    buckets, 5 executables on one chip with the canary's, at ~50 s
+    apiece cold on a v5e."""
     from cometbft_tpu.crypto.tpu import calibrate
 
     if cap is None:
@@ -1036,7 +1046,10 @@ def warmup_plan(
     template: the sharded variant when >1 device is
     visible (what dispatch_batch actually runs there — warmed first),
     plus the single-device variant (``include_single``, default on so a
-    mesh that degrades to one visible device still boots warm)."""
+    mesh that degrades to one visible device still boots warm). The
+    ladder's single-device buckets above a kernel's ``launch`` are left
+    out: no flush reaches them, its entry streams at that size. An
+    explicit ``sizes`` is taken as given."""
     # registering the curve kernels is an import side effect
     from cometbft_tpu.crypto.tpu import ed25519_batch  # noqa: F401
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
@@ -1074,7 +1087,8 @@ def warmup_plan(
                         reg.name, reg.kernel, reg.bucket_shapes(size),
                         reg.donate_from, True, size,
                     ))
-            if ndev == 1 or include_single:
+            streamed = sizes is None and reg.launch and bucket > reg.launch
+            if (ndev == 1 or include_single) and not streamed:
                 targets.append(WarmTarget(
                     reg.name, reg.kernel, reg.bucket_shapes(bucket),
                     reg.donate_from, False, bucket,

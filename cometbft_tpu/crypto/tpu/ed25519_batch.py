@@ -420,25 +420,34 @@ verify_kernel_indexed = jax.jit(_verify_core_indexed)
 # --- host glue -------------------------------------------------------------
 
 _MIN_PAD = 64
-# Per-curve default of a launch's lanes IN TOTAL; [crypto] max_chunk and
-# CBFT_TPU_MAX_CHUNK override it for ALL curve kernels at the shared
-# dispatch layer (mesh.chunk_cap). On the v5e the program runs at
-# 7.3-8.5 us a lane whatever the bucket from 512 up and a launch costs
-# 0.75 ms to issue (1.75 ms over four chips), so a larger launch buys no
-# device time: two launches of 8,192 + 2,048 padded lanes beat one of
-# 16,384 by 19 ms a 10,000-lane commit on four chips (PERF.md, PR 26).
-# Device memory is no bound: HBM peaks at 211 MB of 16 GB.
+# The CEILING of a launch's lanes IN TOTAL (per-curve default);
+# [crypto] max_chunk and CBFT_TPU_MAX_CHUNK override it for ALL curve
+# kernels at the shared dispatch layer (mesh.chunk_cap), the OOM-shrink
+# ladder and the memory guard halve it, and the scheduler budgets a
+# flush's lanes by it. The ed25519 entries launch BELOW it, at
+# _LAUNCH_LANES a chip; it is a launch's size only once it has been
+# shrunk under that. On the v5e the program runs at 7.3-8.5 us a lane
+# whatever the bucket from 512 up and a launch costs 0.75 ms to issue
+# (1.75 ms over four chips), so a larger launch buys no device time: two
+# launches of 8,192 + 2,048 padded lanes beat one of 16,384 by 19 ms a
+# 10,000-lane commit on four chips (PERF.md, PR 26). Device memory is no
+# bound: HBM peaks at 211 MB of 16 GB.
 _MAX_CHUNK = 8192
-# Lanes a chip of ONE launch of the resident commit
-# (verify_valset_resident): the commit is a stream of launches whose
-# lanes are built while the launches before run, so the size trades the
-# host work in front of the first launch against the fixed cost a
-# launch (0.75 ms to issue, ~3.5 ms of each pack call). Fixed on the v5e
-# from a 10,000-lane verify_commit's median: 4,096 a chip 115.9 ms,
-# 2,048 100.5, 1,024 102.1, against 128.1 with every lane built first
-# (PERF.md, PR 27); a bucket of the warm ladder (aot.bucket_ladder) at
-# the defaults.
-_RESIDENT_LAUNCH = 2048
+# The SIZE of one launch of the ed25519 entries, in lanes a chip, within
+# the ceiling above: the resident commit (verify_valset_resident) and the
+# keyed flush (verify_batch) are streams of such launches whose lanes are
+# built while the launches before run, so the size trades the host work
+# in front of the first launch against the fixed cost a launch (0.75 ms
+# to issue, ~3.5 ms of each pack call). Fixed on the v5e from a
+# 10,000-lane verify_commit's median: 4,096 a chip 115.9 ms, 2,048
+# 100.5, 1,024 102.1, against 128.1 with every lane built first
+# (PERF.md, PR 27), and re-read on a 6,464-lane blocksync window's
+# median: one launch of 8,192 107.9 ms, 4,096 102.4, 2,048 83.1, 1,024
+# 77.8 with 512 fewer padded lanes (79.8 for 2,048 at the same padding;
+# PERF.md, PR 29): one size for both routes. A bucket of the warm ladder
+# (aot.bucket_ladder) at the defaults, and where that ladder ends for
+# these kernels on one chip (``launch`` of aot.register_kernel).
+_LAUNCH_LANES = 2048
 
 
 def _le_words(arr_u8: np.ndarray) -> np.ndarray:
@@ -723,7 +732,9 @@ def verify_batch(
 
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
-    out = mesh_mod.dispatch_batch(kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD)
+    out = mesh_mod.dispatch_batch(
+        kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD, launch=_LAUNCH_LANES
+    )
     return list(out & valid_full)
 
 
@@ -801,12 +812,14 @@ def _register_aot_kernels():
         bucket_shapes=lambda b: [((32, b), np.uint32)],
         reachable=lambda: wire_format() == "words",
         forced=True,
+        launch=_LAUNCH_LANES,
     )
     aot.register_kernel(
         "ed25519.verify_resident",
         verify_kernel_resident,
         bucket_shapes=lambda b: [((8, b), np.uint32), ((24, b), np.uint32)],
         donate_from=1,
+        launch=_LAUNCH_LANES,
     )
     aot.register_kernel("ed25519.verify_full", verify_full_kernel)
     # compact-wire kernels (PR 13): the host-hash compact wire is the
@@ -822,6 +835,7 @@ def _register_aot_kernels():
         bucket_shapes=lambda b: [((128, b), np.uint8)],
         reachable=lambda: wire_format() == "compact",
         forced=True,
+        launch=_LAUNCH_LANES,
     )
     aot.register_kernel(
         "ed25519.verify_full_compact",
@@ -832,6 +846,7 @@ def _register_aot_kernels():
         reachable=lambda: (
             wire_format() == "compact" and _device_hash_reachable()
         ),
+        launch=_LAUNCH_LANES,
     )
     aot.register_kernel(
         "ed25519.verify_indexed", verify_kernel_indexed, donate_from=1
@@ -847,7 +862,7 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
     (mesh.shard_plan: the healthy fault domains that own a chip) where
     there is one, else on the default chip. Chunks and padding are the
     one rounding rule's (mesh.shard_chunks), a chunk a launch of at most
-    _RESIDENT_LAUNCH lanes a chip within the chunk cap. Also builds the
+    _LAUNCH_LANES lanes a chip within the chunk cap. Also builds the
     indexed-dispatch view (single-device only): a u8[n_pad, 32] gather
     table plus a pubkey→row index, so steady-state flushes against this
     valset ship an index vector instead of the keys."""
@@ -867,7 +882,7 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
 
     plan = mesh_mod.shard_plan()
     nsh = plan.n_shards if plan is not None else 1
-    cap = min(mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD), _RESIDENT_LAUNCH * nsh)
+    cap = min(mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD), _LAUNCH_LANES * nsh)
     chunks = []
     for start, end, size in mesh_mod.shard_chunks(n, nsh, cap, _MIN_PAD):
         a_words = np.zeros((8, size), np.uint32)

@@ -377,7 +377,7 @@ class MemoryPlane:
 
     def refresh_guard(
         self, handle, default_cap: int, min_pad: int,
-        kernel: str = "ed25519",
+        kernel: str = "ed25519", launch: Optional[int] = None,
     ) -> int:
         """The proactive rung: recompute this device's memory-guard
         chunk cap from fresh(ish) stats and the footprint model, clamp
@@ -385,7 +385,10 @@ class MemoryPlane:
         cap consumer sees it, and return the guarded cap. Halves until
         the projected footprint fits free headroom, floored at
         ``min_pad`` — at the floor the dispatch proceeds and the
-        reactive OOM rung remains the backstop."""
+        reactive OOM rung remains the backstop. ``launch`` is the
+        dispatch's launch size where it has one below the cap
+        (mesh.dispatch_batch): the projection starts from the largest
+        launch the dispatch can issue, not from a cap it never reaches."""
         from cometbft_tpu.crypto.tpu import mesh
 
         try:
@@ -398,6 +401,8 @@ class MemoryPlane:
             # malformed CBFT_TPU_MAX_CHUNK surfaces at dispatch, not here
             handle.set_memory_guard_cap(None)
             return default_cap
+        if launch is not None:
+            base = min(base, max(min_pad, launch))
         free = self.free_headroom_bytes(handle)
         cap = base
         while cap > min_pad and self.projected_bytes(kernel, cap) > free:

@@ -703,12 +703,22 @@ def _note_dispatch(route: str, device_label: str, n: int, tot: dict) -> None:
 
 
 def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
-                   device=None):
+                   device=None, launch: Optional[int] = None):
     """Chunk-pad-dispatch of a batch verify kernel (used by all three
     curve entries): cuts the batch into launches of at most the chunk
     cap, pads each launch's trailing batch axis to a power of two
     (shard_chunks), runs them as one launch_stream and gathers the
     boolean masks.
+
+    ``max_chunk`` is the CEILING of a launch ([crypto] max_chunk, halved
+    by the OOM-shrink ladder and the memory guard). ``launch``, where the
+    curve entry has measured one, is the SIZE of a launch in lanes a
+    chip, applied below the ceiling: a batch of more lanes is a stream
+    of such launches, each packed while the ones before it run; the one
+    short launch goes first and pads to at least half a launch, so the
+    shapes a batch of any size can reach are two a chip (shard_chunks'
+    ``short_floor``). With none given a launch is as large as the
+    ceiling allows.
 
     ``device`` is an optional topology.DeviceHandle naming the fault
     domain this dispatch runs against; when omitted the thread's
@@ -751,8 +761,10 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
     _guard_dev = device if device is not None else _shim_device()
     _kernel_name = getattr(kernel, "__name__", "kernel")
     if _plane is not None:
+        # the guard projects the largest launch this dispatch can issue
         _plane.refresh_guard(
-            _guard_dev, max_chunk, min_pad, kernel=_kernel_name
+            _guard_dev, max_chunk, min_pad, kernel=_kernel_name,
+            launch=launch,
         )
         _mem_baseline = _plane.device_view(_guard_dev).get("bytes_in_use")
     else:
@@ -770,20 +782,25 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
     where, nsh, _wire_route = placement(device), 1, ROUTE_SINGLE
     if route != ROUTE_SINGLE and where is None and n_devices() > 1:
         where, nsh, _wire_route = batch_mesh(), n_devices(), "auto"
+    cap = max_chunk if launch is None else min(max_chunk, launch * nsh)
+    chunks = shard_chunks(
+        n, nsh, cap, min_pad, short_floor=0 if launch is None else cap // 2
+    )
     out, tot = launch_stream(
-        kernel, shard_chunks(n, nsh, max_chunk, min_pad), _slices_of(packed),
-        n, where=where, prefix="mesh", route=_wire_route,
-        device_label=_dev_label, domains=(_dev_label,),
+        kernel, chunks, _slices_of(packed), n, where=where, prefix="mesh",
+        route=_wire_route, device_label=_dev_label, domains=(_dev_label,),
     )
     _note_dispatch(_wire_route, _dev_label, n, tot)
-    if _plane is not None and n > 0:
+    if _plane is not None and chunks:
         # post-dispatch model correction: the observed allocation peak
         # over the pre-dispatch baseline calibrates the per-(kernel,
-        # bucket) footprint model. Best-effort — a stats failure must
-        # never fail a dispatch that already produced its mask.
+        # bucket) footprint model, at the largest launch issued.
+        # Best-effort — a stats failure must never fail a dispatch that
+        # already produced its mask.
         try:
             _plane.observe_dispatch(
-                _guard_dev, _kernel_name, min(max_chunk, _pow2(n, min_pad)),
+                _guard_dev, _kernel_name,
+                max(size for _, _, size in chunks) // nsh,
                 baseline_in_use=_mem_baseline,
             )
         except Exception:  # noqa: BLE001 - observability only
@@ -798,7 +815,8 @@ def _pow2(n: int, floor: int) -> int:
     return size
 
 
-def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int):
+def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int,
+                 short_floor: int = 0):
     """THE rounding rule of a batch launched over ``n_shards`` chips:
     → [(start, end, size)], one entry a launch, lanes [start, end) of
     the batch padded to ``size`` lanes, ``size // n_shards`` on each
@@ -807,6 +825,20 @@ def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int):
     min_pad), rounded up to a multiple of n_shards so the shards are
     equal. A 10,000-lane commit on four chips under the 8,192 cap is
     8,192 + 2,048 padded lanes in two launches, 2,048 and 512 a chip.
+
+    ``short_floor`` (dispatch_batch, where a launch size is given: half
+    a launch) shapes a batch of MORE than one launch as a stream: the
+    launch that is short of ``cap`` lanes pads to at least
+    ``short_floor``, so the stream's shapes are that and ``cap`` and
+    nothing between min_pad and there (the executables a stream of any
+    length needs are the ones its first two lengths built), and it goes
+    FIRST, since the host work in front of the first launch is the part
+    of a stream nothing hides. A 6,464-lane blocksync window under a cap
+    of 2,048 is 1,024 + 3 x 2,048 padded lanes; on the v5e that read
+    79.3 ms a window against 83.1 with the short launch last, 88.9 with
+    it padded to 2,048 and 107.9 in one launch of 8,192 (PERF.md, PR 29).
+    A batch of one launch, and any batch without the floor, rounds as
+    ever.
 
     The resident commit (ed25519_batch._build_resident), dispatch_batch,
     dispatch_sharded, the indexed key store, verifyd's rows and warm
@@ -817,10 +849,14 @@ def shard_chunks(n: int, n_shards: int, cap: int, min_pad: int):
     ``cap``)."""
     n_shards = max(1, int(n_shards))
     cap = max(1, int(cap))
+    n = max(0, int(n))
+    short = n % cap if short_floor and n > cap else 0
+    bounds = [(0, short)] if short else []
+    bounds += [(start, min(start + cap, n)) for start in range(short, n, cap)]
     out = []
-    for start in range(0, max(0, int(n)), cap):
-        end = min(start + cap, n)
-        size = -(-_pow2(end - start, min_pad) // n_shards) * n_shards
+    for start, end in bounds:
+        floor = max(min_pad, short_floor) if end == short else min_pad
+        size = -(-_pow2(end - start, floor) // n_shards) * n_shards
         out.append((start, end, size))
     return out
 

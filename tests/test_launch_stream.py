@@ -1,10 +1,13 @@
-"""The one launch stream (mesh.launch_stream) under its five entries:
+"""The one launch stream (mesh.launch_stream) under its entries:
 dispatch_batch on one chip, dispatch_sharded on the suite's 8-device
-mesh, the resident commit, the indexed key store and verifyd's rows.
+mesh, the resident commit, the indexed key store, verifyd's rows, and
+the keyed ed25519 flush (verify_batch), which streams through
+dispatch_batch at the launch size the resident commit uses.
 
 Every entry is driven over the same 200 lanes under a chunk cap of 64
-(four launches, the last of 8 real lanes), with a hook in whatever the
-entry asks for a launch's host work, and held to the same four things:
+(four launches, the last of 8 real lanes; verify_batch, cut by its launch
+size, puts the 8 first), with a hook in whatever the entry asks for a
+launch's host work, and held to the same four things:
 the order of work, the cancel check between launches, the context a
 failing launch carries, and what the wire ledger books. The two keyed
 entries run a parity kernel that costs nothing to compile; the other
@@ -64,9 +67,10 @@ class _Entry:
     """One caller of the stream: ``run(hook)`` sends the 200 lanes through
     it, ``hook(k)`` called when launch k's host work is asked for."""
 
-    def __init__(self, route, device, prefix, sizes, run, want):
+    def __init__(self, route, device, prefix, sizes, run, want, spans=SPANS):
         self.route, self.device, self.prefix = route, device, prefix
         self.sizes, self.run, self.want = sizes, run, want
+        self.spans = spans
 
 
 def _keyed(dispatch):
@@ -84,7 +88,7 @@ def _keyed(dispatch):
 
 
 ENTRIES = ["dispatch_batch", "dispatch_sharded", "verify_valset_resident",
-           "verify_batch_indexed", "dispatch_rows"]
+           "verify_batch_indexed", "dispatch_rows", "verify_batch"]
 
 
 @pytest.fixture(params=ENTRIES)
@@ -146,6 +150,28 @@ def entry(request, monkeypatch, lanes):
                 return keystore.verify_batch_indexed(pks, msgs, sigs40)
 
             yield _Entry("indexed", "dev0", "mesh", [64] * 4, run, truth)
+        elif request.param == "verify_batch":
+            # the launch size is what cuts the flush, under a ceiling
+            # that would hold it whole, and the short launch goes first
+            monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", str(4 * CAP))
+            monkeypatch.setattr(eb, "_LAUNCH_LANES", CAP)
+            pks = [k.pub_key().bytes() for k in keys]
+            real = eb.prepare_batch_compact
+            short_first = [(0, 8), (8, 72), (72, 136), (136, 200)]
+
+            def run(hook):
+                def prepare(lane_pks, lane_msgs, lane_sigs):
+                    hook(short_first.index(
+                        (pks.index(lane_pks[0]),
+                         pks.index(lane_pks[-1]) + 1)))
+                    return real(lane_pks, lane_msgs, lane_sigs)
+
+                monkeypatch.setattr(eb, "prepare_batch_compact", prepare)
+                with mesh.route_scope(mesh.ROUTE_SINGLE):
+                    return eb.verify_batch(pks, msgs, sigs, hash="host")
+
+            yield _Entry("single", "dev0", "mesh", [64] * 4, run, truth,
+                         spans=short_first)
         else:
             wire, valid = eb.prepare_batch_compact(
                 [k.pub_key().bytes() for k in keys], msgs, sigs)
@@ -193,7 +219,8 @@ def _order(entry):
     chunks = {s["span_id"]: s for s in named["chunk"]}
     assert [chunks[s["parent_id"]]["tags"]["chunk"] for s in pack] == [
         0, 1, 2, 3]
-    assert [c["tags"]["n_sigs"] for c in named["chunk"]] == [64, 64, 64, 8]
+    assert [c["tags"]["n_sigs"] for c in named["chunk"]] == [
+        end - start for start, end in entry.spans]
     assert [c["tags"]["pad"] for c in named["chunk"]] == entry.sizes
     ordered = sorted(pack + launch, key=lambda s: s["start_us"])
     assert [s["name"].rsplit(".", 1)[1] for s in ordered] == [
@@ -220,7 +247,7 @@ def _cancel(entry):
             entry.run(hook)
     assert asked == [0]
     assert "before chunk 1" in str(exc.value)
-    assert f"[64:{N}] undone" in str(exc.value)
+    assert f"[{entry.spans[1][0]}:{N}] undone" in str(exc.value)
 
 
 def _failure(entry):
@@ -236,7 +263,8 @@ def _failure(entry):
     with pytest.raises(RuntimeError) as exc:
         entry.run(hook)
     assert asked == [0, 1]
-    assert f"{entry.route} dispatch of chunk 1 (sigs [64:128]) on " \
+    start, end = entry.spans[1]
+    assert f"{entry.route} dispatch of chunk 1 (sigs [{start}:{end}]) on " \
         f"{entry.device} failed: boom in launch 1" == str(exc.value)
     assert isinstance(exc.value.__cause__, ValueError)
 
@@ -273,3 +301,57 @@ ASPECTS = {"order": _order, "cancel": _cancel, "failure": _failure,
 @pytest.mark.parametrize("aspect", sorted(ASPECTS))
 def test_every_entry_is_the_one_stream(entry, aspect):
     ASPECTS[aspect](entry)
+
+
+# a keyed flush of 290 lanes at a launch size of 256: the 34 lanes short
+# of a launch go first and pad to half a launch, 128, and not to their own
+# bucket, 64
+KEYED_N, KEYED_LAUNCH = 290, 256
+KEYED_SHORT = KEYED_N % KEYED_LAUNCH
+
+
+@pytest.mark.parametrize("forged", [
+    (), (KEYED_SHORT - 1,), (KEYED_SHORT,),
+    (KEYED_SHORT - 1, KEYED_SHORT), (0, KEYED_N - 1),
+], ids=["none", "last_of_launch_0", "first_of_launch_1", "both_sides",
+        "first_and_last_lane"])
+def test_a_keyed_flush_streams_at_the_launch_size(monkeypatch, forged):
+    """verify_batch of more than one launch: launch 1 is packed with
+    launch 0 in flight, the short launch goes first and pads to half a
+    launch, a forged lane on either side of the launch boundary is
+    refused and its neighbours are not, and the mask is the CPU
+    verifier's."""
+    keys = [ed.gen_priv_key_from_secret(b"keyed|%d" % i)
+            for i in range(KEYED_N)]
+    msgs = [b"keyed flush lane %d" % i for i in range(KEYED_N)]
+    sigs = [k.sign(m) for k, m in zip(keys, msgs)]
+    for i in forged:
+        sigs[i] = sigs[i][:40] + bytes([sigs[i][40] ^ 4]) + sigs[i][41:]
+    truth = [k.pub_key().verify_signature(m, s)
+             for k, m, s in zip(keys, msgs, sigs)]
+    assert [i for i, ok in enumerate(truth) if not ok] == sorted(forged)
+    monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
+    monkeypatch.delenv("CBFT_TPU_PIPELINE_DEPTH", raising=False)
+    mesh.configure_chunk_cap(None)
+    monkeypatch.setattr(eb, "_LAUNCH_LANES", KEYED_LAUNCH)
+    before = topology.default_topology()
+    topology.set_default_topology(topology.DeviceTopology.single())
+    tracer = tracelib.Tracer(sample=1.0)
+    root = tracer.start_span("request")
+    try:
+        with tracelib.use(root), mesh.route_scope(mesh.ROUTE_SINGLE):
+            got = eb.verify_batch(
+                [k.pub_key().bytes() for k in keys], msgs, sigs, hash="host")
+    finally:
+        root.end()
+        topology.set_default_topology(before)
+    assert [bool(x) for x in got] == truth
+    spans = tracer.recent()[0]["spans"]
+    chunks = sorted((s for s in spans if s["name"] == "chunk"),
+                    key=lambda s: s["tags"]["chunk"])
+    assert [(c["tags"]["n_sigs"], c["tags"]["pad"]) for c in chunks] == [
+        (KEYED_SHORT, KEYED_LAUNCH // 2), (KEYED_LAUNCH, KEYED_LAUNCH)]
+    pack = sorted((s for s in spans if s["name"] == "mesh.pack"),
+                  key=lambda s: s["start_us"])
+    assert [s["tags"] for s in pack] == [
+        {"chunk": 0, "inflight": 0}, {"chunk": 1, "inflight": 1}]

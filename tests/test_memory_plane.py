@@ -165,6 +165,68 @@ class TestPreDispatchGuard:
         assert plane.refresh_guard(handle, 8192, 64) == 64
 
 
+    @pytest.mark.parametrize("fits,want_guard,want_cap", [
+        # the launch fits: no guard, though the 8,192 cap would not
+        (2048, None, 2048),
+        # it does not: the guard halves from the launch, not from the cap
+        (512, 512, 512),
+    ])
+    def test_guard_projects_the_launch_not_the_cap(
+            self, handle, fits, want_guard, want_cap):
+        from cometbft_tpu.crypto.tpu import mesh
+
+        limit = int(
+            memlib.SEED_BYTES_PER_LANE * fits * mesh.pipeline_depth() / 0.9
+        ) + 1
+        plane = memlib.MemoryPlane(
+            stats=False, model_limit_bytes=limit, poll_ms=0
+        )
+        assert plane.refresh_guard(handle, 8192, 64, launch=2048) == want_cap
+        assert handle.memory_guard_cap() == want_guard
+        shrinks = sum(
+            c.value() for c in plane.metrics.guard_shrinks._series()
+        )
+        assert shrinks == (0 if want_guard is None else 2)  # 2048 -> 512
+
+    def test_dispatch_tells_its_observers_the_largest_launch(
+            self, handle, monkeypatch):
+        """dispatch_batch with a launch size: the guard is asked about
+        that launch and the footprint model is corrected at the largest
+        launch issued, not at the bucket of the whole batch."""
+        import numpy as np
+
+        from cometbft_tpu.crypto.tpu import mesh
+
+        class Plane:
+            asked, observed = [], []
+
+            def refresh_guard(self, dev, cap, min_pad, kernel, launch=None):
+                self.asked.append((cap, launch))
+
+            def device_view(self, dev):
+                return {}
+
+            def observe_dispatch(self, dev, kernel, lanes, baseline_in_use):
+                self.observed.append(lanes)
+
+        plane = Plane()
+        monkeypatch.setattr(memlib, "default_plane", lambda: plane)
+        monkeypatch.setattr(
+            mesh, "launch_stream",
+            lambda kernel, launches, build, n, **kw: (
+                np.zeros(n, bool), {"chunks": 0}))
+        monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
+        mesh.configure_chunk_cap(None)
+        with mesh.route_scope(mesh.ROUTE_SINGLE):
+            mesh.dispatch_batch(
+                len, [np.zeros(6464)], 6464, 8192, 64, device=handle,
+                launch=2048)
+            mesh.dispatch_batch(
+                len, [np.zeros(6464)], 6464, 8192, 64, device=handle)
+        assert plane.asked == [(8192, 2048), (8192, None)]
+        assert plane.observed == [2048, 8192]
+
+
 class TestGuardPreemptsInjectedOom:
     def test_chaos_memory_guard(self):
         """The PR's headline invariant, via the same harness

@@ -33,7 +33,7 @@ from cometbft_tpu.types.vote import SIGNED_MSG_TYPE_PRECOMMIT
 CHAIN_ID = "stream-chain"
 N = 300
 HEIGHT = 9
-LAUNCH = 64  # lanes a chip, patched in for eb._RESIDENT_LAUNCH
+LAUNCH = 64  # lanes a chip, patched in for eb._LAUNCH_LANES
 # shards -> the launches of 300 lanes: (start, end, padded lanes)
 LAUNCHES = {
     1: [(0, 64, 64), (64, 128, 64), (128, 192, 64), (192, 256, 64),
@@ -115,7 +115,7 @@ def shards(request, monkeypatch):
     topology.set_default_topology(
         topology.DeviceTopology.single() if request.param == 1
         else topology.DeviceTopology.virtual(request.param))
-    monkeypatch.setattr(eb, "_RESIDENT_LAUNCH", LAUNCH)
+    monkeypatch.setattr(eb, "_LAUNCH_LANES", LAUNCH)
     monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
     mesh.configure_chunk_cap(None)
     eb._keystore.invalidate()
@@ -272,7 +272,7 @@ def real_launch(monkeypatch):
     monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
     monkeypatch.delenv("CBFT_TPU_MIN_BATCH", raising=False)
     mesh.configure_chunk_cap(None)
-    assert eb._RESIDENT_LAUNCH == 2048
+    assert eb._LAUNCH_LANES == 2048
     assert mesh.chunk_cap(eb._MAX_CHUNK, eb._MIN_PAD) == 8192
 
 
@@ -339,7 +339,7 @@ def test_the_indexed_view_does_not_depend_on_the_launch_size(
            for i in range(299)] + [b"\x07" * 31]
     views = []
     for launch in (64, 2048):
-        monkeypatch.setattr(eb, "_RESIDENT_LAUNCH", launch)
+        monkeypatch.setattr(eb, "_LAUNCH_LANES", launch)
         rv = _launches(pks, 1, monkeypatch)
         views.append((np.asarray(rv.table_dev), rv.index, rv.pk_ok.tolist(),
                       [size for _, _, size, _ in rv.chunks]))

@@ -148,6 +148,101 @@ class TestShardChunks:
         if len(chunks) == 1:
             assert mesh.shard_bucket(n, nsh, 64) == chunks[0][2]
 
+    # lanes -> (real, padded) lanes of each launch of a keyed ed25519
+    # flush on one chip: launches of 2,048 lanes under the 8,192 ceiling,
+    # the short launch of a flush of more than one first and at least
+    # half a launch
+    STREAMED = {
+        1111: [(1111, 2048)], 2048: [(2048, 2048)],
+        2121: [(73, 1024), (2048, 2048)],
+        4141: [(45, 1024), (2048, 2048), (2048, 2048)],
+        6464: [(320, 1024)] + [(2048, 2048)] * 3,
+        8192: [(2048, 2048)] * 4,
+    }
+
+    @staticmethod
+    def _keyed_launches(monkeypatch, n, **kw):
+        """The launches dispatch_batch hands the stream for ``n`` lanes."""
+        handed = []
+
+        def stream(kernel, launches, build, n, **_):
+            handed.extend(launches)
+            return np.zeros(n, bool), {"chunks": 0}
+
+        monkeypatch.setattr(mesh, "launch_stream", stream)
+        monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
+        mesh.configure_chunk_cap(None)
+        topology.set_default_topology(topology.DeviceTopology.single())
+        with mesh.route_scope(mesh.ROUTE_SINGLE):
+            mesh.dispatch_batch(_mod3_kernel, [np.zeros(n)], n, 8192, 64, **kw)
+        assert handed[0][0] == 0 and handed[-1][1] == n
+        assert all(a[1] == b[0] for a, b in zip(handed, handed[1:]))
+        return [(end - start, size) for start, end, size in handed]
+
+    @pytest.mark.parametrize("n", sorted(STREAMED))
+    def test_a_launch_size_below_the_cap_and_the_tail_floor(
+            self, monkeypatch, n):
+        from cometbft_tpu.crypto.tpu import ed25519_batch as eb
+
+        assert eb._LAUNCH_LANES == 2048
+        got = self._keyed_launches(monkeypatch, n, launch=eb._LAUNCH_LANES)
+        assert got == self.STREAMED[n]
+        # the entries that hand no launch size launch as they did
+        assert self._keyed_launches(monkeypatch, n) == [
+            (e - s, size) for s, e, size in mesh.shard_chunks(n, 1, 8192, 64)]
+
+    @pytest.mark.parametrize("n,nsh,cap", [
+        (150, 1, 8192), (6464, 1, 2048), (6464, 1, 8192), (10000, 4, 8192),
+        (16385, 8, 8192), (10000, 3, 8192),
+    ])
+    def test_without_a_short_floor_the_rule_is_what_it_was(self, n, nsh, cap):
+        """PR 26's rule, written out: every launch its own power of two."""
+        want = []
+        for start in range(0, n, cap):
+            end = min(start + cap, n)
+            size = 64
+            while size < end - start:
+                size *= 2
+            want.append((start, end, -(-size // nsh) * nsh))
+        assert mesh.shard_chunks(n, nsh, cap, 64) == want
+        assert mesh.shard_chunks(n, nsh, cap, 64, short_floor=0) == want
+        # with one: the same launches, the short one first and no smaller
+        floored = mesh.shard_chunks(n, nsh, cap, 64, short_floor=cap // 2)
+        assert len(floored) == len(want)
+        assert sorted(e - s for s, e, _ in floored) == sorted(
+            e - s for s, e, _ in want)
+        assert sum(z for _, _, z in floored) >= sum(z for _, _, z in want)
+        if len(want) == 1:
+            assert floored == want
+        else:
+            assert floored[0][1] - floored[0][0] == want[-1][1] - want[-1][0]
+            assert len({size for _, _, size in floored}) <= 2
+
+    def test_a_blocksync_flush_reaches_no_shape_its_warm_up_has_not_built(
+            self, monkeypatch):
+        """The closed set: the deadline can close a flush of the cell's
+        window after any k of its 64 blocks of 101 lanes; whatever k,
+        its launches have sizes the cell's own warm-up bursts and full
+        windows (benchmark/traffic/blocksync_window.warm, as it stands)
+        have already run."""
+        from benchmark.traffic import blocksync_window
+        from cometbft_tpu.crypto.tpu import ed25519_batch as eb
+
+        lanes, blocks, floor, cap = 101, 64, 1024, 8192
+        buckets = blocksync_window.reachable_buckets(lanes, blocks, floor, cap)
+        full = 1 << (lanes * blocks - 1).bit_length()
+        bursts = [k for b, k in sorted(buckets.items()) if b != full]
+        assert bursts == [11, 21] and buckets[full] == 41
+
+        def sizes(k):
+            return {size for _, size in self._keyed_launches(
+                monkeypatch, k * lanes, launch=eb._LAUNCH_LANES)}
+
+        warmed = set().union(*(sizes(k) for k in bursts + [blocks]))
+        assert warmed == {1024, 2048}
+        for k in range(-(-floor // lanes), blocks + 1):
+            assert sizes(k) <= warmed, (k, sizes(k))
+
     def test_one_function_rounds_rows_bucket_and_warm_plan(self, monkeypatch):
         """Swap the rule for one no real rule could be and all three
         follow it: the resident rows' chunks, shard_bucket, and the

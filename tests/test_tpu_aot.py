@@ -298,7 +298,10 @@ class TestBucketLadder:
 
     def test_plan_warms_the_canary_bucket_first(self, monkeypatch):
         """Below the floor only the canary's bucket is warmed, first, for
-        the kernel the canary dispatches — and not for the others."""
+        the kernel the canary dispatches — and not for the others. Above
+        it the single-device ladder of a kernel ends at the launch its
+        entry streams at (register_kernel's ``launch``): no ed25519 flush
+        on one chip reaches @4096 or @8192."""
         monkeypatch.setattr(calibrate, "compile_seconds", lambda *a: {})
         plan = [
             (t.name, t.bucket) for t in
@@ -307,11 +310,18 @@ class TestBucketLadder:
         ]
         assert plan[0] == ("ed25519.verify_compact", 64)
         assert [b for _, b in plan].count(64) == 1
-        assert {b for _, b in plan[1:]} == {1024, 2048, 4096, 8192}
-        assert ("ed25519.verify_resident", 8192) in plan
+        assert {b for _, b in plan[1:]} == {1024, 2048}
+        assert ("ed25519.verify_resident", 2048) in plan
+        assert ("ed25519.verify_compact", 2048) in plan
+        # the sharded variants keep the whole ladder: a launch over a
+        # mesh is capped in total, 8,192 lanes over four chips
+        sharded = {
+            t.bucket for t in aot.warmup_plan(floor=1024) if t.sharded
+        }
+        assert sharded == {1024, 2048, 4096, 8192}
         # an explicit size list is taken as given
-        sized = aot.warmup_plan(sizes=[2048])
-        assert {t.bucket for t in sized if not t.sharded} == {2048}
+        sized = aot.warmup_plan(sizes=[2048, 8192])
+        assert {t.bucket for t in sized if not t.sharded} == {2048, 8192}
 
     def test_measured_compile_cost_reorders_above_floor(self, monkeypatch):
         monkeypatch.setattr(
