@@ -100,6 +100,7 @@ from cometbft_tpu.crypto.batch import (
     resolved_device_plane,
 )
 from cometbft_tpu.crypto.scheduler import Item, VerifyFuture
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.log import Logger
 from cometbft_tpu.libs.metrics import Registry
 from cometbft_tpu.libs.service import BaseService
@@ -602,10 +603,11 @@ class RowPayload:
 
     def as_compact(self) -> Tuple[np.ndarray, np.ndarray]:
         """(u8[128, n] compact rows, valid mask). Indexed payloads
-        host-gather their pubkey rows from the carried entry — used when
-        the flush mixes kinds or runs on the host verifier; a uniform
-        indexed flush on a live device plane keeps the on-device
-        gather instead."""
+        host-gather their pubkey rows from the carried entry: 32 bytes a
+        lane copied on the flush thread (``sched.rows``). Every flush
+        takes this path today (verify_mixed_flush): the 100 B rows save
+        the socket's bytes and the client's key packing, and the device
+        is sent 128 B a lane either way."""
         if self.kind == KIND_COMPACT:
             return self.wire, np.ones(self.n, dtype=bool)
         rows = self.entry.pk_arr[self.idx]          # [n, 32] host gather
@@ -687,19 +689,24 @@ class CachingRowVerifier:
 def dispatch_rows(rows: np.ndarray) -> np.ndarray:
     """Device dispatch of concatenated compact wire columns — the
     zero-double-marshalling half of the tentpole: the u8[128, B] bytes
-    that crossed the socket are the bytes ``device_put`` here. One
-    launch_stream on jax's default chip, cut and padded as the keyed
-    single-chip path's (mesh.shard_chunks), with every launch attributed
-    into the wire ledger under the "service" route so bytes-per-lane is
-    provable from /debug/verify."""
+    that crossed the socket (indexed frames: after verify_mixed_flush
+    gathered their key rows on the host) are the bytes ``device_put``
+    here. One launch_stream on jax's default chip, cut as the keyed
+    flush is (ed25519_batch.verify_batch through mesh.dispatch_batch):
+    launches of _LAUNCH_LANES with the one short launch first and padded
+    to at least half of one, so a flush of any length reaches the shapes
+    that route warms plus the small buckets, and the slices of launch
+    k+1 are padded and sent while launch k runs. Every launch is
+    attributed into the wire ledger under the "service" route so
+    bytes-per-lane is provable from /debug/verify."""
     from cometbft_tpu.crypto.tpu import ed25519_batch as ed
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
     n = int(rows.shape[1])
-    cap = mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD)
+    cap = min(mesh_mod.chunk_cap(ed._MAX_CHUNK, ed._MIN_PAD), ed._LAUNCH_LANES)
     out, _ = mesh_mod.launch_stream(
         ed.verify_kernel_compact,
-        mesh_mod.shard_chunks(n, 1, cap, ed._MIN_PAD),
+        mesh_mod.shard_chunks(n, 1, cap, ed._MIN_PAD, short_floor=cap // 2),
         lambda start, end: [rows[:, start:end]], n, where=None,
         prefix="mesh", route="service", device_label="dev0",
     )
@@ -744,24 +751,29 @@ def verify_mixed_flush(batch, row_verifier, on_fallback=None) -> List[bool]:
     """Verdict mask for one coalesced flush that contains at least one
     row-payload request. Triple requests pack ONCE into the same compact
     layout; row requests contribute their exact socket bytes (indexed
-    frames host-gather their key rows unless the whole flush stays on
-    the device path); the concatenated u8[128, N] block verifies in one
-    shot — this is the cross-client megabatch. A verifier that raises
-    (the device died mid-flight) is reported through
-    ``on_fallback(exc, n_lanes)`` — the scheduler counts it under
-    cpu_fallbacks — before the host rung re-verifies the block."""
+    frames host-gather their key rows, every one of them: no flush
+    keeps an on-device gather); the concatenated u8[128, N] block
+    verifies in one call of the row verifier — this is the cross-client
+    megabatch. A verifier that raises (the device died mid-flight) is
+    reported through ``on_fallback(exc, n_lanes)`` — the scheduler
+    counts it under cpu_fallbacks — before the host rung re-verifies
+    the block."""
     blocks: List[np.ndarray] = []
     valids: List[np.ndarray] = []
-    for req in batch:
-        rows = getattr(req, "rows", None)
-        if rows is not None:
-            w, v = rows.as_compact()
-        else:
-            w, v = pack_items_compact(req.items)
-        blocks.append(w)
-        valids.append(np.asarray(v, dtype=bool))
-    full = blocks[0] if len(blocks) == 1 else np.concatenate(blocks, axis=1)
-    valid = valids[0] if len(valids) == 1 else np.concatenate(valids)
+    with tracelib.stage("sched.rows"):
+        for req in batch:
+            rows = getattr(req, "rows", None)
+            if rows is not None:
+                w, v = rows.as_compact()
+            else:
+                w, v = pack_items_compact(req.items)
+            blocks.append(w)
+            valids.append(np.asarray(v, dtype=bool))
+        full = (
+            blocks[0] if len(blocks) == 1
+            else np.concatenate(blocks, axis=1)
+        )
+        valid = valids[0] if len(valids) == 1 else np.concatenate(valids)
     try:
         mask = np.asarray(row_verifier(full), dtype=bool)[: full.shape[1]]
     except Exception as exc:  # noqa: BLE001 - device died mid-flight: host rung
@@ -919,6 +931,11 @@ class VerifyService(BaseService):
         self._auth_ok = 0
         self._auth_rejects = 0
         self._inline_dispatches = 0
+        # every tenant together: responses enqueued, and the seconds from
+        # their frames decoded to then (the socket's legs are the
+        # client's round trip less this)
+        self._served = 0
+        self._served_s = 0.0
         # per-tenant service panel: RED + wire shape + refusal taxonomy
         self._tenant_stats: Dict[str, Dict[str, Any]] = {}
         if telemetry is not None:
@@ -1314,6 +1331,11 @@ class VerifyService(BaseService):
         ))
 
     def _handle_req(self, conn: _Conn, frame: Frame) -> None:
+        with tracelib.stage("svc.admit"):
+            self._admit(conn, frame, time.monotonic())
+
+    def _admit(self, conn: _Conn, frame: Frame, t0: float) -> None:
+        """A decoded REQ frame to ``submit_rows`` returned."""
         if self._draining:
             # graceful drain: new work is refused with a typed
             # ST_DRAINING response (clients fail over immediately
@@ -1404,7 +1426,7 @@ class VerifyService(BaseService):
             len(frame.payload) / n
         )
         if not self._coalesce:
-            self._dispatch_isolated(conn, frame, payload)
+            self._dispatch_isolated(conn, frame, payload, t0)
             return
         fut = self._sched.submit_rows(
             payload, tenant=conn.tenant, qclass=qname,
@@ -1413,14 +1435,14 @@ class VerifyService(BaseService):
         with conn.mtx:
             if not conn.alive:
                 return  # raced teardown: disconnect already metered
-            conn.pending[frame.req_id] = (n, time.monotonic())
+            conn.pending[frame.req_id] = (n, t0)
         self.metrics.pending.set(self.pending_requests())
         fut.add_done_callback(
             lambda f, c=conn, fr=frame: self._complete(c, fr, f)
         )
 
     def _dispatch_isolated(
-        self, conn: _Conn, frame: Frame, payload: RowPayload
+        self, conn: _Conn, frame: Frame, payload: RowPayload, t0: float
     ) -> None:
         """coalesce=False: verify this frame alone, in this reader
         thread — the per-client-isolated baseline the bench stage
@@ -1432,35 +1454,41 @@ class VerifyService(BaseService):
             )
         rows, valid = payload.as_compact()
         mask = np.asarray(verifier(rows), dtype=bool)[: payload.n] & valid
+        self._respond(conn, frame.req_id, ST_OK, mask)
         with self._smtx:
             self._inline_dispatches += 1
-        self._respond(conn, frame.req_id, ST_OK, mask)
+            self._served += 1
+            self._served_s += time.monotonic() - t0
 
     def _complete(self, conn: _Conn, frame: Frame, fut: VerifyFuture
                   ) -> None:
         """Done-callback on the scheduler's worker (or an inline-dispatch
         submitter): encode the verdict and hand it to the connection's
         writer — never block the flush loop on a client socket."""
-        with conn.mtx:
-            known = conn.pending.pop(frame.req_id, None)
-        self.metrics.pending.set(self.pending_requests())
-        if known is None or not conn.alive:
-            return  # disconnected mid-flight: metered in _teardown
-        try:
-            _, sub = fut.result(timeout=0)
-            mask = np.asarray(sub, dtype=bool)
-            status = ST_REJECTED if fut.rejected else ST_OK
-        except Exception:  # noqa: BLE001 - failed flush = rejected verdict
-            mask = np.zeros(frame.n_lanes, dtype=bool)
-            status = ST_REJECTED
-        _, t0 = known
-        with self._smtx:
-            rec = self._tenant(conn.tenant)
-            rec["responses"] += 1
-            rec["dur_total_s"] += time.monotonic() - t0
-            if status == ST_REJECTED:
-                rec["rejected"] += 1
-        self._respond(conn, frame.req_id, status, mask)
+        with tracelib.stage("svc.respond"):
+            with conn.mtx:
+                known = conn.pending.pop(frame.req_id, None)
+            self.metrics.pending.set(self.pending_requests())
+            if known is None or not conn.alive:
+                return  # disconnected mid-flight: metered in _teardown
+            try:
+                _, sub = fut.result(timeout=0)
+                mask = np.asarray(sub, dtype=bool)
+                status = ST_REJECTED if fut.rejected else ST_OK
+            except Exception:  # noqa: BLE001 - failed flush = rejected verdict
+                mask = np.zeros(frame.n_lanes, dtype=bool)
+                status = ST_REJECTED
+            self._respond(conn, frame.req_id, status, mask)
+            _, t0 = known
+            dur = time.monotonic() - t0
+            with self._smtx:
+                self._served += 1
+                self._served_s += dur
+                rec = self._tenant(conn.tenant)
+                rec["responses"] += 1
+                rec["dur_total_s"] += dur
+                if status == ST_REJECTED:
+                    rec["rejected"] += 1
 
     def _respond(self, conn: _Conn, req_id: int, status: int,
                  mask: np.ndarray) -> None:
@@ -1549,6 +1577,17 @@ class VerifyService(BaseService):
         except Exception:  # noqa: BLE001 - advisory header field
             return 0
 
+    def _keystore_residency(self) -> Optional[dict]:
+        """The key store's entries, generation, evictions and thrash;
+        None while nothing has imported it (a compact-only service)."""
+        ks = sys.modules.get("cometbft_tpu.crypto.tpu.keystore")
+        if ks is None:
+            return None
+        try:
+            return ks.default_store().residency()
+        except Exception:  # noqa: BLE001 - a panel, never a failure
+            return None
+
     # -- observability -----------------------------------------------------
 
     def snapshot(self) -> dict:
@@ -1592,11 +1631,14 @@ class VerifyService(BaseService):
                 "auth_ok": self._auth_ok,
                 "auth_rejects": self._auth_rejects,
                 "inline_dispatches": self._inline_dispatches,
+                "served": self._served,
+                "served_s": self._served_s,
                 "tenants_panel": panel,
             }
         out["pending"] = self.pending_requests()
         out["backend"] = getattr(self._sched.spec, "name", None)
         out["device_plane"] = resolved_device_plane()
+        out["keystore"] = self._keystore_residency()
         out["bytes_per_lane"] = {
             kind: payload_bytes[kind] / lanes[kind]
             for kind in ("compact", "indexed")
@@ -1647,13 +1689,15 @@ class _Agg:
 
 
 class _PendingPart:
-    __slots__ = ("agg", "base", "sent_idx", "deadline")
+    __slots__ = ("agg", "base", "sent_idx", "deadline", "qcode", "t_sent")
 
-    def __init__(self, agg, base, sent_idx, deadline):
+    def __init__(self, agg, base, sent_idx, deadline, qcode):
         self.agg = agg
         self.base = base
         self.sent_idx = sent_idx
         self.deadline = deadline
+        self.qcode = qcode
+        self.t_sent = 0.0  # when its frame went to the socket
 
 
 class RemoteVerifier:
@@ -1729,6 +1773,10 @@ class RemoteVerifier:
         self._max_lanes = 8192
         self._valsets: Dict[bytes, _ClientValset] = {}
         self._stats: Dict[str, int] = {}
+        # seconds from a REQ frame handed to the socket to its RESP
+        # decoded, over the ``rtts`` in _stats: with the server's
+        # served_s, what the two socket legs and the hand-offs cost
+        self._rtt_s = 0.0
         self._next_retry = 0.0
         self._connect_fails = 0
         self._auth_fails = 0
@@ -1841,8 +1889,11 @@ class RemoteVerifier:
                 with self._mtx:
                     self._req_id += 1
                     rid = self._req_id
-                    pend = _PendingPart(agg, base, sent, deadline)
+                    pend = _PendingPart(agg, base, sent, deadline, qcode)
                     self._pending[rid] = pend
+                    self._stats["req_frames"] = (
+                        self._stats.get("req_frames", 0) + 1
+                    )
                 agg.req_ids.append(rid)
                 agg.remaining += 1
                 frame = encode_frame(
@@ -1867,7 +1918,8 @@ class RemoteVerifier:
             return
         if traced:
             agg.wire_span = root.child("wire_wait", parts=len(parts))
-        for frame, _ in parts:
+        for frame, pend in parts:
+            pend.t_sent = time.monotonic()
             try:
                 self._send(frame)
             except OSError as exc:
@@ -1925,6 +1977,9 @@ class RemoteVerifier:
             self._req_id += 1
             rid = self._req_id
             self._reg_waiters[rid] = waiter
+            self._stats["register_frames"] = (
+                self._stats.get("register_frames", 0) + 1
+            )
         try:
             self._send(encode_frame(
                 FT_REGISTER, req_id=rid, n_lanes=len(keys),
@@ -2177,6 +2232,9 @@ class RemoteVerifier:
             with self._mtx:
                 self._server_gen = frame.generation
                 pend = self._pending.pop(frame.req_id, None)
+                if pend is not None:
+                    self._rtt_s += time.monotonic() - pend.t_sent
+                    self._stats["rtts"] = self._stats.get("rtts", 0) + 1
             if pend is None:
                 return
             status = frame.payload[0] if frame.payload else ST_REJECTED
@@ -2212,9 +2270,10 @@ class RemoteVerifier:
             if code == ERR_STALE_GENERATION:
                 # every cached valset registered under an older
                 # generation is now suspect; the next submit
-                # re-registers (resync) before going indexed again
+                # re-registers (resync) before going indexed again, and
+                # the refused lanes go again now, as compact rows
                 self._count("stale")
-                if pend is not None:
+                if pend is not None and not self._resend_compact(pend):
                     self._fail_agg(pend.agg, "stale")
                 return
             if code == ERR_UNAUTHORIZED:
@@ -2237,6 +2296,38 @@ class RemoteVerifier:
             self._count(f"err_{ERR_NAMES.get(code, code)}")
             if pend is not None:
                 self._fail_agg(pend.agg, "error")
+
+    def _resend_compact(self, pend: _PendingPart) -> bool:
+        """The lanes of an indexed frame the server refused as stale,
+        sent again as 128 B compact rows under a new req_id and the same
+        deadline: the server then needs no registration of ours. False
+        where a lane does not pack (the caller's local rung decides
+        then); True once the frame is sent or the connection's failure
+        path owns the request."""
+        agg = pend.agg
+        items = [agg.items[pend.base + int(i)] for i in pend.sent_idx]
+        wire, valid = pack_items_compact(items)
+        if not valid.all():
+            return False
+        with agg.mtx:
+            if agg.failed or agg.future.done():
+                return True
+            with self._mtx:
+                self._req_id += 1
+                rid = self._req_id
+                self._pending[rid] = pend
+                for key in ("stale_resends", "req_frames"):
+                    self._stats[key] = self._stats.get(key, 0) + 1
+            agg.req_ids.append(rid)
+        pend.t_sent = time.monotonic()
+        try:
+            self._send(encode_frame(
+                FT_REQ, qclass=pend.qcode, kind=KIND_COMPACT, req_id=rid,
+                n_lanes=len(items), payload=wire.tobytes(),
+            ))
+        except OSError:
+            self._on_disconnect()
+        return True
 
     def _complete_part(self, pend: _PendingPart, bits: np.ndarray) -> None:
         agg = pend.agg
@@ -2424,4 +2515,5 @@ class RemoteVerifier:
                     "retry_cap_s": self._retry_cap_s,
                 },
                 "stats": dict(self._stats),
+                "rtt_s": self._rtt_s,
             }

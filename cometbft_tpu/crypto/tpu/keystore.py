@@ -15,14 +15,27 @@ Two consumers, one store:
   on-device table (`ed25519_batch.verify_kernel_indexed`).
 
 Generations make staleness impossible to verify against: every entry
-is stamped with the store generation (bumped on every upload and
-invalidation) and the device-topology generation it was built under.
-A valset rotation produces a different valset_id (miss), an explicit
-`invalidate` drops entries, and a topology generation bump — quarantine
-re-slice, fault-domain change — makes every older entry undispatchable:
-`get` drops it and rebuilds, `verify_batch_indexed` refuses it
+is stamped with its upload's sequence number and the device-topology
+generation it was built under. A valset rotation produces a different
+valset_id (miss), an explicit `invalidate` drops entries, and a
+topology generation bump — quarantine re-slice, fault-domain change —
+makes every older entry undispatchable: `get` and `register` drop it
+and rebuild, `verify_batch_indexed` and `entry_for` refuse it
 (`stale_drops`). A stale-generation dispatch therefore MISSES; it never
 verifies against old keys or an old device slicing.
+
+The STORE generation (`generation()`, the verify service's handshake
+token) counts what can make a registration somebody holds wrong: every
+entry that LEAVES the store (invalidation, LRU eviction, a topology-
+stale drop). An insert does not move it: valset ids are content-
+addressed, so new keys beside a client's cannot change what its id
+names, and 32 light clients on 8 chains re-registered for ever while
+every insert staled every client (PERF.md, PR 30).
+
+Two bounds, by what an entry holds: CACHE_MAX entries with rows on the
+device (the 10,000-validator resident sets, ~2.5 MB of HBM each), and
+HOST_KEYS_MAX key rows over the service's host-only registrations
+(4.8 KB a 150-validator chain), each evicted LRU within its own kind.
 """
 
 from __future__ import annotations
@@ -35,8 +48,13 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 # ~10k vals x 256B x 4 = 10 MB of HBM at most (chunks) plus the
-# indexed tables (32 B/key) on top — still < 2 MB per 10k-val entry
+# indexed tables (32 B/key) on top — still < 2 MB per 10k-val entry.
+# Counts the entries that hold device rows.
 CACHE_MAX = 4
+# key rows over all host-only registrations (service.MAX_REGISTER_KEYS a
+# frame, so CACHE_MAX of the largest): 2 MB of rows and their indexes,
+# or 436 chains of 150 validators
+HOST_KEYS_MAX = CACHE_MAX * 16_384
 
 
 class KeyStoreEntry:
@@ -92,6 +110,7 @@ class DeviceKeyStore:
         # race adopts the winner's rows.
         self._mtx = threading.Lock()
         self._max = int(max_entries)
+        self._max_host_keys = HOST_KEYS_MAX
         self._gen = 0
         self._stats = {
             "hits": 0,
@@ -101,30 +120,58 @@ class DeviceKeyStore:
             "stale_drops": 0,
             "indexed_dispatches": 0,
             "indexed_lanes": 0,
+            "evictions": 0,
             # LRU evictions of entries that never served a single use:
             # the churn-thrash signal (valsets rotating faster than
             # flushes drain the cache)
             "keystore_thrash": 0,
         }
 
+    @staticmethod
+    def _host_only(e: KeyStoreEntry) -> bool:
+        return (getattr(e, "table_dev", None) is None
+                and not getattr(e, "chunks", None))
+
+    def _removed_locked(self, n: int = 1) -> None:
+        """``n`` entries left the store: whoever holds a registration
+        from before must resync (the handshake's generation)."""
+        self._gen += n
+
     def _evict_excess_locked(self) -> None:
-        """LRU eviction that honors pins: an in-flight indexed dispatch
-        pins its entry, so per-height valset rotation can never yank the
-        incoming table out from under a flush mid-dispatch. If every
-        entry is pinned the cache overflows temporarily (unpin resumes
-        eviction). An evicted entry that never served a hit counts as
+        """LRU eviction, each kind within its own bound (device entries
+        by count, host-only registrations by key rows), that honors
+        pins: an in-flight indexed dispatch pins its entry, so
+        per-height valset rotation can never yank the incoming table out
+        from under a flush mid-dispatch. If every entry of a kind is
+        pinned it overflows temporarily (unpin resumes eviction). An
+        evicted entry that never served a hit counts as
         ``keystore_thrash``."""
-        while len(self._entries) > self._max:
+        device = host_keys = 0
+        for e in self._entries.values():
+            if self._host_only(e):
+                host_keys += getattr(e, "n", 0)
+            else:
+                device += 1
+        while device > self._max or host_keys > self._max_host_keys:
+            over_host = host_keys > self._max_host_keys
             victim_id = None
             for vid, e in self._entries.items():  # oldest first
-                if getattr(e, "pins", 0) <= 0:
+                if getattr(e, "pins", 0) > 0:
+                    continue
+                if over_host if self._host_only(e) else device > self._max:
                     victim_id = vid
                     break
             if victim_id is None:
                 return
             e = self._entries.pop(victim_id)
+            if self._host_only(e):
+                host_keys -= getattr(e, "n", 0)
+            else:
+                device -= 1
+            self._stats["evictions"] += 1
             if getattr(e, "hits", 0) == 0:
                 self._stats["keystore_thrash"] += 1
+            self._removed_locked()
 
     def get(self, valset_id: bytes, pub_keys, build) -> KeyStoreEntry:
         """Resident entry for valset_id, building (slow H2D, outside the
@@ -142,6 +189,7 @@ class DeviceKeyStore:
                     return e
                 del self._entries[valset_id]
                 self._stats["stale_drops"] += 1
+                self._removed_locked()
             self._stats["misses"] += 1
         e = build(pub_keys)  # slow: H2D upload — outside the lock
         e.valset_id = bytes(valset_id)
@@ -153,12 +201,11 @@ class DeviceKeyStore:
                 # duplicate upload at most, never a corrupted LRU)
                 self._entries.move_to_end(valset_id)
                 return won
-            self._gen += 1
-            e.generation = self._gen
+            self._stats["uploads"] += 1
+            e.generation = self._stats["uploads"]
             e.hits = getattr(e, "hits", 0)
             e.pins = getattr(e, "pins", 0)
             self._entries[valset_id] = e
-            self._stats["uploads"] += 1
             self._evict_excess_locked()
         return e
 
@@ -211,12 +258,12 @@ class DeviceKeyStore:
             for vid in stale:
                 del self._entries[vid]
                 self._stats["stale_drops"] += 1
+            self._removed_locked(len(stale))
             return list(reversed(self._entries.values()))
 
     def invalidate(self, valset_id: Optional[bytes] = None) -> int:
         """Drop one entry (or all, valset_id=None). Bumps the store
-        generation so a snapshot taken before and after can't be
-        confused."""
+        generation: a client that registered before must resync."""
         with self._mtx:
             if valset_id is None:
                 dropped = len(self._entries)
@@ -226,7 +273,7 @@ class DeviceKeyStore:
                     self._entries.pop(valset_id, None) is not None
                 )
             if dropped:
-                self._gen += 1
+                self._removed_locked(dropped)
                 self._stats["invalidations"] += dropped
         return dropped
 
@@ -254,7 +301,8 @@ class DeviceKeyStore:
         """Current store generation — the freshness token of the verify
         service's indexed-frame handshake (stamped on HELLO/RESP frames;
         a client whose cached value diverges must re-register before
-        shipping 100 B indexed rows again)."""
+        shipping 100 B indexed rows again). It counts the entries that
+        have left the store; an insert leaves it alone."""
         with self._mtx:
             return self._gen
 
@@ -262,16 +310,22 @@ class DeviceKeyStore:
                   generation: Optional[int] = None) -> Optional[KeyStoreEntry]:
         """Frame-accept-time lookup for the verify service: the entry
         for ``valset_id``, but ONLY while the client's cached store
-        generation matches the store's — a stale client is refused
-        (``stale_drops`` counted) and falls back to full 128 B compact
-        rows rather than ever verifying against a key space it has not
-        resynced with."""
+        generation matches the store's and the entry was built under the
+        current topology — a stale client is refused (``stale_drops``
+        counted) and falls back to full 128 B compact rows rather than
+        ever verifying against a key space it has not resynced with."""
         vid = bytes(valset_id)
+        topo_gen = _topo_generation()
         with self._mtx:
+            e = self._entries.get(vid)
+            if e is not None and e.topo_generation != topo_gen:
+                del self._entries[vid]
+                self._stats["stale_drops"] += 1
+                self._removed_locked()
+                return None
             if generation is not None and generation != self._gen:
                 self._stats["stale_drops"] += 1
                 return None
-            e = self._entries.get(vid)
             if e is None:
                 return None
             self._entries.move_to_end(vid)
@@ -283,25 +337,31 @@ class DeviceKeyStore:
         """Host-side registration for the verify service's generation
         handshake: build (or reuse) an entry carrying only the host key
         rows + index — ``table_dev`` stays None, and the device-dispatch
-        probes above skip such entries — and bump the store generation
-        on insert, so every remote client's cached generation goes stale
-        exactly when the key space changes. Malformed-length keys get a
-        zeroed row with ``pk_ok`` False (refused at verify, like the
-        device build does)."""
+        probes above skip such entries. The insert leaves the store
+        generation alone (the id is the keys' own digest; only an entry
+        LEAVING can stale a client), an entry from an older topology is
+        dropped and rebuilt. Malformed-length keys get a zeroed row with
+        ``pk_ok`` False (refused at verify, like the device build
+        does)."""
         vid = bytes(valset_id)
+        topo_gen = _topo_generation()
         with self._mtx:
             e = self._entries.get(vid)
-            if e is not None:
+            if e is not None and e.topo_generation == topo_gen:
                 self._entries.move_to_end(vid)
                 self._stats["hits"] += 1
                 e.hits = getattr(e, "hits", 0) + 1
                 return e
+            if e is not None:
+                del self._entries[vid]
+                self._stats["stale_drops"] += 1
+                self._removed_locked()
             self._stats["misses"] += 1
         keys = [_key_bytes(pk) for pk in pub_keys]
         n = len(keys)
         e = KeyStoreEntry()
         e.valset_id = vid
-        e.topo_generation = _topo_generation()
+        e.topo_generation = topo_gen
         e.chunks = []
         e.plan = None
         e.pk_arr = np.zeros((n, 32), np.uint8)
@@ -321,10 +381,9 @@ class DeviceKeyStore:
             if won is not None:
                 self._entries.move_to_end(vid)
                 return won
-            self._gen += 1
-            e.generation = self._gen
-            self._entries[vid] = e
             self._stats["uploads"] += 1
+            e.generation = self._stats["uploads"]
+            self._entries[vid] = e
             self._evict_excess_locked()
         return e
 
@@ -347,6 +406,7 @@ class DeviceKeyStore:
                 "generation": self._gen,
                 "hit_rate": (hits / lookups) if lookups else None,
                 "indexed_dispatches": self._stats["indexed_dispatches"],
+                "evictions": self._stats["evictions"],
                 "thrash": self._stats["keystore_thrash"],
             }
 

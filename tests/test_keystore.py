@@ -254,7 +254,13 @@ class TestSnapshotPlumbing:
 class TestChurnThrash:
     """PR 18: valset churn faster than flushes drain the cache must
     never yank an in-flight table (pins) and must be visible as the
-    ``keystore_thrash`` counter (evictions of never-hit entries)."""
+    ``keystore_thrash`` counter (evictions of never-hit entries).
+    PR 30: host-only registrations are bounded by key rows, not by the
+    device entries' count; here the bound is CACHE_MAX three-key sets."""
+
+    @pytest.fixture(autouse=True)
+    def _room_for_cache_max_sets(self, store, monkeypatch):
+        monkeypatch.setattr(store, "_max_host_keys", 3 * keystore.CACHE_MAX)
 
     def _pks(self, tag, n=3):
         return [hashlib.sha256(tag + b"-%d" % i).digest()
@@ -323,3 +329,90 @@ class TestChurnThrash:
             held = set(store._entries.keys())
         assert all(v not in held for v in vids), "all churned out"
         assert store.residency()["thrash"] == base
+
+
+class TestRegistrationFreshness:
+    """PR 30: the handshake's generation counts entries that LEAVE the
+    store; an insert beside a client's registration stales nobody, and
+    the service's 4.8 KB host-only registrations do not compete with the
+    device entries for CACHE_MAX slots."""
+
+    def _pks(self, tag, n=150):
+        return [hashlib.sha256(tag + b"-%d" % i).digest()
+                for i in range(n)]
+
+    def _register(self, store, tag, n=150):
+        pks = self._pks(tag, n)
+        vid = hashlib.sha256(b"".join(pks)).digest()[:16]
+        store.register(vid, pks)
+        return vid
+
+    def test_eight_chains_fit_and_an_insert_stales_nobody(self, store):
+        gen = store.generation()
+        base = store.snapshot()["stats"]
+        vids = [self._register(store, b"chain-%d" % c) for c in range(8)]
+        assert store.generation() == gen
+        stats = store.snapshot()["stats"]
+        assert stats["evictions"] == base["evictions"]
+        assert stats["keystore_thrash"] == base["keystore_thrash"]
+        for vid in vids:  # the first registered is as fresh as the last
+            assert store.entry_for(vid, gen) is not None
+        uploads = [e["generation"] for e in store.snapshot()["entries"]]
+        assert len(set(uploads)) == 8, "each upload keeps its own number"
+
+    def test_host_registrations_leave_the_device_entries_alone(self, store):
+        keys, pks, vid = _valset(3, b"dev-entry")
+        _resident(vid, pks, keys)
+        for c in range(keystore.CACHE_MAX + 4):
+            self._register(store, b"many-%d" % c)
+        with store._mtx:
+            assert vid in store._entries
+            assert len(store._entries) == keystore.CACHE_MAX + 5
+        # and a host registration is never the victim of device pressure
+        first = self._register(store, b"many-0")
+        for i in range(keystore.CACHE_MAX + 1):
+            k, p, v = _valset(3, b"dev-press-%d" % i)
+            _resident(v, p, k)
+        with store._mtx:
+            assert first in store._entries
+            assert vid not in store._entries, "oldest device entry evicted"
+
+    def test_an_eviction_stales_every_client(self, store, monkeypatch):
+        monkeypatch.setattr(store, "_max_host_keys", 2 * 150)
+        a = self._register(store, b"ev-a")
+        b = self._register(store, b"ev-b")
+        gen = store.generation()
+        evictions = store.snapshot()["stats"]["evictions"]
+        self._register(store, b"ev-c")  # evicts a
+        assert store.generation() == gen + 1
+        assert store.snapshot()["stats"]["evictions"] == evictions + 1
+        assert store.entry_for(b, gen) is None, "b's client must resync"
+        assert store.entry_for(b, gen + 1) is not None
+        assert store.entry_for(a, gen + 1) is None
+
+    def test_invalidate_refuses_the_old_generation(self, store):
+        vid = self._register(store, b"inv")
+        gen = store.generation()
+        assert store.entry_for(vid, gen) is not None
+        assert store.invalidate(vid) == 1
+        before = store.snapshot()["stats"]["stale_drops"]
+        assert store.entry_for(vid, gen) is None
+        assert store.snapshot()["stats"]["stale_drops"] == before + 1
+        assert store.generation() == gen + 1
+
+    def test_topology_bump_refuses_and_register_rebuilds(self, store):
+        vid = self._register(store, b"topo-reg")
+        gen = store.generation()
+        topo = topology.default_topology()
+        assert topo.set_quarantined(0, True)
+        before = store.snapshot()["stats"]["stale_drops"]
+        assert store.entry_for(vid, gen) is None
+        assert store.snapshot()["stats"]["stale_drops"] == before + 1
+        assert store.generation() == gen + 1
+        with store._mtx:
+            assert vid not in store._entries
+        again = self._register(store, b"topo-reg")
+        assert again == vid
+        entry = store.entry_for(vid, store.generation())
+        assert entry is not None
+        assert entry.topo_generation == topo.generation()

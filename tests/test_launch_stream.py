@@ -32,6 +32,8 @@ from cometbft_tpu.libs import trace as tracelib
 N = 200
 CAP = 64
 SPANS = [(0, 64), (64, 128), (128, 192), (192, 200)]
+# a flush cut by a launch size under the ceiling: the short launch first
+SHORT_FIRST = [(0, 8), (8, 72), (72, 136), (136, 200)]
 SPOILED = (5, 70, 199)
 TABLE_KEYS = 40  # the indexed entry's valset: a 64-row table, lanes repeat
 
@@ -55,11 +57,12 @@ def lanes():
 class _Rows:
     """dispatch_rows' ``rows`` with a hook in the slice of a launch."""
 
-    def __init__(self, arr, hook):
+    def __init__(self, arr, hook, spans):
         self._arr, self._hook, self.shape = arr, hook, arr.shape
+        self._spans = spans
 
     def __getitem__(self, key):
-        self._hook(SPANS.index((key[1].start, key[1].stop)))
+        self._hook(self._spans.index((key[1].start, key[1].stop)))
         return self._arr[key]
 
 
@@ -157,11 +160,10 @@ def entry(request, monkeypatch, lanes):
             monkeypatch.setattr(eb, "_LAUNCH_LANES", CAP)
             pks = [k.pub_key().bytes() for k in keys]
             real = eb.prepare_batch_compact
-            short_first = [(0, 8), (8, 72), (72, 136), (136, 200)]
 
             def run(hook):
                 def prepare(lane_pks, lane_msgs, lane_sigs):
-                    hook(short_first.index(
+                    hook(SHORT_FIRST.index(
                         (pks.index(lane_pks[0]),
                          pks.index(lane_pks[-1]) + 1)))
                     return real(lane_pks, lane_msgs, lane_sigs)
@@ -171,15 +173,19 @@ def entry(request, monkeypatch, lanes):
                     return eb.verify_batch(pks, msgs, sigs, hash="host")
 
             yield _Entry("single", "dev0", "mesh", [64] * 4, run, truth,
-                         spans=short_first)
+                         spans=SHORT_FIRST)
         else:
+            # verifyd's rows are cut as the keyed flush is (PR 30)
+            monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", str(4 * CAP))
+            monkeypatch.setattr(eb, "_LAUNCH_LANES", CAP)
             wire, valid = eb.prepare_batch_compact(
                 [k.pub_key().bytes() for k in keys], msgs, sigs)
             assert valid.all()
             yield _Entry(
                 "service", "dev0", "mesh", [64] * 4,
-                lambda hook: servicelib.dispatch_rows(_Rows(wire, hook)),
-                truth)
+                lambda hook: servicelib.dispatch_rows(
+                    _Rows(wire, hook, SHORT_FIRST)),
+                truth, spans=SHORT_FIRST)
     finally:
         eb._keystore.invalidate()
         topology.set_default_topology(before)

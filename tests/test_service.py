@@ -592,29 +592,44 @@ class TestGenerationHandshake:
             assert snap["lanes"].get("indexed", 0) == 8
             assert snap["bytes_per_lane"]["indexed"] == 100.0
 
-            # the key space changes behind the client's back: another
-            # valset lands, bumping the store generation
+            # another valset lands beside the client's: the id names
+            # keys, so nobody's registration goes stale (PR 30)
             other = [
                 ed.gen_priv_key_from_secret(b"gen-bump-%d" % i)
                 .pub_key().bytes()
                 for i in range(4)
             ]
             import hashlib
-            store.register(
-                hashlib.sha256(b"".join(other)).digest()[:16], other
-            )
-
-            # stale submit: the server REFUSES the indexed frame (typed
-            # stale_generation, stale_drops metered), the client
-            # resolves via local fallback with the distinct reason
+            other_id = hashlib.sha256(b"".join(other)).digest()[:16]
+            gen = store.generation()
+            store.register(other_id, other)
+            assert store.generation() == gen
             fut = client.submit(items, subsystem="consensus")
             ok, mask = fut.result(timeout=30)
-            assert fut.reason == "stale"
+            assert getattr(fut, "reason", None) is None
+            assert not ok and mask == want
+            assert client.stats().get("registrations", 0) == 1
+            assert d.service.snapshot()["lanes"]["indexed"] == 16
+
+            # the key space changes behind the client's back: a valset
+            # LEAVES the store, bumping the generation
+            assert store.invalidate(other_id) == 1
+            assert store.generation() == gen + 1
+
+            # stale submit: the server REFUSES the indexed frame (typed
+            # stale_generation, stale_drops metered) and the client sends
+            # the same lanes again as compact rows: served by the daemon,
+            # no local fallback
+            fut = client.submit(items, subsystem="consensus")
+            ok, mask = fut.result(timeout=30)
+            assert getattr(fut, "reason", None) is None
             assert not ok and mask == want
             assert client.stats().get("stale", 0) >= 1
+            assert client.stats().get("stale_resends", 0) == 1
             snap = d.service.snapshot()
             assert snap["stale_drops"] >= 1
             assert snap["errors"].get("stale_generation", 0) >= 1
+            assert snap["lanes"].get("compact", 0) == 8
 
             # next submit resyncs (re-register at the new generation)
             # and goes indexed again — never stuck on the fallback
@@ -624,11 +639,18 @@ class TestGenerationHandshake:
             assert not ok and mask == want
             assert client.stats().get("registrations", 0) == 2
             snap = d.service.snapshot()
-            assert snap["lanes"]["indexed"] == 16
+            assert snap["lanes"]["indexed"] == 24
             assert snap["bytes_per_lane"]["indexed"] == 100.0
-            # compact was never needed: the resync happened client-side
-            # before framing, so every lane stayed <= 100 B
             assert all(v <= 128.0 for v in snap["bytes_per_lane"].values())
+            # the books a fleet's cell reads: every response timed on
+            # both sides, every frame counted
+            assert snap["served"] == 4 and snap["served_s"] > 0
+            assert snap["keystore"]["generation"] == gen + 1
+            stats = client.snapshot()
+            assert stats["stats"]["req_frames"] == 5
+            assert stats["stats"]["register_frames"] == 2
+            assert stats["stats"]["rtts"] == 4
+            assert stats["rtt_s"] >= snap["served_s"]
         finally:
             d.stop()
 

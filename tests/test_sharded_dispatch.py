@@ -276,14 +276,16 @@ class TestShardChunks:
     @pytest.mark.parametrize("route", ["indexed", "service"])
     def test_the_one_chip_entries_round_here_too(self, monkeypatch, route):
         """The indexed key store and verifyd's rows hand the stream the
-        rule's launches with one shard, and round nothing themselves."""
+        rule's launches with one shard, and round nothing themselves:
+        the store under the chunk cap, the rows under the keyed route's
+        launch size with its short-launch floor (PR 30)."""
         from cometbft_tpu.crypto import service as servicelib
         from cometbft_tpu.crypto.tpu import ed25519_batch as eb, keystore
 
         asked = []
 
-        def odd_rule(n, n_shards, cap, min_pad):
-            asked.append((n, n_shards, cap, min_pad))
+        def odd_rule(n, n_shards, cap, min_pad, short_floor=0):
+            asked.append((n, n_shards, cap, min_pad, short_floor))
             return [(s, min(s + 40, n), 48) for s in range(0, n, 40)]
 
         handed = {}
@@ -310,7 +312,10 @@ class TestShardChunks:
         finally:
             eb._keystore.invalidate()
         assert handed == {route: [(0, 40, 48), (40, 80, 48), (80, 100, 48)]}
-        assert asked[-1] == (100, 1, mesh.chunk_cap(8192, 64), 64)
+        assert asked[-1] == {
+            "indexed": (100, 1, mesh.chunk_cap(8192, 64), 64, 0),
+            "service": (100, 1, eb._LAUNCH_LANES, 64, eb._LAUNCH_LANES // 2),
+        }[route]
 
     def test_warm_plan_and_dispatch_arithmetic_lockstep(self):
         # the zero-compiles-after-warm guarantee: every launch of every
