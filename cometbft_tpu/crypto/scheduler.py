@@ -369,11 +369,11 @@ class _Request:
         self.height = height
         # the priority class the subsystem tag resolved to
         self.qclass = qclass
-        # verify-service requests arrive as pre-packed wire rows
-        # (service.RowPayload) instead of (pk, msg, sig) triples; the
-        # socket bytes ARE the dispatch payload (zero double-
-        # marshalling), so ``items`` stays empty and every size
-        # accounting goes through ``n_lanes``
+        # verify-service requests arrive as finished compact lanes
+        # (service.RowPayload, built where the frame was admitted)
+        # instead of (pk, msg, sig) triples; the socket's rows ARE the
+        # dispatch payload (zero double-marshalling), so ``items`` stays
+        # empty and every size accounting goes through ``n_lanes``
         self.rows = rows
 
     @property
@@ -843,8 +843,9 @@ class VerifyScheduler(BaseService):
         trace_ctx=None,
     ) -> VerifyFuture:
         """Queue a verify-service row payload (service.RowPayload — the
-        client's pre-packed compact/indexed wire rows, the exact socket
-        bytes) for the next coalesced dispatch. Runs the SAME admission
+        client's pre-packed compact/indexed wire rows as 128 B compact
+        lanes, an indexed frame's key rows already gathered) for the
+        next coalesced dispatch. Runs the SAME admission
         ladder as ``submit`` — brownout, per-tenant quota, lane
         backpressure — keyed on the remote tenant, with the QoS class
         taken from the frame header (untagged resolves to the top class,
@@ -1378,9 +1379,10 @@ class VerifyScheduler(BaseService):
 
     def _verify_rows(self, batch: List[_Request]) -> List[bool]:
         """Verify a coalesced flush carrying row payloads: the requests'
-        wire rows (plus any triple riders, packed once into the same
-        layout) concatenate into ONE compact megabatch for the row
-        verifier — the cross-client coalescing dispatch. The lazy import
+        compact blocks, finished where their frames were admitted (plus
+        any triple riders, packed once into the same layout), join into
+        ONE compact megabatch for the row verifier — the cross-client
+        coalescing dispatch (stage ``sched.rows``). The lazy import
         mirrors how the service imports the scheduler: neither pays for
         the other unless row traffic actually flows."""
         from cometbft_tpu.crypto import service as servicelib

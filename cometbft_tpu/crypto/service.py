@@ -22,8 +22,11 @@ PR 13 wire format. The client packs compact u8[128,B] rows (or 100 B
 indexed rows when its cached keystore generation matches the server's)
 exactly once via ``ed25519_batch.prepare_batch_compact`` /
 ``_prepare_rsh_compact`` — the same ``pack_compact_rows`` plane layout
-the kernels consume — and the server ``device_put``s those same bytes.
-Nothing is ever re-marshalled into triples on the server.
+the kernels consume — and the server ``device_put``s those same rows:
+a compact frame's payload as it is, an indexed frame's behind its key
+rows (gathered where the frame is admitted, on its reader thread), the
+requests of a flush interleaved row by row. Nothing is ever
+re-marshalled into triples on the server.
 
 Frame protocol (length-prefixed binary, no external deps):
 
@@ -581,41 +584,65 @@ def pack_items_indexed(
 
 
 class RowPayload:
-    """One client frame's rows as the scheduler carries them: the exact
-    socket bytes (never re-marshalled into triples), plus — for indexed
-    frames — the resident keystore entry the indices address. The entry
-    OBJECT rides along (valset ids are content-addressed), so a
-    concurrent LRU eviction cannot swap the keys out from under an
-    admitted request; the generation check is a frame-accept-time
-    freshness protocol only."""
+    """One client frame's rows as the scheduler carries them: the
+    request's FINISHED compact block and its valid mask, built where the
+    frame was admitted (the connection's reader thread, stage
+    ``svc.gather``) and not touched again until a flush joins them.
 
-    __slots__ = ("kind", "wire", "idx", "entry", "valset_id", "n")
+    ``rows`` is the u8[128, n] block A‖R‖S‖h as ``128 * n`` bytes in
+    the compact wire's own C order: a compact frame's payload (the
+    stale resend's too) IS it, untouched; an indexed frame's is its key
+    rows, gathered and transposed, in front of its R‖S‖h rows as they
+    crossed the socket. ``valid`` is ``n`` bytes, one 0/1 a lane: 0
+    where the registered key the lane's index addresses is malformed
+    (``pk_ok`` false), which the flush refuses whatever the kernel says.
 
-    def __init__(self, kind: int, wire: np.ndarray,
-                 idx: Optional[np.ndarray] = None, entry=None,
-                 valset_id: bytes = b""):
-        self.kind = kind
-        self.wire = wire
-        self.idx = idx
-        self.entry = entry
-        self.valset_id = valset_id
-        self.n = int(wire.shape[1])
+    An indexed frame's key rows are COPIED out of the key-store entry
+    when the frame is accepted, so an entry evicted or invalidated
+    between admission and flush cannot change the keys a request is
+    verified against: the guarantee the entry object riding along used
+    to give, by simpler means (a copy needs no reference). The
+    generation check is a frame-accept-time freshness protocol only."""
+
+    __slots__ = ("rows", "valid", "n")
+
+    def __init__(self, rows: bytes, valid: bytes):
+        self.rows = rows
+        self.valid = valid
+        self.n = len(valid)
+
+    @classmethod
+    def from_compact(cls, wire: bytes) -> "RowPayload":
+        """A compact frame's payload: no gather and no copy."""
+        return cls(wire, b"\x01" * (len(wire) // COMPACT_ROW_BYTES))
+
+    @classmethod
+    def from_indexed(cls, rsh: bytes, idx: np.ndarray, entry
+                     ) -> "RowPayload":
+        """An indexed frame's u8[96, n] R‖S‖h rows (their bytes) and its
+        bounds-checked table indices into ``entry``: the host gather of
+        the key rows, 32 bytes a lane, and of their ``pk_ok`` bits. At a
+        light client's size (~100 lanes) none of these calls gives the
+        GIL up, so a reader builds its block without waiting its turn
+        again (PERF.md, PR 31)."""
+        return cls(
+            entry.pk_arr[idx].T.tobytes() + rsh,
+            np.asarray(entry.pk_ok[idx], dtype=bool).tobytes(),
+        )
 
     def as_compact(self) -> Tuple[np.ndarray, np.ndarray]:
-        """(u8[128, n] compact rows, valid mask). Indexed payloads
-        host-gather their pubkey rows from the carried entry: 32 bytes a
-        lane copied on the flush thread (``sched.rows``). Every flush
-        takes this path today (verify_mixed_flush): the 100 B rows save
-        the socket's bytes and the client's key packing, and the device
-        is sent 128 B a lane either way."""
-        if self.kind == KIND_COMPACT:
-            return self.wire, np.ones(self.n, dtype=bool)
-        rows = self.entry.pk_arr[self.idx]          # [n, 32] host gather
-        valid = np.asarray(self.entry.pk_ok[self.idx], dtype=bool).copy()
-        wire = np.empty((COMPACT_ROW_BYTES, self.n), np.uint8)
-        wire[:32] = rows.T
-        wire[32:] = self.wire
-        return wire, valid
+        """(u8[128, n] compact rows, valid mask): read-only views of what
+        admission built; nothing is gathered or copied here."""
+        return _block_views(self.rows, self.valid)
+
+
+def _block_views(rows: bytes, valid: bytes) -> Tuple[np.ndarray, np.ndarray]:
+    """C-order block bytes as the u8[128, N] array the row verifiers
+    take, and the bool[N] mask beside it."""
+    return (
+        np.frombuffer(rows, np.uint8).reshape(COMPACT_ROW_BYTES, len(valid)),
+        np.frombuffer(valid, dtype=bool),
+    )
 
 
 # -- row verification (host ground truth + device dispatch) ------------------
@@ -687,18 +714,19 @@ class CachingRowVerifier:
 
 
 def dispatch_rows(rows: np.ndarray) -> np.ndarray:
-    """Device dispatch of concatenated compact wire columns — the
-    zero-double-marshalling half of the tentpole: the u8[128, B] bytes
-    that crossed the socket (indexed frames: after verify_mixed_flush
-    gathered their key rows on the host) are the bytes ``device_put``
-    here. One launch_stream on jax's default chip, cut as the keyed
-    flush is (ed25519_batch.verify_batch through mesh.dispatch_batch):
-    launches of _LAUNCH_LANES with the one short launch first and padded
-    to at least half of one, so a flush of any length reaches the shapes
-    that route warms plus the small buckets, and the slices of launch
-    k+1 are padded and sent while launch k runs. Every launch is
-    attributed into the wire ledger under the "service" route so
-    bytes-per-lane is provable from /debug/verify."""
+    """Device dispatch of a flush's compact wire columns — the
+    zero-double-marshalling half of the tentpole: the u8[128, B] block
+    is the requests' rows as admission left them (a compact frame's
+    socket bytes; an indexed frame's behind its key rows, gathered on
+    the reader thread), interleaved by verify_mixed_flush into one
+    contiguous array. One launch_stream on jax's default chip, cut as
+    the keyed flush is (ed25519_batch.verify_batch through
+    mesh.dispatch_batch): launches of _LAUNCH_LANES with the one short
+    launch first and padded to at least half of one, so a flush of any
+    length reaches the shapes that route warms plus the small buckets,
+    and the slices of launch k+1 are padded and sent while launch k
+    runs. Every launch is attributed into the wire ledger under the
+    "service" route so bytes-per-lane is provable from /debug/verify."""
     from cometbft_tpu.crypto.tpu import ed25519_batch as ed
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
@@ -747,33 +775,59 @@ def resolve_row_verifier(spec=None) -> Callable[[np.ndarray], np.ndarray]:
     return host_row_verifier()
 
 
+def assemble_flush(batch) -> Tuple[np.ndarray, np.ndarray, int]:
+    """(u8[128, N] block, bool[N] valid, row requests) of one flush's
+    requests, in request order. A row request contributes the block its
+    admission built; a triple rider is packed here, once, into the same
+    layout. Row ``i`` of the megabatch is row ``i`` of every block end
+    to end, so the blocks interleave: ``128 x requests`` byte slices and
+    one ``bytes.join``, all under the GIL (a join of 1 MiB or more gives
+    it up once). The flush thread makes no numpy call per request, and
+    gives the GIL up a number of times that does not grow with the
+    requests it carries; a one-request flush copies nothing."""
+    blocks: List[Tuple[bytes, int]] = []
+    valids: List[bytes] = []
+    prebuilt = 0
+    for req in batch:
+        rows = getattr(req, "rows", None)
+        if rows is not None:
+            blocks.append((rows.rows, rows.n))
+            valids.append(rows.valid)
+            prebuilt += 1
+        else:
+            w, v = pack_items_compact(req.items)
+            blocks.append((w.tobytes(), len(v)))
+            valids.append(v.tobytes())
+    if len(blocks) == 1:
+        wire = blocks[0][0]
+    else:
+        wire = b"".join([
+            blk[i * n:(i + 1) * n]
+            for i in range(COMPACT_ROW_BYTES) for blk, n in blocks
+        ])
+    full, valid = _block_views(wire, b"".join(valids))
+    return full, valid, prebuilt
+
+
 def verify_mixed_flush(batch, row_verifier, on_fallback=None) -> List[bool]:
     """Verdict mask for one coalesced flush that contains at least one
-    row-payload request. Triple requests pack ONCE into the same compact
-    layout; row requests contribute their exact socket bytes (indexed
-    frames host-gather their key rows, every one of them: no flush
-    keeps an on-device gather); the concatenated u8[128, N] block
-    verifies in one call of the row verifier — this is the cross-client
-    megabatch. A verifier that raises (the device died mid-flight) is
-    reported through ``on_fallback(exc, n_lanes)`` — the scheduler
-    counts it under cpu_fallbacks — before the host rung re-verifies
-    the block."""
-    blocks: List[np.ndarray] = []
-    valids: List[np.ndarray] = []
-    with tracelib.stage("sched.rows"):
-        for req in batch:
-            rows = getattr(req, "rows", None)
-            if rows is not None:
-                w, v = rows.as_compact()
-            else:
-                w, v = pack_items_compact(req.items)
-            blocks.append(w)
-            valids.append(np.asarray(v, dtype=bool))
-        full = (
-            blocks[0] if len(blocks) == 1
-            else np.concatenate(blocks, axis=1)
-        )
-        valid = valids[0] if len(valids) == 1 else np.concatenate(valids)
+    row-payload request. Row requests arrive FINISHED (RowPayload: their
+    lanes and valid bits were built where their frames were admitted, an
+    indexed frame's key rows gathered there; no flush gathers, and none
+    keeps an on-device gather); triple requests pack ONCE into the same
+    layout; ``sched.rows`` interleaves them (assemble_flush) and the
+    u8[128, N] block verifies in one call of the row verifier — this is
+    the cross-client megabatch. A verifier that raises (the device died
+    mid-flight) is reported through ``on_fallback(exc, n_lanes)`` — the
+    scheduler counts it under cpu_fallbacks — before the host rung
+    re-verifies the block."""
+    # recorder tags: what the flush carried, and how many of its blocks
+    # came from admission
+    with tracelib.stage("sched.rows") as span:
+        full, valid, prebuilt = assemble_flush(batch)
+        span.set_tag("requests", len(batch))
+        span.set_tag("lanes", int(full.shape[1]))
+        span.set_tag("prebuilt", prebuilt)
     try:
         mask = np.asarray(row_verifier(full), dtype=bool)[: full.shape[1]]
     except Exception as exc:  # noqa: BLE001 - device died mid-flight: host rung
@@ -835,6 +889,11 @@ class ServiceMetrics:
         self.registrations = r.counter(
             SUBSYSTEM, "registrations",
             "Valset registrations accepted, by tenant.",
+        )
+        self.rows_prebuilt = r.counter(
+            SUBSYSTEM, "rows_prebuilt",
+            "Row requests whose compact block (key rows gathered, valid "
+            "bits set) was built where the frame was admitted.",
         )
 
     @classmethod
@@ -931,6 +990,9 @@ class VerifyService(BaseService):
         self._auth_ok = 0
         self._auth_rejects = 0
         self._inline_dispatches = 0
+        # row requests whose compact block was built at admission (every
+        # one admitted: beside ``served`` it says no flush built any)
+        self._rows_prebuilt = 0
         # every tenant together: responses enqueued, and the seconds from
         # their frames decoded to then (the socket's legs are the
         # client's round trip less this)
@@ -1335,7 +1397,9 @@ class VerifyService(BaseService):
             self._admit(conn, frame, time.monotonic())
 
     def _admit(self, conn: _Conn, frame: Frame, t0: float) -> None:
-        """A decoded REQ frame to ``submit_rows`` returned."""
+        """A decoded REQ frame to ``submit_rows`` returned: bounds, the
+        freshness rule, the request's compact block built (``svc.gather``
+        inside this stage), counters, QoS admission."""
         if self._draining:
             # graceful drain: new work is refused with a typed
             # ST_DRAINING response (clients fail over immediately
@@ -1376,10 +1440,7 @@ class VerifyService(BaseService):
             raise FrameError(ERR_BAD_CLASS, str(exc)) from None
         kind_name = _KIND_NAMES[frame.kind]
         if frame.kind == KIND_COMPACT:
-            rows = np.frombuffer(frame.payload, np.uint8).reshape(
-                COMPACT_ROW_BYTES, n
-            )
-            payload = RowPayload(KIND_COMPACT, rows)
+            payload = RowPayload.from_compact(frame.payload)
         else:
             store = self._keystore()
             entry = store.entry_for(frame.valset_id, frame.generation)
@@ -1397,19 +1458,21 @@ class VerifyService(BaseService):
                     ERR_UNKNOWN_VALSET,
                     f"valset {frame.valset_id.hex()} is not registered",
                 )
-            rsh = np.frombuffer(
-                frame.payload[: RSH_ROW_BYTES * n], np.uint8
-            ).reshape(RSH_ROW_BYTES, n)
             idx = np.frombuffer(frame.payload[RSH_ROW_BYTES * n:], "<i4")
             if idx.size and (idx.min() < 0 or idx.max() >= entry.n):
                 raise FrameError(
                     ERR_MALFORMED,
                     f"table index outside [0, {entry.n})",
                 )
-            payload = RowPayload(
-                KIND_INDEXED, rsh, idx, entry, frame.valset_id
-            )
+            # the key rows leave the entry HERE, on this connection's
+            # reader thread: the flush joins finished blocks, and an
+            # entry that leaves the store from now on changes nothing
+            with tracelib.stage("svc.gather"):
+                payload = RowPayload.from_indexed(
+                    frame.payload[: RSH_ROW_BYTES * n], idx, entry
+                )
         with self._smtx:
+            self._rows_prebuilt += 1
             self._lanes[kind_name] = self._lanes.get(kind_name, 0) + n
             self._payload_bytes[kind_name] = (
                 self._payload_bytes.get(kind_name, 0) + len(frame.payload)
@@ -1422,6 +1485,7 @@ class VerifyService(BaseService):
                 rec["last_generation"] = frame.generation
                 rec["generations_seen"] += 1
         self.metrics.lanes.with_labels(kind=kind_name).add(n)
+        self.metrics.rows_prebuilt.add()
         self.metrics.bytes_per_lane.with_labels(kind=kind_name).set(
             len(frame.payload) / n
         )
@@ -1437,8 +1501,12 @@ class VerifyService(BaseService):
                 return  # raced teardown: disconnect already metered
             conn.pending[frame.req_id] = (n, t0)
         self.metrics.pending.set(self.pending_requests())
+        # the callback keeps the request's id and size, not its frame: a
+        # pending request holds its 128 B a lane once, in the payload
         fut.add_done_callback(
-            lambda f, c=conn, fr=frame: self._complete(c, fr, f)
+            lambda f, c=conn, rid=frame.req_id, n=n: self._complete(
+                c, rid, n, f
+            )
         )
 
     def _dispatch_isolated(
@@ -1460,14 +1528,14 @@ class VerifyService(BaseService):
             self._served += 1
             self._served_s += time.monotonic() - t0
 
-    def _complete(self, conn: _Conn, frame: Frame, fut: VerifyFuture
-                  ) -> None:
+    def _complete(self, conn: _Conn, req_id: int, n_lanes: int,
+                  fut: VerifyFuture) -> None:
         """Done-callback on the scheduler's worker (or an inline-dispatch
         submitter): encode the verdict and hand it to the connection's
         writer — never block the flush loop on a client socket."""
         with tracelib.stage("svc.respond"):
             with conn.mtx:
-                known = conn.pending.pop(frame.req_id, None)
+                known = conn.pending.pop(req_id, None)
             self.metrics.pending.set(self.pending_requests())
             if known is None or not conn.alive:
                 return  # disconnected mid-flight: metered in _teardown
@@ -1476,9 +1544,9 @@ class VerifyService(BaseService):
                 mask = np.asarray(sub, dtype=bool)
                 status = ST_REJECTED if fut.rejected else ST_OK
             except Exception:  # noqa: BLE001 - failed flush = rejected verdict
-                mask = np.zeros(frame.n_lanes, dtype=bool)
+                mask = np.zeros(n_lanes, dtype=bool)
                 status = ST_REJECTED
-            self._respond(conn, frame.req_id, status, mask)
+            self._respond(conn, req_id, status, mask)
             _, t0 = known
             dur = time.monotonic() - t0
             with self._smtx:
@@ -1631,6 +1699,7 @@ class VerifyService(BaseService):
                 "auth_ok": self._auth_ok,
                 "auth_rejects": self._auth_rejects,
                 "inline_dispatches": self._inline_dispatches,
+                "rows_prebuilt": self._rows_prebuilt,
                 "served": self._served,
                 "served_s": self._served_s,
                 "tenants_panel": panel,
