@@ -41,6 +41,7 @@ from cometbft_tpu.blocksync.messages import (
 )
 from cometbft_tpu.blocksync.pool import BlockPool
 from cometbft_tpu.crypto import batch as cryptobatch
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.log import Logger
 from cometbft_tpu.p2p.base_reactor import Reactor
 from cometbft_tpu.p2p.conn.connection import ChannelDescriptor
@@ -87,6 +88,12 @@ class BlocksyncReactor(Reactor):
         )
         self.blocks_synced = 0
         self.sync_error: Optional[Exception] = None
+        # monotonic books of the sync loop (sync_counters())
+        self._stages = tracelib.StageSeconds()
+        self._counts = {
+            "passes": 0, "blocks_refused": 0, "sync_one_calls": 0,
+            "light_lanes_submitted": 0,
+        }
         self._pool_thread: Optional[threading.Thread] = None
 
     # -- Reactor interface ---------------------------------------------------
@@ -199,7 +206,6 @@ class BlocksyncReactor(Reactor):
     # -- sync loop -------------------------------------------------------------
 
     def _pool_routine(self) -> None:
-        chain_id = self.initial_state.chain_id
         state = self.initial_state
         last_status = 0.0
         last_switch_check = 0.0
@@ -228,7 +234,7 @@ class BlocksyncReactor(Reactor):
                         )
                     return
             try:
-                state = self._try_sync_window(chain_id, state)
+                state = self.sync_pass(state)
             except Exception as exc:
                 # the reference panics here ("failed to process committed
                 # block"); a dead daemon thread would leave a zombie node,
@@ -242,6 +248,30 @@ class BlocksyncReactor(Reactor):
                 self.pool.stop()
                 return
             time.sleep(TRY_SYNC_INTERVAL)
+
+    def sync_pass(self, state):
+        """One pass of the sync loop, what ``_pool_routine`` runs between
+        its timers: verify and apply the window the pool holds now (up to
+        ``verify_window`` blocks and the one after). Returns the new
+        state: ``state`` itself where the pool held no two contiguous
+        blocks or the first one was refused. Raises what the routine
+        treats as fatal (a block that verified and then failed to apply)."""
+        self._counts["passes"] += 1
+        return self._try_sync_window(state.chain_id, state)
+
+    def sync_counters(self) -> dict:
+        """The sync loop's books, monotonic, readable while it runs:
+        ``passes`` (``sync_pass`` calls), ``blocks_applied``,
+        ``blocks_refused`` (validation failures that re-requested two
+        heights), ``sync_one_calls`` (falls to the single-block path),
+        ``light_lanes_submitted`` (quorum-prefix lanes handed to the
+        scheduler), and ``seconds`` by stage: this reactor's ``sync.*``
+        and its executor's ``exec.*``."""
+        execs = getattr(self.block_exec, "stage_seconds", None)
+        seconds = execs.snapshot() if execs is not None else {}
+        seconds.update(self._stages.snapshot())
+        return dict(self._counts, blocks_applied=self.blocks_synced,
+                    seconds=seconds)
 
     def _try_sync_window(self, chain_id: str, state):
         """Verify + apply the buffered window. Returns the new state.
@@ -265,14 +295,75 @@ class BlocksyncReactor(Reactor):
             return self._sync_one(chain_id, state)
 
         firsts = window[:batchable]
+        with self._stages.stage("sync.build", blocks=batchable):
+            built = self._build_window(chain_id, state, window, batchable)
+        if built is None:
+            # malformed commit in the window — single-block path will
+            # attribute and redo it
+            return self._sync_one(chain_id, state)
+        block_ids, part_sets, per_block, lanes_per_block = built
+        needed = state.validators.total_voting_power() * 2 // 3
+
+        with self._stages.stage("sync.submit", blocks=batchable):
+            futs = self._submit_window_commits(
+                per_block, lanes_per_block, state
+            )
+        if futs is not None:
+            return self._apply_window_pipelined(
+                chain_id, state, val_hash, firsts, block_ids, part_sets,
+                per_block, futs, window, needed,
+            )
+
+        with self._stages.stage("sync.verdict_wait"):
+            mask = self._verify_window_lanes(
+                per_block, lanes_per_block, state
+            )
+        if not all(mask):
+            return self._sync_one(chain_id, state)
+
+        # all signatures verified: check quorum per block, then apply
+        pos = 0
+        for i, entries in enumerate(per_block):
+            tallied = 0
+            for (idx, val), sig_ok in zip(entries, mask[pos : pos + len(entries)]):
+                if sig_ok:
+                    tallied += val.voting_power
+            pos += len(entries)
+            if tallied <= needed:
+                return self._sync_one(chain_id, state)
+
+        for i, first in enumerate(firsts):
+            # a validator-set change mid-window invalidates the batch
+            # assumption from this point on — re-verify individually
+            if state.validators.hash() != val_hash:
+                return state
+            try:
+                with self._stages.stage("sync.validate"):
+                    self.block_exec.validate_block(state, first)
+            except Exception:
+                # single-block path re-verifies and attributes the failure
+                return self._sync_one(chain_id, state)
+            state = self._apply_one(
+                state, block_ids[i], first, part_sets[i],
+                window[i + 1].last_commit,
+            )
+        return state
+
+    def _build_window(self, chain_id: str, state, window, batchable: int):
+        """Part sets, block ids and quorum-prefix lanes (sign-bytes
+        included) of the window's first ``batchable`` blocks, each
+        verified by the LastCommit of the block after it. → (block_ids,
+        part_sets, per_block, lanes_per_block), or None when a commit in
+        the window is malformed."""
         block_ids: List[BlockID] = []
         part_sets: List[object] = []
         per_block: List[List[Tuple[int, object]]] = []
         lanes_per_block: List[Tuple[list, list]] = []
         n_lanes = len(state.validators.validators)
         needed = state.validators.total_voting_power() * 2 // 3
-        for i, first in enumerate(firsts):
-            parts = first.make_part_set(BLOCK_PART_SIZE_BYTES)
+        for i, first in enumerate(window[:batchable]):
+            with self._stages.stage("sync.part_set"):
+                parts = first.make_part_set(BLOCK_PART_SIZE_BYTES)
             block_id = BlockID(first.hash(), parts.header())
             block_ids.append(block_id)
             part_sets.append(parts)
@@ -301,49 +392,10 @@ class BlocksyncReactor(Reactor):
                 ):
                     lane_msgs[idx] = msg
             except Exception:
-                # malformed commit in the window — single-block path will
-                # attribute and redo it
-                return self._sync_one(chain_id, state)
+                return None
             per_block.append(entries)
             lanes_per_block.append((lane_msgs, lane_sigs))
-
-        futs = self._submit_window_commits(per_block, lanes_per_block, state)
-        if futs is not None:
-            return self._apply_window_pipelined(
-                chain_id, state, val_hash, firsts, block_ids, part_sets,
-                per_block, futs, window, needed,
-            )
-
-        mask = self._verify_window_lanes(per_block, lanes_per_block, state)
-        if not all(mask):
-            return self._sync_one(chain_id, state)
-
-        # all signatures verified: check quorum per block, then apply
-        pos = 0
-        for i, entries in enumerate(per_block):
-            tallied = 0
-            for (idx, val), sig_ok in zip(entries, mask[pos : pos + len(entries)]):
-                if sig_ok:
-                    tallied += val.voting_power
-            pos += len(entries)
-            if tallied <= needed:
-                return self._sync_one(chain_id, state)
-
-        for i, first in enumerate(firsts):
-            # a validator-set change mid-window invalidates the batch
-            # assumption from this point on — re-verify individually
-            if state.validators.hash() != val_hash:
-                return state
-            try:
-                self.block_exec.validate_block(state, first)
-            except Exception:
-                # single-block path re-verifies and attributes the failure
-                return self._sync_one(chain_id, state)
-            state = self._apply_one(
-                state, block_ids[i], first, part_sets[i],
-                window[i + 1].last_commit,
-            )
-        return state
+        return block_ids, part_sets, per_block, lanes_per_block
 
     def _submit_window_commits(self, per_block, lanes_per_block, state):
         """Submit every window block's quorum prefix as its OWN request
@@ -374,6 +426,9 @@ class BlocksyncReactor(Reactor):
             for entries in per_block
         ) and all(isinstance(v.pub_key, ed.PubKeyEd25519) for v in vals):
             return None  # device-resident fixed executable wins at scale
+        self._counts["light_lanes_submitted"] += sum(
+            len(entries) for entries in per_block
+        )
         return [
             scheduler.submit(
                 [
@@ -400,20 +455,36 @@ class BlocksyncReactor(Reactor):
         scheduler — the next block's commit is in flight during the
         current block's apply. A failed verdict or quorum only costs the
         suffix: the verified prefix stays applied and the reference
-        single-block path re-attributes the failure from there."""
+        single-block path re-attributes the failure from there.
+
+        ``futs[i].result()`` takes no timeout, and needs none: a device
+        dispatch that dies does not leave its flush unanswered. Under the
+        node's supervisor the watchdog abandons it after ``[crypto]
+        dispatch_timeout_ms`` and the host pool answers the flush
+        (``BackendSupervisor.verify_items`` "never raises for
+        device-plane reasons"); without one ``VerifyScheduler._verify``
+        catches the failure and verifies on the CPU; a scheduler stopped
+        over a wedged worker fails its pending futures, which raises
+        here and ends the sync as any fatal error does. What is left is
+        a flush worker that died itself, and that hangs every caller of
+        the scheduler alike, ``validate_block``'s ``verify_commit`` and
+        ``_sync_one`` among them: a timeout here would only move the
+        hang to the fallback it fell to."""
         for i, first in enumerate(firsts):
             # a validator-set change mid-window invalidates the batch
             # assumption from this point on — re-verify individually
             if state.validators.hash() != val_hash:
                 return state
-            ok_all, mask_i = futs[i].result()
+            with self._stages.stage("sync.verdict_wait"):
+                ok_all, _ = futs[i].result()
             if not ok_all:
                 return self._sync_one(chain_id, state)
             tallied = sum(val.voting_power for _, val in per_block[i])
             if tallied <= needed:
                 return self._sync_one(chain_id, state)
             try:
-                self.block_exec.validate_block(state, first)
+                with self._stages.stage("sync.validate"):
+                    self.block_exec.validate_block(state, first)
             except Exception:
                 # single-block path re-verifies and attributes the failure
                 return self._sync_one(chain_id, state)
@@ -470,21 +541,26 @@ class BlocksyncReactor(Reactor):
     def _sync_one(self, chain_id: str, state):
         """The reference's exact PeekTwoBlocks path (:348-404): verify one
         block, redo + punish on failure."""
+        self._counts["sync_one_calls"] += 1
         first, second = self.pool.peek_two_blocks()
         if first is None or second is None:
             return state
-        parts = first.make_part_set(BLOCK_PART_SIZE_BYTES)
+        with self._stages.stage("sync.part_set"):
+            parts = first.make_part_set(BLOCK_PART_SIZE_BYTES)
         block_id = BlockID(first.hash(), parts.header())
         try:
-            state.validators.verify_commit_light(
-                chain_id,
-                block_id,
-                first.header.height,
-                second.last_commit,
-                backend=self.crypto_backend,
-            )
-            self.block_exec.validate_block(state, first)
+            with self._stages.stage("sync.verdict_wait"):
+                state.validators.verify_commit_light(
+                    chain_id,
+                    block_id,
+                    first.header.height,
+                    second.last_commit,
+                    backend=self.crypto_backend,
+                )
+            with self._stages.stage("sync.validate"):
+                self.block_exec.validate_block(state, first)
         except Exception as exc:
+            self._counts["blocks_refused"] += 1
             self.logger.error("error in validation", err=str(exc))
             for h in (first.header.height, second.header.height):
                 peer_id = self.pool.redo_request(h)
@@ -502,8 +578,10 @@ class BlocksyncReactor(Reactor):
 
     def _apply_one(self, state, block_id: BlockID, first: Block, parts, seen_commit):
         self.pool.pop_request()
-        self.store.save_block(first, parts, seen_commit)
-        new_state, _ = self.block_exec.apply_block(state, block_id, first)
+        with self._stages.stage("sync.save_block"):
+            self.store.save_block(first, parts, seen_commit)
+        with self._stages.stage("sync.apply"):
+            new_state, _ = self.block_exec.apply_block(state, block_id, first)
         self.blocks_synced += 1
         if self.blocks_synced % 100 == 0:
             self.logger.info(

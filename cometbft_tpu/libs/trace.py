@@ -266,6 +266,53 @@ class stage:
         return False
 
 
+class StageSeconds:
+    """Monotonic seconds by stage name, readable without a profiler
+    session: ``with book.stage("sync.apply"):`` is
+    ``stage("sync.apply")`` whose one clock reading also lands in the
+    book.  A layer that owns request-path stages owns one of these and
+    serves ``snapshot()`` (the blocksync reactor's ``sync.*``, the block
+    executor's ``exec.*``); a reader takes the difference of two
+    snapshots.  A body that raises is timed like any other."""
+
+    __slots__ = ("_lock", "_seconds")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._seconds: Dict[str, float] = {}
+
+    def stage(self, name: str, **tags: Any) -> "_BookedStage":
+        return _BookedStage(self, name, tags)
+
+    def _add(self, name: str, seconds: float) -> None:
+        with self._lock:
+            self._seconds[name] = self._seconds.get(name, 0.0) + seconds
+
+    def snapshot(self) -> Dict[str, float]:
+        """{stage name: seconds so far}, a copy."""
+        with self._lock:
+            return dict(self._seconds)
+
+
+class _BookedStage:
+    __slots__ = ("_book", "_name", "_stage", "_t0")
+
+    def __init__(self, book: StageSeconds, name: str, tags: Dict[str, Any]):
+        self._book = book
+        self._name = name
+        self._stage = stage(name, **tags)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Span":
+        span = self._stage.__enter__()
+        self._t0 = time.perf_counter()
+        return span
+
+    def __exit__(self, etype: Any, exc: Any, tb: Any) -> bool:
+        self._book._add(self._name, time.perf_counter() - self._t0)
+        return self._stage.__exit__(etype, exc, tb)
+
+
 class background:
     """Marks this thread's work as nothing a request waits for (audit,
     probe, canary): stages opened inside keep their flight-recorder span

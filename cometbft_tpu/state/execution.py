@@ -10,10 +10,12 @@ process between every persistence step.
 
 from __future__ import annotations
 
+import time
 from typing import List, Optional, Tuple
 
 from cometbft_tpu.abci import types as abci
 from cometbft_tpu.libs import fail
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.log import Logger, new_nop_logger
 from cometbft_tpu.state import State, median_time
 from cometbft_tpu.state.store import ABCIResponses, Store
@@ -94,6 +96,8 @@ class BlockExecutor:
         )
         self._event_bus = event_bus if event_bus is not None else NopEventBus()
         self._logger = logger or new_nop_logger()
+        # seconds by exec.* stage, whoever applies (consensus, blocksync)
+        self.stage_seconds = tracelib.StageSeconds()
 
     def set_event_bus(self, event_bus) -> None:
         self._event_bus = event_bus
@@ -131,17 +135,19 @@ class BlockExecutor:
     ) -> Tuple[State, int]:
         """Returns (new_state, retain_height).
         Reference: state/execution.go:131-208."""
-        self.validate_block(state, block)
+        stages = self.stage_seconds
+        with stages.stage("exec.validate"):
+            self.validate_block(state, block)
 
-        import time as _time
-
-        exec_start = _time.monotonic()
-        abci_responses = exec_block_on_proxy_app(
-            self._proxy_app, block, self._store, state.initial_height, self._logger
-        )
-        self._metrics.block_processing_time.observe(
-            _time.monotonic() - exec_start
-        )
+        with stages.stage("exec.abci"):
+            exec_start = time.monotonic()
+            abci_responses = exec_block_on_proxy_app(
+                self._proxy_app, block, self._store, state.initial_height,
+                self._logger,
+            )
+            self._metrics.block_processing_time.observe(
+                time.monotonic() - exec_start
+            )
 
         fail.fail()  # ABCI_RESPONSES not yet saved
         self._store.save_abci_responses(block.header.height, abci_responses)
@@ -158,14 +164,18 @@ class BlockExecutor:
         )
 
         # Lock mempool, commit app state, update mempool.
-        app_hash, retain_height = self._commit(new_state, block, abci_responses)
+        with stages.stage("exec.commit"):
+            app_hash, retain_height = self._commit(
+                new_state, block, abci_responses
+            )
 
         # Update evpool with the latest state.
         self._evpool.update(new_state, block.evidence)
         fail.fail()  # about to persist the new state
 
         new_state.app_hash = app_hash
-        self._store.save(new_state)
+        with stages.stage("exec.save_state"):
+            self._store.save(new_state)
         fail.fail()  # state saved
 
         self._fire_events(block, block_id, abci_responses, validator_updates)
