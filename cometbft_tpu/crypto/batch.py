@@ -17,7 +17,7 @@ import os
 import sys
 import threading
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Tuple, Union
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 from cometbft_tpu.crypto import PubKey
 from cometbft_tpu.crypto import ed25519 as ed
@@ -101,7 +101,17 @@ def ed25519_routing_floor(config_min_batch: Optional[int] = None) -> int:
 
 
 class BatchVerifier:
-    """Interface (new; upstream cometbft >= v0.35 has an analogous shape)."""
+    """Interface (new; upstream cometbft >= v0.35 has an analogous shape).
+
+    Two ways in, one verdict: ``add`` x n + ``verify`` (a caller that
+    meets its lanes one at a time), and ``verify_many(items)`` (a caller
+    that already holds the flush: the scheduler's coalesced triples, the
+    supervisor's dispatch worker). Who copies a lane's bytes: ``add``
+    normalises msg and sig to ``bytes`` and keeps the triple; the bulk
+    entry takes the triples AS THEY ARE (``VerifyScheduler.submit`` made
+    them ``bytes`` at admission; a lane that is not is normalised as
+    ``add`` would) and copies none, so a lane's bytes are copied once,
+    where the backend packs its launch."""
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         raise NotImplementedError
@@ -112,6 +122,30 @@ class BatchVerifier:
     def verify(self) -> Tuple[bool, List[bool]]:
         """Returns (all_valid, per-entry validity mask) and resets the batch."""
         raise NotImplementedError
+
+    def verify_many(
+        self, items: Sequence[Tuple[PubKey, bytes, bytes]]
+    ) -> Tuple[bool, List[bool]]:
+        """The bulk entry: ``verify()`` of ``items`` (after whatever
+        ``add`` already collected). Here it IS ``add`` x n + ``verify``,
+        so a backend that overrides those (or reads ``count()`` in its
+        ``verify``) sees what it always saw; a backend that can take the
+        flush in one pass overrides it (TPUBatchVerifier)."""
+        for pk, m, s in items:
+            self.add(pk, m, s)
+        return self.verify()
+
+
+def verify_flush(
+    bv, items: Sequence[Tuple[PubKey, bytes, bytes]]
+) -> Tuple[bool, List[bool]]:
+    """Drive ANY verifier over a flush's triples: through its bulk entry
+    where it has one, else lane by lane (a duck-typed double with only
+    ``add`` / ``verify``)."""
+    bulk = getattr(bv, "verify_many", None)
+    if bulk is None:
+        return BatchVerifier.verify_many(bv, items)
+    return bulk(items)
 
 
 class CPUBatchVerifier(BatchVerifier):
@@ -216,7 +250,8 @@ class TPUBatchVerifier(BatchVerifier):
     faster, so small commits (150 validators) verify on the CPU even
     under the "tpu" backend — the hybrid IS the design, the device earns
     its round trip only at scale. ``host_lanes``/``device_lanes`` say
-    where the last verify()'s lanes actually ran."""
+    where the last flush's lanes actually ran, ``single_curve`` whether
+    it was one curve (then no partition was built)."""
 
     def __init__(
         self,
@@ -239,6 +274,7 @@ class TPUBatchVerifier(BatchVerifier):
         )
         self.host_lanes = 0
         self.device_lanes = 0
+        self.single_curve = False
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         if pub_key is None:
@@ -249,60 +285,120 @@ class TPUBatchVerifier(BatchVerifier):
         return len(self._items)
 
     def verify(self) -> Tuple[bool, List[bool]]:
-        from cometbft_tpu.crypto import secp256k1 as secp
-        from cometbft_tpu.crypto import sr25519 as sr
-
         items, self._items = self._items, []
-        if not items:
-            return False, []
-        mask: List[Optional[bool]] = [None] * len(items)
-        by_curve: Dict[str, List[int]] = {c: [] for c in self._floors}
-        for i, (pk, msg, sig) in enumerate(items):
-            idxs = by_curve.get(pk.type())
-            if idxs is not None:
-                idxs.append(i)
-            else:
-                mask[i] = pk.verify_signature(msg, sig)
-        self.device_lanes = 0
-        for curve, idxs in by_curve.items():
-            if not idxs:
-                continue
-            if len(idxs) < self._floors[curve]:
-                if curve == ed.KEY_TYPE:
-                    sub_mask = ed.verify_many([items[i] for i in idxs])
-                    for j, i in enumerate(idxs):
-                        mask[i] = sub_mask[j]
-                else:
-                    for i in idxs:
-                        pk, msg, sig = items[i]
-                        mask[i] = pk.verify_signature(msg, sig)
-                continue
-            self.device_lanes += len(idxs)
-            if curve == ed.KEY_TYPE:
-                from cometbft_tpu.crypto.tpu import ed25519_batch as kernel
-            elif curve == secp.KEY_TYPE:
-                from cometbft_tpu.crypto.tpu import secp256k1_batch as kernel
-            else:
-                from cometbft_tpu.crypto.tpu import sr25519_batch as kernel
-            pks = [items[i][0].bytes() for i in idxs]
-            msgs = [items[i][1] for i in idxs]
-            sigs = [items[i][2] for i in idxs]
-            ok = None
-            if curve == ed.KEY_TYPE:
-                # steady-state flushes against a resident valset ship an
-                # index vector instead of the pubkeys (100 B/lane vs 128
-                # — crypto/tpu/keystore.py); None = no fresh entry
-                # covers the flush, fall through to the full wire
-                from cometbft_tpu.crypto.tpu import keystore
+        return self.verify_many(items)
 
-                ok = keystore.verify_batch_indexed(pks, msgs, sigs)
-            if ok is None:
-                ok = kernel.verify_batch(pks, msgs, sigs)
-            for j, i in enumerate(idxs):
-                mask[i] = bool(ok[j])
-        self.host_lanes = len(items) - self.device_lanes
-        final = [bool(m) for m in mask]
-        return all(final), final
+    def verify_many(
+        self, items: Sequence[Tuple[PubKey, bytes, bytes]]
+    ) -> Tuple[bool, List[bool]]:
+        """ONE pass from the flush's triples to the kernels' columns,
+        under the stage ``sup.columns`` (tags ``lanes``, ``single_curve``)
+        on whichever thread drives the verifier: the supervisor's
+        dispatch worker in a node. The columns are ``zip(*items)``; which
+        curves the flush holds is read from the set of the keys' classes
+        (a key's curve is its class's), so a one-curve flush (every
+        flush of an ed25519 chain) builds no index list and gathers
+        nothing, and a mixed one is partitioned from the same columns.
+        Nothing is copied here but a device partition's key bytes: the
+        lanes' msg / sig objects go to the kernel entry as they are. A
+        ``None`` key raises before anything is dispatched; lengths and
+        s < L are the kernel entry's check (``_parse_inputs``)."""
+        if self._items:
+            items, self._items = self._items + list(items), []
+        n = len(items)
+        if n == 0:
+            return False, []
+        floors = self._floors
+
+        def part(curve, idxs, ks, ms, ss):
+            # a partition that clears its curve's floor goes to the device
+            # and ships its keys as bytes; the rest stays on the host
+            on_device = curve in floors and len(ks) >= floors[curve]
+            return (curve, idxs, ks, ms, ss,
+                    [k.bytes() for k in ks] if on_device else None)
+
+        with tracelib.stage("sup.columns", lanes=n) as span:
+            keys, msgs, sigs = zip(*items)
+            kinds = dict(zip(map(type, keys), keys))  # class -> one key
+            if type(None) in kinds:
+                raise ValueError("nil pubkey")
+            if set(map(type, msgs)) | set(map(type, sigs)) != {bytes}:
+                # what add() does a lane at a time, for a caller that
+                # hands in bytearrays or views
+                msgs, sigs = tuple(map(bytes, msgs)), tuple(map(bytes, sigs))
+            curves = {k.type() for k in kinds.values()}
+            self.single_curve = len(curves) == 1
+            span.set_tag("single_curve", int(self.single_curve))
+            if self.single_curve:
+                parts = [part(curves.pop(), None, keys, msgs, sigs)]
+            else:
+                by_curve: Dict[str, List[int]] = {c: [] for c in floors}
+                for i, k in enumerate(keys):
+                    by_curve.setdefault(k.type(), []).append(i)
+                parts = [
+                    part(
+                        curve, idxs,
+                        [keys[i] for i in idxs],
+                        [msgs[i] for i in idxs],
+                        [sigs[i] for i in idxs],
+                    )
+                    for curve, idxs in by_curve.items() if idxs
+                ]
+        mask: List[bool] = [False] * n
+        self.device_lanes = 0
+        for curve, idxs, ks, ms, ss, pk_bytes in parts:
+            if pk_bytes is None:
+                sub = _verify_on_host(curve, ks, ms, ss)
+            else:
+                self.device_lanes += len(ks)
+                sub = _verify_on_device(curve, pk_bytes, ms, ss)
+            if len(sub) != len(ks):
+                raise RuntimeError(
+                    f"{curve} partition returned {len(sub)} verdicts for "
+                    f"{len(ks)} lanes"
+                )
+            if idxs is None:
+                mask = sub
+            else:
+                for i, ok in zip(idxs, sub):
+                    mask[i] = ok
+        self.host_lanes = n - self.device_lanes
+        return all(mask), mask
+
+
+def _verify_on_host(curve: str, keys, msgs, sigs) -> List[bool]:
+    """A partition its floor keeps off the device (or of no batch
+    curve): ed25519 through the host pool's one native call, any other
+    key serially."""
+    if curve == ed.KEY_TYPE:
+        return list(map(bool, ed.verify_many(list(zip(keys, msgs, sigs)))))
+    return [
+        bool(pk.verify_signature(m, s)) for pk, m, s in zip(keys, msgs, sigs)
+    ]
+
+
+def _verify_on_device(curve: str, pk_bytes, msgs, sigs) -> List[bool]:
+    """A partition that cleared its floor, as columns, to its curve's
+    kernel entry."""
+    from cometbft_tpu.crypto import secp256k1 as secp
+
+    ok = None
+    if curve == ed.KEY_TYPE:
+        from cometbft_tpu.crypto.tpu import ed25519_batch as kernel
+        # steady-state flushes against a resident valset ship an index
+        # vector instead of the pubkeys (100 B/lane vs 128 —
+        # crypto/tpu/keystore.py); None = no fresh entry covers the
+        # flush, fall through to the full wire
+        from cometbft_tpu.crypto.tpu import keystore
+
+        ok = keystore.verify_batch_indexed(pk_bytes, msgs, sigs)
+    elif curve == secp.KEY_TYPE:
+        from cometbft_tpu.crypto.tpu import secp256k1_batch as kernel
+    else:
+        from cometbft_tpu.crypto.tpu import sr25519_batch as kernel
+    if ok is None:
+        ok = kernel.verify_batch(pk_bytes, msgs, sigs)
+    return list(map(bool, ok))
 
 
 def resident_commit_eligible(

@@ -101,6 +101,7 @@ from cometbft_tpu.crypto.batch import (
     CPUBatchVerifier,
     clears_device_floor,
     new_batch_verifier,
+    verify_flush,
 )
 from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.log import Logger
@@ -1720,10 +1721,7 @@ class VerifyScheduler(BaseService):
                 items, reason=reason, origins=origins
             ), wire_route
         try:
-            bv = new_batch_verifier(self.spec)
-            for pk, m, s in items:
-                bv.add(pk, m, s)
-            _, mask = bv.verify()
+            _, mask = verify_flush(new_batch_verifier(self.spec), items)
             if len(mask) != len(items):
                 raise RuntimeError(
                     f"backend returned {len(mask)} verdicts for "

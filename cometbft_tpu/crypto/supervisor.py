@@ -98,6 +98,7 @@ from cometbft_tpu.crypto.batch import (
     CPUBatchVerifier,
     new_batch_verifier,
     unwrap_backend,
+    verify_flush,
 )
 from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.log import Logger, new_nop_logger
@@ -446,6 +447,12 @@ class Metrics:
         self.device_dispatches = r.counter(
             SUBSYSTEM, "device_dispatches",
             "Batches dispatched to the supervised backend.",
+        )
+        self.single_curve_dispatches = r.counter(
+            SUBSYSTEM, "single_curve_dispatches",
+            "Supervised device dispatches whose flush held one curve: its "
+            "columns went to the backend with no per-curve partition "
+            "(stage sup.columns; every flush of an ed25519 chain).",
         )
         self.host_lanes = r.counter(
             SUBSYSTEM, "host_lanes",
@@ -1555,7 +1562,12 @@ class BackendSupervisor:
         so the mesh chunk loop caps chunks by THIS device's shrink
         ladder and fault injection can target one domain.
         ``force_device`` lifts the backend's routing floor (canary and
-        triage: the point is to exercise the device, however few lanes)."""
+        triage: the point is to exercise the device, however few lanes).
+        ``items`` go to the backend's bulk entry AS THE LIST THEY ARE
+        (``batch.verify_flush``): the worker copies no lane and re-adds
+        none; the flush's columns are made once, inside the backend
+        (stage ``sup.columns``), and the audit, the hedge and triage keep
+        reading the same triples."""
         # import OUTSIDE the timed region so a cold jax import can never
         # eat the first dispatch's timeout budget
         from cometbft_tpu.crypto.tpu import aot, mesh, topology
@@ -1585,15 +1597,14 @@ class BackendSupervisor:
                     bv = new_batch_verifier(
                         self.spec, force_device=force_device
                     )
-                    for pk, m, s in items:
-                        bv.add(pk, m, s)
-                    _, mask = bv.verify()
+                    _, mask = verify_flush(bv, items)
                 if len(mask) != len(items):
                     raise RuntimeError(
                         f"backend returned {len(mask)} verdicts for "
                         f"{len(items)} items"
                     )
                 h.box["host_lanes"] = getattr(bv, "host_lanes", 0)
+                h.box["single_curve"] = getattr(bv, "single_curve", False)
                 h.box["mask"] = mask
             except BaseException as exc:  # noqa: BLE001 - crosses threads
                 h.box["exc"] = exc
@@ -1624,6 +1635,8 @@ class BackendSupervisor:
         host_lanes = h.box.get("host_lanes", 0)
         if host_lanes:
             self.metrics.host_lanes.add(host_lanes)
+        if h.box.get("single_curve"):
+            self.metrics.single_curve_dispatches.add()
         if self._telemetry is not None:
             self._telemetry.note_device_busy(
                 dom.handle.label, t1 - h.dispatch_s(t1), t1,

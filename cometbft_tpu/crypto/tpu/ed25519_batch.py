@@ -471,19 +471,26 @@ def _s_below_l(s_arr: np.ndarray) -> np.ndarray:
 
 def _parse_inputs(pub_keys, sigs):
     """→ (pk_arr u8[B,32], sig_arr u8[B,64], valid) with wrong-length and
-    s ≥ L entries masked out (zero-filled placeholders keep the shapes)."""
+    s ≥ L entries masked out (zero-filled placeholders keep the shapes).
+    THE place a keyed lane's key and signature bytes are copied: one
+    join a column into the launch's arrays. The lengths are read as a
+    set first, so the placeholder loop runs only for a launch that
+    holds a malformed lane."""
     n = len(pub_keys)
     valid = np.ones(n, bool)
-    pk_parts, sig_parts = [], []
-    for i in range(n):
-        pk, sig = pub_keys[i], sigs[i]
-        if len(pk) != 32 or len(sig) != 64:
-            valid[i] = False
-            pk_parts.append(b"\x00" * 32)
-            sig_parts.append(b"\x00" * 64)
-        else:
-            pk_parts.append(pk)
-            sig_parts.append(sig)
+    if set(map(len, pub_keys)) <= {32} and set(map(len, sigs)) <= {64}:
+        pk_parts, sig_parts = pub_keys, sigs
+    else:
+        pk_parts, sig_parts = [], []
+        for i in range(n):
+            pk, sig = pub_keys[i], sigs[i]
+            if len(pk) != 32 or len(sig) != 64:
+                valid[i] = False
+                pk_parts.append(b"\x00" * 32)
+                sig_parts.append(b"\x00" * 64)
+            else:
+                pk_parts.append(pk)
+                sig_parts.append(sig)
     pk_arr = np.frombuffer(b"".join(pk_parts), np.uint8).reshape(n, 32)
     sig_arr = np.frombuffer(b"".join(sig_parts), np.uint8).reshape(n, 64)
     valid &= _s_below_l(sig_arr[:, 32:])
@@ -699,7 +706,11 @@ def verify_batch(
 ) -> List[bool]:
     """Public entry used by crypto.batch.TPUBatchVerifier. Packing runs
     per dispatch chunk (the callable form of dispatch_batch) so the host
-    hashing of chunk i+1 overlaps the device's work on chunk i.
+    hashing of chunk i+1 overlaps the device's work on chunk i. The
+    three columns are sequences of bytes-like lanes, taken as they are
+    and only sliced here: a lane's bytes are copied once, by its
+    launch's pack (``_parse_inputs``; the messages by the hash), and the
+    length / s < L checks are made there.
 
     Route selection is two-dimensional: wire_format() picks compact
     (raw uint8 rows, on-device decompress — the default) vs the legacy
@@ -735,7 +746,7 @@ def verify_batch(
     out = mesh_mod.dispatch_batch(
         kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD, launch=_LAUNCH_LANES
     )
-    return list(out & valid_full)
+    return (out & valid_full).tolist()
 
 
 # --- valset-resident commit verification ------------------------------------

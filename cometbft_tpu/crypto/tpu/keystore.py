@@ -512,4 +512,4 @@ def verify_batch_indexed(
             device_label="dev0", donate_from=1,
         )
     _default.note_indexed(n)
-    return list(out & valid)
+    return (out & valid).tolist()

@@ -330,4 +330,4 @@ def verify_batch(
     out = mesh_mod.dispatch_batch(
         verify_kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD
     )
-    return list(out & valid_full)
+    return (out & valid_full).tolist()

@@ -40,6 +40,7 @@ STAGES = {
     "sched.demux": ("bench:device", "flush"),
     "sup.supervise": ("bench:device", "flush"),
     "sup.device": (PREFIX + "sup.supervise", "worker"),
+    "sup.columns": (PREFIX + "sup.device", "worker"),
     "mesh.pack": (PREFIX + "sup.device", "worker"),
     "mesh.launch": (PREFIX + "sup.device", "worker"),
     "mesh.retire": (PREFIX + "sup.device", "worker"),
