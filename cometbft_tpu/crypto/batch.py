@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 from cometbft_tpu.crypto import PubKey
 from cometbft_tpu.crypto import ed25519 as ed
+from cometbft_tpu.crypto import wire as wirelib
 from cometbft_tpu.libs import trace as tracelib
 
 
@@ -317,7 +318,8 @@ class TPUBatchVerifier(BatchVerifier):
             return (curve, idxs, ks, ms, ss,
                     [k.bytes() for k in ks] if on_device else None)
 
-        with tracelib.stage("sup.columns", lanes=n) as span:
+        columns = tracelib.stage("sup.columns", lanes=n)
+        with columns as span:
             keys, msgs, sigs = zip(*items)
             kinds = dict(zip(map(type, keys), keys))  # class -> one key
             if type(None) in kinds:
@@ -344,6 +346,8 @@ class TPUBatchVerifier(BatchVerifier):
                     )
                     for curve, idxs in by_curve.items() if idxs
                 ]
+        # the flush record's columns phase, inside its lead
+        wirelib.add_phase("columns", columns.seconds)
         mask: List[bool] = [False] * n
         self.device_lanes = 0
         for curve, idxs, ks, ms, ss, pk_bytes in parts:

@@ -1234,8 +1234,13 @@ class VerifyScheduler(BaseService):
 
     def _dispatch(self, batch: List[_Request], reason: str) -> None:
         """ONE backend verify over the coalesced items, demultiplexed back
-        into per-request verdict slices."""
-        with tracelib.stage("sched.assemble"):
+        into per-request verdict slices. The flush's life is booked in
+        the wire ledger's flush record (crypto/wire.FlushRecord): queue
+        from the oldest rider's wait, ``assemble`` / ``route`` / ``demux``
+        from the three stages' own readings, whose edges are also where
+        ``queue`` ends, ``lead`` starts and ``tail`` ends."""
+        assembled = tracelib.stage("sched.assemble")
+        with assembled:
             t0 = time.monotonic()
             # memory-plane freshness ride-along: the flush threads are the
             # natural pollers — no background thread needed. The sys.modules
@@ -1306,7 +1311,8 @@ class VerifyScheduler(BaseService):
             origins = [
                 (req.n_lanes, req.subsystem, req.height) for req in batch
             ]
-        with tracelib.stage("sched.route"):
+        routed = tracelib.stage("sched.route")
+        with routed:
             # decision plane ride-along: one RouteDecision per flush, input
             # gathering gated on an installed ledger so the off-edge is a
             # single attribute read (bench_micro's decisions section bounds
@@ -1326,10 +1332,18 @@ class VerifyScheduler(BaseService):
                     qos={name: c[1] for name, c in by_class.items()} or None,
                     feasible=self._decision_feasible(items, breakers),
                 )
+        # the flush was born with its oldest rider's submit: that wait
+        # was read against t0, a statement after the assemble stage opened
+        queue_s = max(waits, default=0.0)
+        flush = wirelib.open_flush(
+            n_total, assembled.t0_ns - int(queue_s * 1e9), routed.t1_ns,
+            queue=queue_s, assemble=assembled.seconds, route=routed.seconds,
+        )
         t_verify = time.perf_counter()
         built = _built_s()
         try:
-            with tracelib.use(dspan), declib.use(dec):
+            with tracelib.use(dspan), declib.use(dec), \
+                    wirelib.flush_scope(flush):
                 if has_rows:
                     mask = self._verify_rows(batch)
                     wire_route = "service"
@@ -1352,8 +1366,8 @@ class VerifyScheduler(BaseService):
         # the ledger's fifth phase (host-side fan-out back to futures)
         dspan.end(route=wire_route)
         service_s = time.monotonic() - t0
-        with tracelib.stage("sched.demux"):
-            t_demux = time.perf_counter()
+        demuxed = tracelib.stage("sched.demux")
+        with demuxed:
             pos = 0
             for i, req in enumerate(batch):
                 sub = mask[pos : pos + req.n_lanes]
@@ -1372,11 +1386,12 @@ class VerifyScheduler(BaseService):
                         subsystem=req.subsystem,
                         height=req.height,
                     )
-            ledger = wirelib.default_ledger()
-            if ledger is not None:
-                ledger.note_demux(
-                    wire_route, n_total, time.perf_counter() - t_demux
-                )
+        ledger = wirelib.default_ledger()
+        if ledger is not None:
+            ledger.note_demux(wire_route, n_total, demuxed.seconds)
+        if flush is not None:
+            flush.close(wire_route, demuxed.t0_ns, demuxed.t1_ns,
+                        demux=demuxed.seconds)
 
     def _verify_rows(self, batch: List[_Request]) -> List[bool]:
         """Verify a coalesced flush carrying row payloads: the requests'

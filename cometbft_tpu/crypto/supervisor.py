@@ -91,6 +91,7 @@ import time
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 from cometbft_tpu.crypto import PubKey, decisions as declib
+from cometbft_tpu.crypto import wire as wirelib
 from cometbft_tpu.crypto.batch import (
     Backend,
     BackendSpec,
@@ -1081,17 +1082,19 @@ class BackendSupervisor:
     ) -> List[bool]:
         """Run one shard per healthy domain — shard 0 inline on the
         calling thread, the rest on workers that re-install the
-        supervise span so their device/cpu children parent correctly.
+        supervise span so their device/cpu children parent correctly,
+        and the flush record so their streams are stamped on it.
         Each shard is independently supervised (watchdog, ladder,
         triage, audit); a shard whose worker outlives even the watchdog
         bound is served from the CPU ground truth, so the full mask is
         always returned."""
         results: List[Optional[List[bool]]] = [None] * len(shards)
         outcomes: List[Optional[str]] = [None] * len(shards)
+        flush = wirelib.current_flush()
 
         def run_shard(i: int, dom: _Domain, start: int, end: int) -> None:
             try:
-                with tracelib.use(span):
+                with tracelib.use(span), wirelib.flush_scope(flush):
                     m, oc = self._supervise_shard(
                         dom, items[start:end], reason,
                         _slice_origins(origins, start, end),
@@ -1582,8 +1585,10 @@ class BackendSupervisor:
             device=dom.handle.label, route=route or "auto",
         )
 
-        # a probe's or the canary's dispatch stays background on the worker
+        # a probe's or the canary's dispatch stays background on the worker,
+        # and the worker works for the flush its spawner works for
         quiet = tracelib.in_background()
+        flush = wirelib.current_flush()
 
         def run():
             h.build = aot.build_clock()
@@ -1593,7 +1598,8 @@ class BackendSupervisor:
                         tracelib.stage("sup.device", tracelib.NOOP_SPAN), \
                         mesh.cancel_scope(h.cancel), \
                         topology.device_scope(dom.handle), \
-                        mesh.route_scope(route):
+                        mesh.route_scope(route), \
+                        wirelib.flush_scope(flush):
                     bv = new_batch_verifier(
                         self.spec, force_device=force_device
                     )

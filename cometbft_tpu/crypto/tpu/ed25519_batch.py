@@ -1017,9 +1017,11 @@ def verify_valset_resident(
     fetched = []  # the messages of the launch that is next
 
     def fetch(chunk, start, end, inflight):
-        with tracelib.stage("commit.msgs_chunk", chunk=chunk,
-                            lanes=end - start, inflight=inflight):
+        built = tracelib.stage("commit.msgs_chunk", chunk=chunk,
+                               lanes=end - start, inflight=inflight)
+        with built:
             fetched.append(msgs(start, end))
+        return built.seconds  # the wire ledger's fetch phase
 
     def build(start, end):
         rsh, valid[start:end] = _prepare_rsh(
@@ -1030,7 +1032,8 @@ def verify_valset_resident(
         return [rsh]
 
     # this path runs beside the scheduler (no flush, no supervisor), so
-    # the wire ledger is the only place its device lanes are on record;
+    # the wire ledger is the only place its device lanes are on record
+    # (its flush record is verify_commit*'s own: wire.own_flush);
     # only the per-commit rsh staging is donated — the resident pubkey
     # rows lead the call and must survive across commits
     out, _ = mesh_mod.launch_stream(

@@ -486,7 +486,8 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
     found malformed stays its caller's business. ``fetch(chunk, start,
     end, inflight)``, where given, gathers what build will pack and is
     no packing itself (a commit's sign-bytes, under a stage of their
-    own). ``where`` is the placement: a jax Mesh (arguments sharded on
+    own) and returns that stage's seconds: the wire ledger's ``fetch``
+    phase. ``where`` is the placement: a jax Mesh (arguments sharded on
     the trailing axis, the sharded executable) or one jax device / None
     for jax's default (run_single). ``domains`` are the labels of the
     fault domains the lanes are attributed to, in shard order: a shard
@@ -500,7 +501,14 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
     v5e this order beat building every lane first 100.48 to 128.12 ms a
     10,000-lane commit (PERF.md, PR 27). The thread's cancel event is
     checked at every launch boundary; a launch that fails says which it
-    was and which lanes it held."""
+    was and which lanes it held.
+
+    One clock: a launch's ``pack`` / ``h2d`` / ``compute`` / ``d2h`` are
+    the readings its three stages take anyway (libs/trace.stage), split
+    once inside ``.launch`` between device_put and the call. The flush
+    record this thread works for (crypto/wire.current_flush; none on a
+    background thread) is stamped with launch 0's issue and the last
+    retire, and is told the build seconds no launch in flight hid."""
     from collections import deque
 
     import jax
@@ -542,6 +550,7 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
 
     hub = _telemetry.default_hub()
     ledger = _wirelib.default_ledger()
+    flush = _wirelib.current_flush()
     depth = pipeline_depth()
     cancel = current_cancel_event()
     # an executable a launch has to build first (registry miss) is host
@@ -550,8 +559,10 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
     clock = aot.build_clock()
     built0 = clock.total()
     t_wall0 = time.perf_counter()
-    tot = {"wall_s": 0.0, "pack_s": 0.0, "h2d_s": 0.0, "compute_s": 0.0,
-           "d2h_s": 0.0, "hidden_s": 0.0, "wire_bytes": 0, "chunks": 0}
+    tot = {"wall_s": 0.0, "fetch_s": 0.0, "pack_s": 0.0, "h2d_s": 0.0,
+           "compute_s": 0.0, "d2h_s": 0.0, "hidden_s": 0.0, "wire_bytes": 0,
+           "chunks": 0}
+    last_retire_ns = 0
     out = np.zeros(n, bool)
     inflight: "deque" = deque()
     n_domains = max(1, len(domains))
@@ -574,18 +585,19 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
             ) from exc
 
     def retire(slot):
+        nonlocal last_retire_ns
         chunk, start, end, size, mask, span, shard_spans, phases = slot
         # np.asarray blocks until the device finishes this launch (and,
         # sharded, gathers the mask's slices from the chips): the wait
-        # measured here IS the device-time attribution of the span
-        t_dev = time.perf_counter_ns()
+        # the stage reads IS the device-time attribution of the span
+        waited = _trace.stage(prefix + ".retire", span=span.child(
+            prefix + ".retire", shards=nsh, lanes_per_shard=size // nsh,
+        ))
         with launch_context("retire", chunk, start, end, span, shard_spans), \
-                _trace.stage(prefix + ".retire", span=span.child(
-                    prefix + ".retire", shards=nsh,
-                    lanes_per_shard=size // nsh,
-                )):
+                waited:
             out[start:end] = np.asarray(mask)[: end - start]
-        wait_ns = time.perf_counter_ns() - t_dev
+        last_retire_ns = waited.t1_ns
+        wait_ns = waited.t1_ns - waited.t0_ns
         tot["d2h_s"] += wait_ns / 1e9
         if ledger is not None:
             ledger.note_chunk(
@@ -610,30 +622,38 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         span = _trace.child_of_current(
             "chunk", chunk=chunk, n_sigs=real, shards=nsh
         )
+        fetch_s = 0.0
         with launch_context("dispatch", chunk, start, end, span, ()):
             if fetch is not None:
-                fetch(chunk, start, end, flying)
-            t_host = time.perf_counter_ns()
-            with _trace.stage(prefix + ".pack", span=span.child(
+                fetch_s = fetch(chunk, start, end, flying) or 0.0
+            packed = _trace.stage(prefix + ".pack", span=span.child(
                 prefix + ".pack", chunk=chunk, inflight=flying,
-            )):
+            ))
+            with packed:
                 padded = []
                 for a in build(start, end):
                     p = np.zeros(a.shape[:-1] + (size,), a.dtype)
                     p[..., :real] = a
                     padded.append(p)
-            t_pack = time.perf_counter_ns()
             built = clock.total()
             # the ISSUE cost: both calls return before the device is done;
             # only the launch's own staging is donated
-            with _trace.stage(prefix + ".launch", span=span.child(
+            issued = _trace.stage(prefix + ".launch", span=span.child(
                 prefix + ".launch", shards=nsh, lanes_per_shard=size // nsh,
                 chunk=chunk, inflight=flying,
-            )):
+            ))
+            with issued:
                 placed = [put(p) for p in padded]
+                # the one reading no stage takes: it splits the launch
+                # stage between the transfer's issue and the call's
                 t_h2d = time.perf_counter_ns()
                 mask = call(lead + placed)
-            t_call = time.perf_counter_ns()
+        if flush is not None:
+            if chunk == 0:
+                flush.note_issue(issued.t1_ns, route)
+            if not flying:
+                # nothing was in flight: the device waited for this build
+                flush.add("build_exposed", fetch_s + packed.seconds)
         # attribution comes after the issue: nothing the device does not
         # need stands between a launch's pack and its start
         per = size // n_domains
@@ -645,12 +665,13 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
             ))
             if hub is not None:
                 hub.note_chunk(label, lanes, per)
-        h2d_s = (t_h2d - t_pack) / 1e9
+        h2d_s = (t_h2d - packed.t1_ns) / 1e9
         phases = {
-            "pack_s": (t_pack - t_host) / 1e9,
+            "fetch_s": fetch_s,
+            "pack_s": packed.seconds,
             "h2d_s": h2d_s,
             "compute_s": max(
-                0.0, (t_call - t_h2d) / 1e9 - (clock.total() - built)
+                0.0, (issued.t1_ns - t_h2d) / 1e9 - (clock.total() - built)
             ),
             # a transfer issued while an earlier launch was in flight paid
             # no wall time of its own (only launch 0's H2D is exposed)
@@ -679,6 +700,8 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         inflight.append(issue(chunk, start, end, size, lead))
         drain(depth)
     drain(0)
+    if flush is not None and tot["chunks"]:
+        flush.note_retire(last_retire_ns, tot["chunks"], n)
     tot["wall_s"] = max(
         0.0, time.perf_counter() - t_wall0 - (clock.total() - built0)
     )

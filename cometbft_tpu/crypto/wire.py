@@ -19,9 +19,49 @@ timestamps five phases on every chunk and feeds them here:
   (crypto/scheduler.py notes it at flush level).
 
 compute and d2h split differently per backend; their SUM is the
-device-side residency either way, and pack + h2d + compute + d2h
-reconciles with the dispatch wall time (the ledger records coverage =
-phase sum / wall per dispatch — the acceptance bound is within 10%).
+device-side residency either way, and fetch + pack + h2d + compute +
+d2h reconciles with the dispatch wall time (the ledger records coverage
+= phase sum / wall per dispatch — the acceptance bound is within 10%).
+``fetch`` is a launch's sixth number: what the stream's caller gathered
+for the launch before it was packed (a commit's sign-bytes on the
+resident path), 0 where the stream has no such callback.
+
+A flush's whole life (PR 34). Those phases start at the first launch's
+pack and end at the last retire; what lies in front of and behind them
+is booked by a :class:`FlushRecord`, opened where a flush is born (the
+scheduler's ``_dispatch``; ``verify_commit*`` on the resident path,
+which has no scheduler), carried to whichever thread runs the stream
+(``flush_scope`` / ``current_flush``, re-installed by the supervisor's
+workers beside ``mesh.route_scope``) and closed where the last future
+is set. It holds stamps on ``time.perf_counter_ns`` — each one the
+reading a ``libs/trace.stage`` took anyway, handed on — and the stages'
+own seconds, and closes into the same ``phase_seconds`` histogram
+through ``note_phase`` and into a bounded deque (``flushes``):
+
+* ``queue``    — the oldest rider's submit → ``_dispatch`` entry (the
+  scheduler's own reading of the oldest wait);
+* ``assemble``, ``route`` — the two scheduler stages' own seconds;
+* ``lead``     — ``_verify`` entry (resident: ``verify_commit*``
+  entry) → the first launch's kernel call returned: supervise, the
+  thread hop, ``columns`` (its own phase, inside lead), the first
+  launch's fetch, pack and issue;
+* ``stream``   — the first launch's call returned → the last retire;
+  the launches inside it stay booked as pack / h2d / compute / d2h;
+* ``build_exposed`` — fetch + pack seconds of launches issued with no
+  launch in flight: build time the device waited for;
+* ``tail``     — the last retire → ``_verify`` returned (resident:
+  ``verify_commit*`` returned, the tally included);
+* ``demux``    — as before.
+
+queue + assemble + route + lead + stream + tail + demux is the flush's
+life, last future set less oldest submit, but for the statements
+between two stages. Several streams under one record (a hedge's, a
+retry's, per-domain shards) stamp the earliest issue and the latest
+retire. A flush that never reached a launch (the floor kept it on the
+host) closes with queue, assemble, route and demux alone. A thread that
+is ``tracelib.in_background()`` (canary, probe, audit) opens no record
+and books none of these: nobody waits for its work. There is no switch
+but the ledger's own: no ledger, nothing booked.
 
 Overlap accounting: under the double-buffered pipeline
 (mesh.pipeline_depth) the host packs/transfers chunk N+1 while the
@@ -52,6 +92,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.metrics import MICRO_BUCKETS, Registry
 
 SUBSYSTEM = "verify_wire"
@@ -60,6 +101,13 @@ SUBSYSTEM = "verify_wire"
 # fifth phase, measured at flush level by the scheduler.
 CHUNK_PHASES = ("pack", "h2d", "compute", "d2h")
 PHASES = CHUNK_PHASES + ("demux",)
+# What a FlushRecord holds when it closes and books, one observation a
+# flush each (but demux: note_demux's, with its EWMA rows). Histogram
+# only: no cost profile, prediction or price reads them.
+FLUSH_PHASES = (
+    "queue", "assemble", "route", "lead", "columns", "stream",
+    "build_exposed", "tail", "demux",
+)
 
 DEFAULT_WINDOW = 64     # EWMA window (samples); alpha = 2 / (window + 1)
 _MAX_SAMPLES = 512      # per-phase percentile retention per profile
@@ -119,8 +167,10 @@ class Metrics:
         r = registry if registry is not None else Registry()
         self.phase_seconds = r.histogram(
             SUBSYSTEM, "phase_seconds",
-            "Per-dispatch-phase wall seconds (pack / h2d / compute / "
-            "d2h per chunk, demux per flush), by phase and route.",
+            "Per-dispatch-phase wall seconds (fetch / pack / h2d / "
+            "compute / d2h per chunk; queue / assemble / route / lead / "
+            "columns / stream / build_exposed / tail / demux per "
+            "flush), by phase and route.",
             buckets=MICRO_BUCKETS,
         )
         self.chunks = r.counter(
@@ -163,8 +213,8 @@ class Metrics:
         self.coverage = r.gauge(
             SUBSYSTEM, "coverage",
             "Phase-sum / dispatch-wall reconciliation of the latest "
-            "attributed dispatch, by route (1.0 = the five phases "
-            "account for the whole dispatch).",
+            "attributed dispatch, by route (1.0 = fetch, pack, h2d, "
+            "compute and d2h account for the whole dispatch).",
         )
         self.bytes_per_lane = r.gauge(
             SUBSYSTEM, "bytes_per_lane",
@@ -236,6 +286,138 @@ class _DemuxStat:
         self.samples: deque = deque(maxlen=_MAX_SAMPLES)
 
 
+# Every record's lock: a stamp comes once a stream and an add is three
+# dictionary operations, so records do not wait for each other, and a
+# flush every few milliseconds allocates no lock of its own.
+_record_lock = threading.Lock()
+
+
+class FlushRecord:
+    """One flush's life on ``time.perf_counter_ns``: the stamps of its
+    edges and the lexical seconds inside them (module docstring). Made
+    by ``WireLedger.open_flush``; the thread that opened it closes it,
+    any thread under its ``flush_scope`` may ``add`` seconds and stamp a
+    stream, and nothing said after ``close`` is kept (a worker the
+    watchdog abandoned). What a flush pays for it is on its verdict's
+    path (the riders wake when the flush thread next blocks), so close
+    computes three differences, observes the phases and keeps the record
+    itself; ``entry()`` formats it when somebody asks."""
+
+    __slots__ = (
+        "_ledger", "_closed", "lanes", "route", "streams", "launches",
+        "stream_lanes", "t_born_ns", "t_lead_ns", "t_issue_ns",
+        "t_retire_ns", "t_done_ns", "t_close_ns", "seconds",
+    )
+
+    def __init__(self, ledger: "WireLedger", lanes: int, t_born_ns: int,
+                 t_lead_ns: int, seconds: Dict[str, float]):
+        self._ledger = ledger
+        self._closed = False
+        self.lanes = lanes
+        self.route: Optional[str] = None  # of the first stream, if any
+        self.streams = 0
+        self.launches = 0
+        self.stream_lanes = 0
+        self.t_born_ns = t_born_ns
+        self.t_lead_ns = t_lead_ns
+        self.t_issue_ns: Optional[int] = None
+        self.t_retire_ns: Optional[int] = None
+        self.t_done_ns = self.t_close_ns = t_lead_ns
+        self.seconds = seconds
+
+    def add(self, phase: str, seconds: float) -> None:
+        """``seconds`` more of a lexical phase (a stage's own reading)."""
+        with _record_lock:
+            if not self._closed:
+                self.seconds[phase] = self.seconds.get(phase, 0.0) + seconds
+
+    def note_issue(self, t_ns: int, route: str) -> None:
+        """A stream's first launch was issued (its call returned) at
+        ``t_ns``; the earliest of several streams stands."""
+        with _record_lock:
+            if self._closed:
+                return
+            self.streams += 1
+            if self.route is None:
+                self.route = route
+            if self.t_issue_ns is None or t_ns < self.t_issue_ns:
+                self.t_issue_ns = t_ns
+
+    def note_retire(self, t_ns: int, launches: int, lanes: int) -> None:
+        """A stream of ``launches`` over ``lanes`` real lanes retired its
+        last launch at ``t_ns``; the latest of several streams stands."""
+        with _record_lock:
+            if self._closed:
+                return
+            self.launches += launches
+            self.stream_lanes += lanes
+            if self.t_retire_ns is None or t_ns > self.t_retire_ns:
+                self.t_retire_ns = t_ns
+
+    def close(self, route: Optional[str] = None,
+              t_done_ns: Optional[int] = None,
+              t_close_ns: Optional[int] = None,
+              **seconds: float) -> Optional["FlushRecord"]:
+        """Books the record's phases under ``route`` (left out: the
+        route of the stream that ran; none ran: nothing is booked).
+        ``t_done_ns`` is where the verify returned, ``t_close_ns`` where
+        the last future was set: the demux stage's two readings, or one
+        reading of the clock here for a caller that has no demux.
+        ``seconds`` are last lexical phases (``demux=``). → the record,
+        or None where nothing was booked."""
+        with _record_lock:
+            if self._closed:
+                return None
+            self._closed = True
+        if route is None:
+            route = self.route
+            if route is None:
+                return None
+        if t_done_ns is None:
+            t_done_ns = t_close_ns = time.perf_counter_ns()
+        elif t_close_ns is None:
+            t_close_ns = t_done_ns
+        booked = self.seconds
+        if seconds:
+            booked.update(seconds)
+        t_issue, t_retire = self.t_issue_ns, self.t_retire_ns
+        if t_issue is not None:
+            # a stream the answer did not wait for (a hedge the host
+            # won) ends, for this flush, where the verify returned
+            if t_retire is None or t_retire > t_done_ns:
+                t_retire = t_done_ns
+            if t_issue > t_retire:
+                t_issue = t_retire
+            booked["lead"] = max(0, t_issue - self.t_lead_ns) / 1e9
+            booked["stream"] = (t_retire - t_issue) / 1e9
+            booked["tail"] = (t_done_ns - t_retire) / 1e9
+        self.route = route
+        self.t_done_ns, self.t_close_ns = t_done_ns, t_close_ns
+        self._ledger._note_flush(self)
+        return self
+
+    def entry(self) -> Dict[str, Any]:
+        """The closed record as ``flushes`` serves it, in ms."""
+        return {
+            "route": self.route,
+            # the riders' lanes; a record that has no riders (the
+            # resident path's own): the lanes its streams carried
+            "lanes": int(self.lanes or self.stream_lanes),
+            "streams": self.streams,
+            "launches": int(self.launches),
+            "life_ms": round(
+                max(0, self.t_close_ns - self.t_born_ns) / 1e6, 4
+            ),
+            "verify_ms": round(
+                max(0, self.t_done_ns - self.t_lead_ns) / 1e6, 4
+            ),
+            "phases_ms": {
+                ph: round(self.seconds[ph] * 1e3, 4)
+                for ph in FLUSH_PHASES if ph in self.seconds
+            },
+        }
+
+
 class WireLedger:
     """Continuous per-phase dispatch attribution with EWMA cost
     profiles keyed by (route, pow2 bucket, device). Thread-safe; the
@@ -255,9 +437,12 @@ class WireLedger:
         self._profiles: Dict[Tuple[str, int, str], _Profile] = {}
         self._demux: Dict[Tuple[str, int], _DemuxStat] = {}
         self._recent: deque = deque(maxlen=_MAX_DISPATCHES)
+        self._flushes: deque = deque(maxlen=self.window)
+        self._phase_series: Dict[str, Dict[str, Any]] = {}
         self.chunks = 0
         self.n_dispatches = 0
         self.demux_notes = 0
+        self.flush_notes = 0
         self._lanes: Dict[str, int] = {}
         self._padded: Dict[str, int] = {}
         self._link = dict(link) if link else None
@@ -291,13 +476,16 @@ class WireLedger:
         d2h_s: float,
         hidden_s: float = 0.0,
         padded_lanes: Optional[int] = None,
+        fetch_s: float = 0.0,
     ) -> None:
         """One chunk's phase attribution from the mesh dispatch loop.
         ``hidden_s`` is the portion of ``h2d_s`` spent while an earlier
         chunk was still in flight (paid no wall time). ``padded_lanes``
         is what the chunk was padded to over all its shards; left out,
         it is ``bucket`` (a loop that keys its profiles by the per-shard
-        bucket says the total)."""
+        bucket says the total). ``fetch_s`` is what the stream's caller
+        gathered for this launch before it was packed (launch_stream's
+        ``fetch``): a histogram phase of its own, in no cost profile."""
         a = self._alpha
         bucket = int(bucket)
         padded = max(0, int(bucket if padded_lanes is None else padded_lanes))
@@ -345,6 +533,8 @@ class WireLedger:
         m = self.metrics
         for name, v in phases:
             m.phase_seconds.with_labels(phase=name, route=route).observe(v)
+        if fetch_s > 0.0:
+            self.note_phase(route, "fetch", fetch_s)
         m.chunks.with_labels(route=route).add()
         m.lanes.with_labels(route=route).add(max(0, int(lanes)))
         m.padded_lanes.with_labels(route=route).add(padded)
@@ -371,11 +561,13 @@ class WireLedger:
         hidden_s: float,
         wire_bytes: int,
         chunks: int,
+        fetch_s: float = 0.0,
     ) -> None:
         """One whole dispatch_batch/dispatch_sharded call: summed phase
         seconds vs the observed wall — the reconciliation record the
-        acceptance bound (within 10%) is judged on."""
-        phase_s = pack_s + h2d_s + compute_s + d2h_s
+        acceptance bound (within 10%) is judged on. ``fetch_s`` (the
+        stream's totals carry it) runs inside the wall, so it counts."""
+        phase_s = fetch_s + pack_s + h2d_s + compute_s + d2h_s
         coverage = (phase_s / wall_s) if wall_s > 0.0 else None
         overlap = (
             max(0.0, min(1.0, hidden_s / h2d_s)) if h2d_s > 0.0 else None
@@ -386,6 +578,7 @@ class WireLedger:
             "n": int(n),
             "chunks": int(chunks),
             "wall_ms": round(wall_s * 1e3, 3),
+            "fetch_ms": round(fetch_s * 1e3, 3),
             "pack_ms": round(pack_s * 1e3, 3),
             "h2d_ms": round(h2d_s * 1e3, 3),
             "compute_ms": round(compute_s * 1e3, 3),
@@ -424,6 +617,67 @@ class WireLedger:
         self.metrics.phase_seconds.with_labels(
             phase="demux", route=route
         ).observe(demux_s)
+
+    def _series_of(self, route: str) -> Dict[str, Any]:
+        """{phase: its ``phase_seconds`` series} for ``route``, made once
+        (a series nothing was observed in is not exposed)."""
+        series = self._phase_series.get(route)
+        if series is None:
+            series = self._phase_series[route] = {
+                phase: self.metrics.phase_seconds.with_labels(
+                    phase=phase, route=route
+                )
+                for phase in FLUSH_PHASES + ("fetch",)
+            }
+        return series
+
+    def note_phase(self, route: str, phase: str, seconds: float) -> None:
+        """``seconds`` of a flush phase or of ``fetch`` into
+        ``phase_seconds{phase, route}``: the histogram and nothing else."""
+        self._series_of(route)[phase].observe(max(0.0, seconds))
+
+    def open_flush(
+        self,
+        lanes: int = 0,
+        t_born_ns: Optional[int] = None,
+        t_lead_ns: Optional[int] = None,
+        **seconds: float,
+    ) -> Optional[FlushRecord]:
+        """A record for the flush that is being dispatched on this
+        thread, or None on a background thread (canary, probe, audit:
+        nobody waits for it). ``t_lead_ns`` is where the verify starts
+        (left out: the clock is read here), ``t_born_ns`` where the
+        flush's life does: the oldest rider's submit (left out: where
+        the verify starts; no queue, the resident path). ``seconds`` are
+        the lexical phases that are over already (``queue=``,
+        ``assemble=``, ``route=``)."""
+        if tracelib.in_background():
+            return None
+        if t_lead_ns is None:
+            t_lead_ns = time.perf_counter_ns()
+        return FlushRecord(
+            self, lanes, t_lead_ns if t_born_ns is None else t_born_ns,
+            t_lead_ns, seconds,
+        )
+
+    def _note_flush(self, rec: FlushRecord) -> None:
+        series = self._series_of(rec.route)
+        for phase, seconds in rec.seconds.items():
+            # demux is in the record and booked by note_demux, which the
+            # scheduler calls as it always did
+            if phase != "demux":
+                series[phase].observe(seconds)
+        with self._lock:
+            self.flush_notes += 1
+            self._flushes.append(rec)
+
+    def flushes(self) -> List[dict]:
+        """The last ``window`` closed flush records, oldest first: what
+        ``snapshot()`` serves under ``flushes`` and an incident dump
+        carries."""
+        with self._lock:
+            recs = list(self._flushes)
+        return [rec.entry() for rec in recs]
 
     # --- cost queries --------------------------------------------------------
 
@@ -558,8 +812,9 @@ class WireLedger:
     def snapshot(self) -> Dict[str, Any]:
         """The /debug/verify wire section: per-(route, bucket, device)
         phase EWMAs + p50/p99, bytes/lane, effective bandwidth, overlap
-        ratio, demux stats, the probed link ceiling, and the most
-        recent dispatch reconciliation records."""
+        ratio, demux stats, the probed link ceiling, the most recent
+        dispatch reconciliation records, and the last ``window`` flush
+        records (a flush's life by phase, ms)."""
         with self._lock:
             profiles = [
                 (k, p.n, dict(p.ewma_s),
@@ -625,6 +880,8 @@ class WireLedger:
             "profiles": prof_rows,
             "demux": demux_rows,
             "recent": recent,
+            "flush_notes": self.flush_notes,
+            "flushes": self.flushes(),
         }
 
 
@@ -672,6 +929,91 @@ def set_default_ledger(
         prev = _default_ledger
         _default_ledger = ledger
         return prev
+
+
+# --- the flush a thread is working for ---------------------------------------
+# The scheduler's flush thread installs its record around the verify; a
+# thread that takes the work over (the supervisor's dispatch worker, a
+# per-domain shard) re-installs what its spawner had, the pattern of
+# mesh.route_scope and decisions.use. The mesh's stream and the
+# verifier's columns pass read it with one attribute lookup.
+
+_flush_local = threading.local()
+
+
+def current_flush() -> Optional[FlushRecord]:
+    """The flush record THIS thread works for, if any; none while the
+    thread's work is background (the one place the rule is kept: a
+    worker that re-applies its spawner's ``tracelib.background`` reads
+    None here whatever it was handed)."""
+    if tracelib.in_background():
+        return None
+    return getattr(_flush_local, "rec", None)
+
+
+class flush_scope:
+    """Context manager installing ``rec`` (None: explicitly none) as
+    this thread's flush record; nests."""
+
+    __slots__ = ("_rec", "_prev")
+
+    def __init__(self, rec: Optional[FlushRecord]):
+        self._rec = rec
+        self._prev = None
+
+    def __enter__(self) -> Optional[FlushRecord]:
+        self._prev = getattr(_flush_local, "rec", None)
+        _flush_local.rec = self._rec
+        return self._rec
+
+    def __exit__(self, *exc_info) -> bool:
+        _flush_local.rec = self._prev
+        return False
+
+
+def open_flush(*stamps: Any, **seconds: float) -> Optional[FlushRecord]:
+    """The process-default ledger's ``open_flush``; None without a
+    ledger or on a background thread."""
+    ledger = _default_ledger
+    if ledger is None:
+        return None
+    return ledger.open_flush(*stamps, **seconds)
+
+
+def add_phase(phase: str, seconds: float) -> None:
+    """``seconds`` of a lexical phase to this thread's flush record;
+    nothing where there is none."""
+    rec = current_flush()
+    if rec is not None:
+        rec.add(phase, seconds)
+
+
+class own_flush:
+    """For an entry point that may run with no scheduler above it
+    (``verify_commit*``): opens a record where the thread has none and
+    closes it under the route of the stream that ran inside; a call that
+    reached no stream (a commit under the floor, a commit that went
+    through the scheduler, whose flush thread keeps its own record)
+    books nothing."""
+
+    __slots__ = ("_rec",)
+
+    def __init__(self) -> None:
+        self._rec: Optional[FlushRecord] = None
+
+    def __enter__(self) -> Optional[FlushRecord]:
+        if current_flush() is None:
+            rec = self._rec = open_flush()
+            if rec is not None:
+                _flush_local.rec = rec
+        return self._rec
+
+    def __exit__(self, *exc_info) -> bool:
+        rec = self._rec
+        if rec is not None:
+            _flush_local.rec = None
+            rec.close()
+        return False
 
 
 def seed_from_calibration(ledger: Optional[WireLedger] = None) -> bool:

@@ -5,7 +5,7 @@ BackendSupervisor -> mesh.dispatch_batch) and several threads.  Aggregate
 counters cannot attribute a slow commit verification to queue wait vs.
 flush deadline vs. device dispatch vs. CPU fallback; spans can.
 
-Two recorders, one entry point (``stage``):
+Two recorders and one clock reading, one entry point (``stage``):
 
 - The *flight recorder* (``Tracer``/``Span``) is SAMPLED
   (``trace_sample``, 0 by default) and keeps whole request trees with
@@ -23,6 +23,19 @@ Two recorders, one entry point (``stage``):
   attribution gives a gap to the span opened last on ANY thread, so
   work that no request waits for runs under ``background()`` and writes
   none.
+- Neither of the two gives seconds in a process that runs unsampled and
+  unprofiled, which is every node on every day.  So a ``stage`` reads
+  ``time.perf_counter_ns`` once as it opens and once as it closes,
+  always (two reads, no lock, nothing allocated) and serves
+  ``t0_ns`` / ``t1_ns`` / ``seconds`` after exit.  That pair is the ONE
+  reading of the region: whoever needs its seconds keeps the stage
+  object and takes them from it, and never wraps a second clock pair
+  around it.  The always-on books are fed from it: for the verify plane
+  the wire ledger (``crypto/wire.py``: a launch's ``pack`` / ``h2d`` /
+  ``compute`` / ``d2h`` from ``<prefix>.pack / .launch / .retire``, a
+  flush's ``assemble`` / ``route`` / ``demux`` / ``columns`` / ``fetch``
+  and the edges of its ``queue`` / ``lead`` / ``tail`` intervals), for
+  the blocksync reactor and the block executor their ``StageSeconds``.
 
 Design:
 
@@ -231,9 +244,16 @@ class stage:
     chunk's child); ``NOOP_SPAN`` there means annotation only, for a
     span that is made on one thread and ended on another.  Ending is
     first-wins, so a body that ends the span with its outcome tags
-    keeps them.  Nothing the tracing itself raises reaches the body."""
+    keeps them.  Nothing the tracing itself raises reaches the body.
 
-    __slots__ = ("_label", "_span", "_ann")
+    The stage reads ``time.perf_counter_ns`` first thing as it opens and
+    last thing as it closes, so two stages in a row leave nothing
+    between them but the statements between them; ``t0_ns``, ``t1_ns``
+    and ``seconds`` are for the caller that kept the stage object
+    (``st = stage(...)``, ``with st as span:``) and are the only clock
+    reading the region needs."""
+
+    __slots__ = ("_label", "_span", "_ann", "t0_ns", "t1_ns")
 
     def __init__(self, name: str, span: Optional["Span"] = None, **tags: Any):
         self._label = STAGE_PREFIX + name
@@ -241,8 +261,16 @@ class stage:
             child_of_current(name, **tags) if span is None else span
         )
         self._ann = None
+        self.t0_ns = 0
+        self.t1_ns = 0
+
+    @property
+    def seconds(self) -> float:
+        """The closed stage's wall seconds (0.0 while it is open)."""
+        return max(0, self.t1_ns - self.t0_ns) / 1e9
 
     def __enter__(self) -> "Span":
+        self.t0_ns = time.perf_counter_ns()
         span = self._span
         if not span.noop:
             _install(span)
@@ -263,6 +291,7 @@ class stage:
         if not span.noop:
             _uninstall(span)
             span.__exit__(etype, exc, tb)
+        self.t1_ns = time.perf_counter_ns()
         return False
 
 
@@ -295,22 +324,24 @@ class StageSeconds:
 
 
 class _BookedStage:
-    __slots__ = ("_book", "_name", "_stage", "_t0")
+    __slots__ = ("_book", "_name", "_stage")
 
     def __init__(self, book: StageSeconds, name: str, tags: Dict[str, Any]):
         self._book = book
         self._name = name
         self._stage = stage(name, **tags)
-        self._t0 = 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self._stage.seconds
 
     def __enter__(self) -> "Span":
-        span = self._stage.__enter__()
-        self._t0 = time.perf_counter()
-        return span
+        return self._stage.__enter__()
 
     def __exit__(self, etype: Any, exc: Any, tb: Any) -> bool:
-        self._book._add(self._name, time.perf_counter() - self._t0)
-        return self._stage.__exit__(etype, exc, tb)
+        out = self._stage.__exit__(etype, exc, tb)
+        self._book._add(self._name, self._stage.seconds)
+        return out
 
 
 class background:

@@ -366,10 +366,17 @@ class Node(BaseService):
         # every incident dump — whoever triggers it — carries the memory
         # plane's view of the device; the post-mortem reads HBM pressure
         # next to the breaker states instead of guessing
-        _mem_plane = self.memory_plane
-        self.tracer.set_dump_context(
-            lambda: {"memory": _mem_plane.snapshot()}
-        )
+        # ... and the wire ledger's last flush records: where the flushes
+        # before the incident spent their lives, by phase
+        _mem_plane, _wire = self.memory_plane, self.wire_ledger
+
+        def _dump_context() -> dict:
+            doc = {"memory": _mem_plane.snapshot()}
+            if _wire is not None:
+                doc["flushes"] = _wire.flushes()
+            return doc
+
+        self.tracer.set_dump_context(_dump_context)
 
         # 0h. the decision ledger (crypto/decisions.py): one
         # RouteDecision per coalesced flush — inputs, per-candidate

@@ -16,12 +16,14 @@ truncation-toward-zero integer division.
 
 from __future__ import annotations
 
+import functools
 from bisect import bisect_left
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from cometbft_tpu.crypto import batch as cryptobatch
 from cometbft_tpu.crypto import merkle
+from cometbft_tpu.crypto import wire as wirelib
 from cometbft_tpu.libs import protoio
 from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.types.block import BlockID, Commit
@@ -31,6 +33,21 @@ PRIORITY_WINDOW_SIZE_FACTOR = 2  # validator_set.go PriorityWindowSizeFactor
 
 _INT64_MAX = (1 << 63) - 1
 _INT64_MIN = -(1 << 63)
+
+
+def _books_its_flush(verify):
+    """A ``verify_commit*`` entry runs under a flush record of the wire
+    ledger (crypto/wire.own_flush): with no scheduler above it (the
+    resident path) the call IS the flush, its ``lead`` starts here and
+    its ``tail`` ends where it returns or raises, the tally included. A
+    commit that reaches no device stream on this thread books nothing."""
+
+    @functools.wraps(verify)
+    def wrapper(*args, **kwargs):
+        with wirelib.own_flush():
+            return verify(*args, **kwargs)
+
+    return wrapper
 
 
 def _go_div(a: int, b: int) -> int:
@@ -317,6 +334,7 @@ class ValidatorSet:
         _, mask = bv.verify()
         return mask
 
+    @_books_its_flush
     def verify_commit(
         self,
         chain_id: str,
@@ -367,6 +385,7 @@ class ValidatorSet:
         if tallied <= needed:
             raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
+    @_books_its_flush
     def verify_commit_light(
         self,
         chain_id: str,
@@ -418,6 +437,7 @@ class ValidatorSet:
                     return
         raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
+    @_books_its_flush
     def verify_commit_light_trusting(
         self,
         chain_id: str,
