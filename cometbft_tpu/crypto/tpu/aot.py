@@ -321,8 +321,12 @@ class Metrics:
 
 # Bumped when what a stored blob means changes; older files are then
 # never looked up. 2: entries are loaded onto their own device assignment
-# and proven to run when loaded (ExecutableStore.load).
-_STORE_FORMAT = 2
+# and proven to run when loaded (ExecutableStore.load). 3: field.sq is a
+# squaring (153 limb products), so every ed25519 / sr25519 program
+# changed, and the key holds nothing of a program: kernel name, shapes
+# and fingerprints are those of the executables a checkout upgraded in
+# place still has in its store.
+_STORE_FORMAT = 3
 
 
 class ExecutableStore:

@@ -426,7 +426,8 @@ _MIN_PAD = 64
 # ladder and the memory guard halve it, and the scheduler budgets a
 # flush's lanes by it. The ed25519 entries launch BELOW it, at
 # _LAUNCH_LANES a chip; it is a launch's size only once it has been
-# shrunk under that. On the v5e the program runs at 7.3-8.5 us a lane
+# shrunk under that. On the v5e the program runs at 6.2-6.5 us a lane
+# (7.3-8.5 while a squaring cost a full multiplication; PERF.md, PR 35)
 # whatever the bucket from 512 up and a launch costs 0.75 ms to issue
 # (1.75 ms over four chips), so a larger launch buys no device time: two
 # launches of 8,192 + 2,048 padded lanes beat one of 16,384 by 19 ms a

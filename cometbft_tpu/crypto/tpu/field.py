@@ -11,6 +11,12 @@ to assembly in golang.org/x/crypto; there is no Go source to mirror):
   a sequential 17-step scan, so every op is a handful of wide [17, B]
   VPU instructions. Exact bounds are proven per-op below; limb products
   (2^15+127)^2 < 2^31 stay inside native int32 multiplies.
+* A squaring is a squaring: `sq` takes a² from its 153 distinct limb
+  products (17 diagonal, 136 off-diagonal taken once at weight 2)
+  where `mul` takes 289 — 1,529 of a verification lane's 3,700 field
+  multiplications. The same columns as `mul(a, a)`, integer for
+  integer, so the same limbs; each multiplication form has its square
+  (`_SQ_IMPLS` beside `_MUL_IMPLS`, one name selects both).
 * **Limb-major layout**: the limb axis is axis 0 and the batch axis is
   the trailing (minor-most) axis. XLA's TPU layout maps the minor-most
   dimension onto the 128-wide vector lanes — with the batch there, every
@@ -164,29 +170,30 @@ def _mul_shift_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return _reduce(folded)
 
 
-def _fold_matrices():
-    """Constant [17, 289] int32 matrices folding the flattened outer
-    product (lo and hi 15-bit parts) straight into the 17 output columns:
-    entry (k, 17i+j) is the weight of a_i·b_j's part in column k — 1 on
-    its own column c, 19 on c-17 (2^255 ≡ 19). Precomposing the
-    column-fold into the scatter matrix turns the whole schoolbook
-    multiply into two matmuls."""
-    import numpy as np
-
-    m_lo = np.zeros((NUM_LIMBS, NUM_LIMBS * NUM_LIMBS), np.int32)
-    m_hi = np.zeros((NUM_LIMBS, NUM_LIMBS * NUM_LIMBS), np.int32)
-    for i in range(NUM_LIMBS):
-        for j in range(NUM_LIMBS):
-            idx = i * NUM_LIMBS + j
-            for m, c in ((m_lo, i + j), (m_hi, i + j + 1)):
-                if c < NUM_LIMBS:
-                    m[c, idx] = 1
-                else:
-                    m[c - NUM_LIMBS, idx] = 19
+def _fold_matrices(i_idx, j_idx, weight):
+    """Constant [17, n] int32 matrices folding the lo and hi 15-bit
+    parts of the n limb products a_{i_idx}·b_{j_idx} straight into the
+    17 output columns: entry (k, idx) is the weight of product idx's
+    part in column k — weight[idx] on its own column c, 19 times that on
+    c-17 (2^255 ≡ 19). Precomposing the column-fold into the scatter
+    matrix turns the whole schoolbook multiply into two matmuls. Host
+    arrays, built once."""
+    m_lo = np.zeros((NUM_LIMBS, len(i_idx)), np.int32)
+    m_hi = np.zeros((NUM_LIMBS, len(i_idx)), np.int32)
+    for idx, (i, j, w) in enumerate(zip(i_idx, j_idx, weight)):
+        for m, c in ((m_lo, i + j), (m_hi, i + j + 1)):
+            if c < NUM_LIMBS:
+                m[c, idx] = w
+            else:
+                m[c - NUM_LIMBS, idx] = 19 * w
     return m_lo, m_hi
 
 
-_M_LO, _M_HI = _fold_matrices()
+# the flattened outer product: product 17i+j is a_i·b_j, weight 1
+_M_LO, _M_HI = _fold_matrices(
+    *np.indices((NUM_LIMBS, NUM_LIMBS)).reshape(2, -1),
+    np.ones(NUM_LIMBS * NUM_LIMBS, np.int32),
+)
 
 
 def _mul_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -284,6 +291,18 @@ def _mul_f32(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 # columns 17..33 (weight 2^255 ≡ 19) brings them to < 2^25 — the
 # _reduce precondition. All implementations share this bound analysis
 # (the f32 form documents its own).
+#
+# A square takes each off-diagonal product a_i·a_j (i < j) ONCE and
+# weights it 2. The weight goes on the PARTS, never on an operand or on
+# the product: 2·a_i·a_j reaches 2·(2^15+127)^2 > 2^31 (already
+# 32,768 × 65,536 = 2^31), so a pre-doubled limb or a product doubled
+# before the split wraps. Split first (p & 0x7FFF, p >> 15), then double
+# the off-diagonal parts' column sums: 2·lo(p) + 2·hi(p)·2^15 is what
+# mul(a, a) adds for p = a_i·a_j and again for a_j·a_i, so every column
+# is the exact integer mul(a, a) gives it and the bounds above hold
+# unchanged (over all 2^17 vectors of the invariant's corners, limbs -4
+# or 2^15+127: worst column 834,807 < 2^21, worst folded column
+# 15,585,451 < 2^25).
 _MUL_IMPLS = {
     "stack": _mul_stack,
     "shift_add": _mul_shift_add,
@@ -303,22 +322,125 @@ def default_mul_impl() -> str:
     return "matmul" if jax.default_backend() == "cpu" else "stack"
 
 
-def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Schoolbook 17×15-bit-limb multiply in native int32 lanes."""
+def _impl(impls: dict):
+    """The form CBFT_TPU_MUL (or the platform) names, of a product or of
+    a square: one name selects both."""
     import os
 
     name = os.environ.get("CBFT_TPU_MUL") or default_mul_impl()
-    impl = _MUL_IMPLS.get(name)
+    impl = impls.get(name)
     if impl is None:
         raise ValueError(
             f"unknown CBFT_TPU_MUL={name!r}; choose from "
-            f"{sorted(_MUL_IMPLS)}"
+            f"{sorted(impls)}"
         )
-    return impl(a, b)
+    return impl
+
+
+def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Schoolbook 17×15-bit-limb multiply in native int32 lanes."""
+    return _impl(_MUL_IMPLS)(a, b)
+
+
+# The 153 distinct limb products of a square, (i, j) with i ≤ j in row
+# order, and their fold matrices: a diagonal product at weight 1, an
+# off-diagonal one (taken once, standing for a_i·a_j and a_j·a_i) at 2.
+_SQ_I, _SQ_J = np.triu_indices(NUM_LIMBS)
+_SQ_M_LO, _SQ_M_HI = _fold_matrices(_SQ_I, _SQ_J, 1 + (_SQ_I != _SQ_J))
+
+
+def _sq_diagonals(a: jnp.ndarray):
+    """The 136 off-diagonal products, by diagonals: diagonal k (1..16) is
+    ``a[:17-k] * a[k:]``, the products a_i·a_{i+k}, two plain slices of
+    the operand and no broadcast. Their lo parts fall on columns 2i+k,
+    every OTHER column, so the 34 columns are kept as two [17, B] halves
+    — E, columns 0, 2, .., 32, and O, columns 1, 3, .., 33 — in each of
+    which a diagonal is contiguous: for k = 2t the lo parts are
+    E[t..t+16-k] and the hi parts (one column up) O[t..]; for k = 2t+1
+    the lo parts are O[t..] and the hi parts E[t+1..]. → (the padded
+    [17, B] terms of E, those of O), weight not yet applied.
+
+    Chosen on the v5e over the triangle's rows (``a[i] * a[i+1:]`` into
+    34 columns) and over one concatenated product: 2.48 us a square at
+    [17, 2048] against 2.97 and 12.3, mul(a, a) 4.25; the whole
+    verify_compact@2048 12.77 ms against 13.63, and 15.80 with
+    mul(a, a) (PERF.md, PR 35)."""
+    tail_pad = [(0, 0)] * (a.ndim - 1)
+    even, odd = [], []
+    for k in range(1, NUM_LIMBS):
+        p = a[: NUM_LIMBS - k] * a[k:]  # [17-k, B]
+        lo_to, hi_to = (even, odd) if k % 2 == 0 else (odd, even)
+        lo_at = k // 2
+        hi_at = lo_at + k % 2
+        lo_to.append(jnp.pad(p & _MASK, [(lo_at, k - lo_at)] + tail_pad))
+        hi_to.append(jnp.pad(p >> RADIX, [(hi_at, k - hi_at)] + tail_pad))
+    return even, odd
+
+
+def _sq_fold(a: jnp.ndarray, even_off: jnp.ndarray,
+             odd_off: jnp.ndarray) -> jnp.ndarray:
+    """The diagonal a_i² (lo on column 2i = E[i], hi on O[i]) plus the
+    summed off-diagonal parts at weight 2, folded (column c + 17 onto c
+    at weight 19: O[m+8] onto E[m], E[m+9] onto O[m]) and interleaved
+    ONCE back into limb order."""
+    d = a * a
+    e = (d & _MASK) + 2 * even_off  # the weight, on the summed PARTS
+    o = (d >> RADIX) + 2 * odd_off
+    half = (NUM_LIMBS + 1) // 2  # 9 even limbs 0, 2, .., 16; 8 odd ones
+    f_even = e[:half] + 19 * o[half - 1 :]
+    f_odd = o[: half - 1] + 19 * e[half:]
+    f_odd = jnp.pad(f_odd, [(0, 1)] + [(0, 0)] * (a.ndim - 1))
+    folded = jnp.stack([f_even, f_odd], axis=1).reshape(
+        (2 * half,) + a.shape[1:]
+    )[:NUM_LIMBS]
+    return _reduce(folded)
+
+
+def _sq_stack(a: jnp.ndarray) -> jnp.ndarray:
+    """_mul_stack's square: each half's terms stacked and summed."""
+    even, odd = _sq_diagonals(a)
+    return _sq_fold(
+        a,
+        jnp.sum(jnp.stack(even, axis=0), axis=0),
+        jnp.sum(jnp.stack(odd, axis=0), axis=0),
+    )
+
+
+def _sq_shift_add(a: jnp.ndarray) -> jnp.ndarray:
+    """_mul_shift_add's square: the same terms into two running
+    accumulators."""
+    even, odd = _sq_diagonals(a)
+    return _sq_fold(a, sum(even[1:], even[0]), sum(odd[1:], odd[0]))
+
+
+def _sq_matmul(a: jnp.ndarray) -> jnp.ndarray:
+    """_mul_matmul's square: ONE [153, B] product from two static index
+    vectors, folded by two [17, 153] matmuls that carry the weights —
+    a smaller graph than the [289, B] product's, which is what the CPU
+    backend's compile time follows."""
+    prod = a[_SQ_I] * a[_SQ_J]  # [153, B]
+    folded = jnp.asarray(_SQ_M_LO) @ (prod & _MASK) + jnp.asarray(
+        _SQ_M_HI
+    ) @ (prod >> RADIX)
+    return _reduce(folded)
+
+
+# One square per multiplication form, under the same names: CBFT_TPU_MUL
+# selects both. f32 squares through its product (it lost 3.3× on the
+# last chip that ran it; ROADMAP C1).
+_SQ_IMPLS = {
+    "stack": _sq_stack,
+    "shift_add": _sq_shift_add,
+    "matmul": _sq_matmul,
+    "f32": lambda a: _mul_f32(a, a),
+}
 
 
 def sq(a: jnp.ndarray) -> jnp.ndarray:
-    return mul(a, a)
+    """a² from its 153 distinct limb products (17 diagonal, 136
+    off-diagonal taken once at weight 2) instead of mul's 289: the same
+    columns, so the same limbs as mul(a, a)."""
+    return _impl(_SQ_IMPLS)(a)
 
 
 def mul_small(a: jnp.ndarray, c: int) -> jnp.ndarray:

@@ -306,7 +306,9 @@ class TestBucketLadder:
         plan = [
             (t.name, t.bucket) for t in
             aot.warmup_plan(floor=1024, include_single=True)
-            if not t.sharded
+            # the ed25519 entries: a kernel another test file registered
+            # in this process without a ``launch`` keeps the whole ladder
+            if not t.sharded and t.name.startswith("ed25519.")
         ]
         assert plan[0] == ("ed25519.verify_compact", 64)
         assert [b for _, b in plan].count(64) == 1

@@ -82,6 +82,249 @@ class TestField:
         assert _fe_int(x) == pow(fe.P - 2, 2**6, fe.P), impl
 
 
+_TOP = 2**15 + 127  # the invariant's upper edge; -4 is its lower
+
+# raw limb vectors at the invariant's corners, which _fe1 never produces
+# (it builds canonical 15-bit limbs)
+_SQ_CORNERS = {
+    "all_top": [_TOP] * 17,
+    "all_bottom": [-4] * 17,
+    "alternating": [_TOP, -4] * 8 + [_TOP],
+    "alternating_from_bottom": [-4, _TOP] * 8 + [-4],
+    "limb0_top_rest_2^15": [_TOP] + [2**15] * 16,
+}
+
+
+def _raw(limbs):
+    import jax.numpy as jnp
+
+    return jnp.array(limbs, jnp.int32)[:, None]
+
+
+def _raw_int(x) -> int:
+    """The integer a (possibly redundant, signed) limb vector stands for."""
+    return fe.limbs_to_int(np.asarray(x)[:, 0])
+
+
+@pytest.mark.parametrize("impl", sorted(fe._SQ_IMPLS))
+class TestSquareForms:
+    """field.sq takes a square from its 153 distinct limb products; every
+    form CBFT_TPU_MUL can select has one, and each must give what the
+    product of that form gives — the TPU's default (stack) otherwise
+    runs only on hardware."""
+
+    def test_matches_oracle_on_random_elements(self, impl):
+        sq = fe._SQ_IMPLS[impl]
+        rng = np.random.default_rng(impl.encode()[0] + 1)
+        for _ in range(8):
+            a = int(rng.integers(0, 2**63)) ** 5 % fe.P
+            assert _fe_int(sq(_fe1(a))) == a * a % fe.P, impl
+
+    @pytest.mark.parametrize("corner", sorted(_SQ_CORNERS))
+    def test_invariant_corners(self, impl, corner):
+        """Limbs at the edges of [-4, 2^15 + 127]. A square that doubles
+        an OPERAND (2·a_i) or a PRODUCT before the split fails here:
+        2 · (2^15 + 127)^2 > 2^31 wraps int32; the weight 2 belongs on
+        the 15-bit parts."""
+        a = _raw(_SQ_CORNERS[corner])
+        got = fe._SQ_IMPLS[impl](a)
+        assert _raw_int(got) % fe.P == _raw_int(a) ** 2 % fe.P, (impl, corner)
+        limbs = np.asarray(got)
+        assert limbs.min() >= -4 and limbs.max() <= _TOP, (impl, corner)
+
+    def test_equals_the_forms_own_product(self, impl):
+        """sq(a) and mul(a, a) of one form: the same columns, so the same
+        limbs after to_canonical (and before: the sums are one integer)."""
+        sq, mul = fe._SQ_IMPLS[impl], fe._MUL_IMPLS[impl]
+        rng = np.random.default_rng(17)
+        cases = [_raw(c) for c in _SQ_CORNERS.values()] + [
+            _raw(rng.integers(-4, _TOP + 1, 17)) for _ in range(8)
+        ]
+        for a in cases:
+            s, m = sq(a), mul(a, a)
+            assert np.array_equal(np.asarray(s), np.asarray(m)), impl
+            assert np.array_equal(
+                np.asarray(fe.to_canonical(s)), np.asarray(fe.to_canonical(m))
+            ), impl
+
+    def test_chained_squarings(self, impl):
+        sq = fe._SQ_IMPLS[impl]
+        x = _fe1(fe.P - 2)
+        for _ in range(20):
+            x = sq(x)
+        assert _fe_int(x) == pow(fe.P - 2, 2**20, fe.P), impl
+
+    def test_invert_and_pow_p58(self, impl, monkeypatch):
+        monkeypatch.setenv("CBFT_TPU_MUL", impl)
+        a = 0xDEADBEEFCAFEBABE1234567890ABCDEF ** 2 % fe.P
+        assert a * _fe_int(fe.invert(_fe1(a))) % fe.P == 1, impl
+        assert _fe_int(fe.pow_p58(_fe1(a))) == pow(
+            a, (fe.P - 5) // 8, fe.P
+        ), impl
+
+
+def test_sq_and_mul_dispatch_on_one_name(monkeypatch):
+    """CBFT_TPU_MUL names the form of the product AND of the square; a
+    form has both or the name is refused."""
+    assert sorted(fe._SQ_IMPLS) == sorted(fe._MUL_IMPLS)
+    seen = []
+    monkeypatch.setitem(fe._SQ_IMPLS, "shift_add", lambda a: seen.append("sq") or a)
+    monkeypatch.setitem(fe._MUL_IMPLS, "shift_add", lambda a, b: seen.append("mul") or a)
+    monkeypatch.setenv("CBFT_TPU_MUL", "shift_add")
+    fe.sq(_fe1(3))
+    fe.mul(_fe1(3), _fe1(5))
+    assert seen == ["sq", "mul"]
+    monkeypatch.setenv("CBFT_TPU_MUL", "no_such_form")
+    for call in (lambda: fe.sq(_fe1(3)), lambda: fe.mul(_fe1(3), _fe1(5))):
+        with pytest.raises(ValueError, match="no_such_form"):
+            call()
+
+
+class _FieldOpCount:
+    """Counts field.sq / field.mul call sites while a kernel is traced,
+    with the loop trip counts applied: lax.fori_loop runs its body once,
+    in Python, under the loop's trip count as a multiplier. Phases as
+    benchmark/opcount.py has them: decompress() is decompress, the
+    127-step loop the ladder, invert() and what follows it encode, the
+    rest the table."""
+
+    def __init__(self, monkeypatch, refuse_mul_of_one_operand=False):
+        from jax import lax
+
+        self.counts = {}
+        self._mult = 1
+        self._phase = []
+        self._after_invert = False
+        self._refuse = refuse_mul_of_one_operand
+        real_sq, real_mul = fe.sq, fe.mul
+        real_invert, real_decompress = fe.invert, ed25519_batch.decompress
+
+        def per_lane(*operands):
+            # [17, 1] operands are constants (the four table entries
+            # that hold multiples of B alone): XLA folds them, no lane
+            # runs them, and opcount.py leaves them out
+            return any(x.shape[-1] != 1 for x in operands)
+
+        def sq(a):
+            if per_lane(a):
+                self._note("sq")
+            return real_sq(a)
+
+        def mul(a, b):
+            if self._refuse:
+                assert a is not b, "a call site squares through field.mul"
+            if per_lane(a, b):
+                self._note("mul")
+            return real_mul(a, b)
+
+        def fori_loop(lower, upper, body, init):
+            ladder = upper - lower == ed25519_batch.NUM_DIGITS
+            if ladder:
+                self._phase.append("ladder")
+            self._mult *= upper - lower
+            try:
+                return body(lower, init)
+            finally:
+                self._mult //= upper - lower
+                if ladder:
+                    self._phase.pop()
+
+        def phased(name, fn):
+            def wrapped(*args):
+                self._phase.append(name)
+                try:
+                    return fn(*args)
+                finally:
+                    self._phase.pop()
+                    self._after_invert |= name == "encode"
+            return wrapped
+
+        monkeypatch.setattr(fe, "sq", sq)
+        monkeypatch.setattr(fe, "mul", mul)
+        monkeypatch.setattr(lax, "fori_loop", fori_loop)
+        monkeypatch.setattr(fe, "invert", phased("encode", real_invert))
+        monkeypatch.setattr(
+            ed25519_batch, "decompress", phased("decompress", real_decompress)
+        )
+
+    def _note(self, op):
+        phase = self._phase[-1] if self._phase else (
+            "encode" if self._after_invert else "table"
+        )
+        key = (phase, op)
+        self.counts[key] = self.counts.get(key, 0) + self._mult
+
+    def total(self, op):
+        return sum(n for (_, o), n in self.counts.items() if o == op)
+
+    def phase(self, name):
+        return sum(n for (p, _), n in self.counts.items() if p == name)
+
+
+def _trace_ed25519_lane_program():
+    import jax
+    import jax.numpy as jnp
+
+    fe_shape = jax.ShapeDtypeStruct((fe.NUM_LIMBS, 8), jnp.int32)
+    bit = jax.ShapeDtypeStruct((8,), jnp.int32)
+    digits = jax.ShapeDtypeStruct((ed25519_batch.NUM_DIGITS, 8), jnp.int32)
+    # a fresh callable: eval_shape caches a function's trace, and the
+    # count is taken while tracing
+    jax.eval_shape(
+        lambda *args: ed25519_batch._verify_unpacked(*args),
+        fe_shape, bit, fe_shape, bit, digits, digits,
+    )
+
+
+class TestSquareEngagement:
+    """There is no path beside field.sq to count at run time: every
+    ed25519 lane runs it wherever the program squares. What can be held
+    is that no call site squares through field.mul, and how many of a
+    lane's field multiplications are squarings."""
+
+    def test_no_ed25519_call_site_squares_through_mul(self, monkeypatch):
+        counter = _FieldOpCount(monkeypatch, refuse_mul_of_one_operand=True)
+        _trace_ed25519_lane_program()
+        assert counter.total("sq") and counter.total("mul")
+
+    def test_no_sr25519_call_site_squares_through_mul(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        from cometbft_tpu.crypto.tpu import sr25519_batch
+
+        counter = _FieldOpCount(monkeypatch, refuse_mul_of_one_operand=True)
+        jax.eval_shape(
+            lambda wire: sr25519_batch._verify_core(wire),
+            jax.ShapeDtypeStruct((32, 8), jnp.uint32),
+        )
+        assert counter.total("sq") and counter.total("mul")
+
+    def test_a_lanes_squarings_and_products_are_opcounts(self, monkeypatch):
+        """1,529 squarings + 2,171 products = the 3,700 field
+        multiplications benchmark/opcount.py counts a lane, phase by
+        phase (that file counts a squaring as a full multiplication, by
+        design: it is the roofline's yardstick, read here, not edited)."""
+        from benchmark import opcount
+
+        counter = _FieldOpCount(monkeypatch)
+        _trace_ed25519_lane_program()
+        want = opcount.field_muls()
+        assert {p: counter.phase(p) for p in want} == want
+        squarings = {
+            p: counter.counts.get((p, "sq"), 0) for p in want
+        }
+        assert squarings == {
+            "decompress": 251 + 4,
+            "table": 4,
+            "ladder": opcount.LADDER_STEPS * 2 * 4,
+            "encode": 254,
+        }
+        assert counter.total("sq") == 1529
+        assert counter.total("mul") == 2171
+        assert counter.total("sq") + counter.total("mul") == sum(want.values())
+
+
 class TestWireUnpack:
     """Device-side unpack of the compact u32 wire vs independent numpy
     oracles — the wire format is the dispatch ABI, so a silent bit-slip
@@ -247,6 +490,69 @@ class TestVerifyBatchParity:
 
     def test_empty_batch(self):
         assert ed25519_batch.verify_batch([], [], []) == []
+
+
+def edge_case_batch(lanes: int = 64):
+    """(pks, msgs, sigs) of ``lanes`` lanes: valid ones around a forged
+    signature (R, S, message), a forged key, a non-canonical R (y = p + 1
+    for the identity, with its canonical twin), an R of all ones,
+    s >= L, a small-order key (y = -1, order 4) and a key off the curve.
+    Also what the builder's chip script feeds the TPU's own form."""
+    keys = [ed.gen_priv_key_from_secret(bytes([i, 35])) for i in range(lanes)]
+    msgs = [b"height %d round 0 precommit" % i for i in range(lanes)]
+    sigs = [k.sign(m) for k, m in zip(keys, msgs)]
+    pks = [k.pub_key().bytes() for k in keys]
+
+    def flip(i, byte, bit=1):
+        sig = bytearray(sigs[i])
+        sig[byte] ^= bit
+        sigs[i] = bytes(sig)
+
+    flip(3, 0)  # R
+    flip(9, 40, 0x80)  # S
+    msgs[17] = b"another message"
+    pks[21] = pks[22]  # forged key
+    identity = (1).to_bytes(32, "little")
+    pks[30], sigs[30] = identity, (fe.P + 1).to_bytes(32, "little") + bytes(32)
+    pks[31], sigs[31] = identity, identity + bytes(32)  # the canonical twin
+    sigs[36] = b"\xff" * 32 + sigs[36][32:]
+    s_int = int.from_bytes(sigs[41][32:], "little") + fe.L
+    sigs[41] = sigs[41][:32] + s_int.to_bytes(32, "little")
+    order4 = (fe.P - 1).to_bytes(32, "little")
+    pks[50] = order4
+    pks[51], sigs[51] = order4, b"\x01" * 64
+    pks[58] = b"\xff" * 32
+    return pks, msgs, sigs
+
+
+@pytest.mark.slow
+def test_whole_kernel_parity_under_the_chips_form(monkeypatch):
+    """Tier-1 compiles the kernel under ``matmul`` (the CPU platform's
+    default); this is the whole program under ``stack``, the form the
+    chip runs, against the CPU verifier. Slow: XLA's CPU backend takes
+    ~2.5 min over the unrolled form's graph (field.py, _mul_matmul)."""
+    import jax
+
+    from cometbft_tpu.crypto.tpu import aot
+
+    monkeypatch.setenv("CBFT_TPU_MUL", "stack")
+    # the registry's and the store's keys hold nothing of the program's
+    # form: both would serve the matmul executable of the same shape
+    registry = aot.default_registry()
+    aot.reset_default_registry()
+    aot.configure_exec_store("")
+    jax.clear_caches()
+    compiled = registry.compile_count
+    try:
+        pks, msgs, sigs = edge_case_batch()
+        got = _assert_parity(pks, msgs, sigs)
+        assert registry.compile_count > compiled
+        assert sum(got) == 64 - 10 or sum(got) == 64 - 11, got
+        assert not any(got[i] for i in (3, 9, 17, 21, 30, 36, 41, 50, 51, 58))
+    finally:
+        aot.reset_default_registry()
+        aot.configure_exec_store(None)
+        jax.clear_caches()
 
 
 class TestDeviceHashMode:
