@@ -11,15 +11,24 @@ loop takes the pool's contiguous window of fetched blocks and verifies
 every commit in it through ONE BatchVerifier call — pipeline-depth ×
 quorum-sigs signatures per device round-trip, which is where batch
 hardware wins (BASELINE.md config #4). Validator-set changes inside the
-window are detected via header.validators_hash and those blocks drop out
-of the batch to the exact reference per-block path.
+window are detected via header.validators_hash: the leading blocks that
+carry the state's hash are batched under the state's set.
 
 When the node's VerifyScheduler travels crypto_backend
 (crypto/scheduler.py), each window block's commit is submitted as its
 own request instead: the scheduler coalesces them (and any concurrent
 consensus/light submissions) into one dispatch, and the per-block
 futures let block i APPLY while blocks i+1.. are still verifying —
-the next commit is in flight during the current apply.
+the next commit is in flight during the current apply. On that path
+the window SURVIVES a validator-set change: whether a signature is
+valid depends on (key, sign-bytes, signature) and on nobody's power,
+so the blocks past the first change ride the same dispatch with lanes
+chosen by CommitSig.validator_address against the keys the state knows
+now (_speculate_window), and the quorum walk (order, powers, > 2/3) is
+made at apply time against the set the state then holds
+(_tally_speculated), verifying there whatever lane the speculation did
+not cover. Without a scheduler (a bare backend name, or the resident
+route) the blocks past a change wait for the next pass, as before.
 """
 
 from __future__ import annotations
@@ -54,6 +63,10 @@ TRY_SYNC_INTERVAL = 0.01  # reference: trySyncIntervalMS = 10
 STATUS_UPDATE_INTERVAL = 10.0  # reference :36
 SWITCH_TO_CONSENSUS_INTERVAL = 1.0  # reference :39
 DEFAULT_VERIFY_WINDOW = 16  # blocks batch-verified per device call
+# A block past a validator-set change is tallied under powers the blocks
+# before it may still move, so its by-address lanes go on past the quorum
+# of the newest powers known, by this share of that quorum.
+SPECULATION_MARGIN = 1 / 8
 
 
 class BlocksyncReactor(Reactor):
@@ -92,7 +105,9 @@ class BlocksyncReactor(Reactor):
         self._stages = tracelib.StageSeconds()
         self._counts = {
             "passes": 0, "blocks_refused": 0, "sync_one_calls": 0,
-            "light_lanes_submitted": 0,
+            "light_lanes_submitted": 0, "window_blocks": 0,
+            "valset_changes_in_window": 0, "speculated_lanes": 0,
+            "tally_lanes": 0, "speculation_miss_lanes": 0,
         }
         self._pool_thread: Optional[threading.Thread] = None
 
@@ -265,8 +280,15 @@ class BlocksyncReactor(Reactor):
         ``blocks_refused`` (validation failures that re-requested two
         heights), ``sync_one_calls`` (falls to the single-block path),
         ``light_lanes_submitted`` (quorum-prefix lanes handed to the
-        scheduler), and ``seconds`` by stage: this reactor's ``sync.*``
-        and its executor's ``exec.*``."""
+        scheduler), ``window_blocks`` (blocks whose commits were
+        submitted ahead of their apply), ``valset_changes_in_window``
+        (times ``validators_hash`` moved from one such block to the
+        next), ``speculated_lanes`` (by-address lanes submitted for the
+        blocks past a change), ``tally_lanes`` (lanes the apply-time
+        quorum walks of those blocks needed), ``speculation_miss_lanes``
+        (of them, verified at apply because no speculated lane carried
+        the true set's key), and ``seconds`` by stage: this reactor's
+        ``sync.*`` and its executor's ``exec.*``."""
         execs = getattr(self.block_exec, "stage_seconds", None)
         seconds = execs.snapshot() if execs is not None else {}
         seconds.update(self._stages.snapshot())
@@ -304,14 +326,32 @@ class BlocksyncReactor(Reactor):
         block_ids, part_sets, per_block, lanes_per_block = built
         needed = state.validators.total_voting_power() * 2 // 3
 
-        with self._stages.stage("sync.submit", blocks=batchable):
-            futs = self._submit_window_commits(
-                per_block, lanes_per_block, state
+        scheduler = self._window_scheduler(per_block, state)
+        if scheduler is not None:
+            # the blocks past the first validator-set change ride the
+            # same dispatch, their lanes chosen by address
+            ahead = window[batchable:-1]
+            speculated: List[Tuple[dict, list]] = []
+            if ahead:
+                with self._stages.stage("sync.build", blocks=len(ahead)):
+                    ids, parts, speculated = self._speculate_window(
+                        chain_id, state, window, batchable
+                    )
+                block_ids += ids
+                part_sets += parts
+            hashes = [val_hash] + [
+                blk.header.validators_hash for blk in window[:-1]
+            ]
+            self._counts["valset_changes_in_window"] += sum(
+                a != b for a, b in zip(hashes, hashes[1:])
             )
-        if futs is not None:
+            with self._stages.stage("sync.submit", blocks=len(window) - 1):
+                futs = self._submit_window_commits(
+                    scheduler, per_block, lanes_per_block, speculated, state
+                )
             return self._apply_window_pipelined(
-                chain_id, state, val_hash, firsts, block_ids, part_sets,
-                per_block, futs, window, needed,
+                chain_id, state, val_hash, window, block_ids, part_sets,
+                per_block, speculated, futs, needed, scheduler,
             )
 
         with self._stages.stage("sync.verdict_wait"):
@@ -397,17 +437,10 @@ class BlocksyncReactor(Reactor):
             lanes_per_block.append((lane_msgs, lane_sigs))
         return block_ids, part_sets, per_block, lanes_per_block
 
-    def _submit_window_commits(self, per_block, lanes_per_block, state):
-        """Submit every window block's quorum prefix as its OWN request
-        to the node-wide verification scheduler → one VerifyFuture per
-        block, or None when the scheduler isn't wired (bare backend
-        name/spec) or the resident full-lane path is the better route.
-
-        All requests land inside one flush deadline, so the scheduler
-        coalesces the whole window (plus whatever consensus/light have
-        pending) into one dispatch — and because each block keeps its
-        own verdict slice, a bad commit deep in the window no longer
-        throws away its verified predecessors."""
+    def _window_scheduler(self, per_block, state):
+        """The node-wide verification scheduler the window's per-block
+        requests go to, or None when it isn't wired (bare backend
+        name/spec) or the resident full-lane path is the better route."""
         scheduler = (
             self.crypto_backend
             if hasattr(self.crypto_backend, "submit")
@@ -426,28 +459,112 @@ class BlocksyncReactor(Reactor):
             for entries in per_block
         ) and all(isinstance(v.pub_key, ed.PubKeyEd25519) for v in vals):
             return None  # device-resident fixed executable wins at scale
+        return scheduler
+
+    def _speculate_window(
+        self, chain_id: str, state, window, batchable: int
+    ):
+        """The window's blocks past the first validator-set change: their
+        block ids and part sets and, a block, the lanes its quorum walk
+        is likely to need, chosen before the set that will make the walk
+        is known. → (block_ids, part_sets, speculated), ``speculated``
+        one ``(lanes, items)`` a block: ``items`` the request's
+        ``(pub_key, sign-bytes, signature)`` triples, ``lanes`` {commit
+        index: (position in ``items``, pub_key)}.
+
+        The verifying commit's signatures for the block are taken in
+        commit order; one whose ``validator_address`` the state knows (in
+        ``validators`` or ``next_validators``) gives a lane under that
+        address's key, any other (a validator that joins inside the
+        window) gives none. The walk stops once the newest powers known
+        have carried it ``SPECULATION_MARGIN`` past the quorum of
+        ``next_validators``: the true walk, under powers moved since,
+        ends near there. A commit that cannot be read gives no lanes;
+        the apply-time walk refuses it."""
+        known = {}
+        for vals in (state.validators, state.next_validators):
+            for val in vals.validators:
+                known[val.address] = val  # the newest power wins
+        quorum = state.next_validators.total_voting_power() * 2 // 3
+        reach = quorum + int(quorum * SPECULATION_MARGIN)
+        block_ids: List[BlockID] = []
+        part_sets: List[object] = []
+        out: List[Tuple[dict, list]] = []
+        for i in range(batchable, len(window) - 1):
+            with self._stages.stage("sync.part_set"):
+                parts = window[i].make_part_set(BLOCK_PART_SIZE_BYTES)
+            block_ids.append(BlockID(window[i].hash(), parts.header()))
+            part_sets.append(parts)
+            commit = window[i + 1].last_commit
+            lanes: dict = {}
+            items: list = []
+            with self._stages.stage("sync.speculate"):
+                idxs, keys, msgs, power = [], [], [], 0
+                try:
+                    for idx, csig in enumerate(commit.signatures):
+                        if not csig.for_block():
+                            continue
+                        val = known.get(csig.validator_address)
+                        if val is None:
+                            continue
+                        idxs.append(idx)
+                        keys.append(val.pub_key)
+                        power += val.voting_power
+                        if power > reach:
+                            break
+                    msgs = commit.vote_sign_bytes_many(chain_id, idxs)
+                except Exception:
+                    msgs = []
+                for pos, (idx, key, msg) in enumerate(zip(idxs, keys, msgs)):
+                    lanes[idx] = (pos, key)
+                    items.append((key, msg, cs_sig(commit, idx)))
+            out.append((lanes, items))
+        return block_ids, part_sets, out
+
+    def _submit_window_commits(
+        self, scheduler, per_block, lanes_per_block, speculated, state
+    ):
+        """Submit every window block's lanes as its OWN request to the
+        node-wide verification scheduler → one VerifyFuture per block:
+        the quorum prefix of a block under the state's set
+        (``per_block``), then the by-address lanes of the blocks past a
+        validator-set change (``speculated``).
+
+        All requests land inside one flush deadline, so the scheduler
+        coalesces the whole window (plus whatever consensus/light have
+        pending) into one dispatch — and because each block keeps its
+        own verdict slice, a bad commit deep in the window no longer
+        throws away its verified predecessors."""
+        self._counts["window_blocks"] += len(per_block) + len(speculated)
         self._counts["light_lanes_submitted"] += sum(
             len(entries) for entries in per_block
         )
+        self._counts["speculated_lanes"] += sum(
+            len(items) for _, items in speculated
+        )
+        requests = [
+            [
+                (val.pub_key, lane_msgs[idx], lane_sigs[idx])
+                for idx, val in entries
+            ]
+            for entries, (lane_msgs, lane_sigs) in zip(
+                per_block, lanes_per_block
+            )
+        ] + [items for _, items in speculated]
         return [
             scheduler.submit(
-                [
-                    (val.pub_key, lane_msgs[idx], lane_sigs[idx])
-                    for idx, val in entries
-                ],
+                items,
                 subsystem="blocksync",
                 # block i of the window commits at this height; trace
                 # tag only, never routing
                 height=state.last_block_height + 1 + i,
             )
-            for i, (entries, (lane_msgs, lane_sigs)) in enumerate(
-                zip(per_block, lanes_per_block)
-            )
+            for i, items in enumerate(requests)
         ]
 
     def _apply_window_pipelined(
-        self, chain_id, state, val_hash, firsts, block_ids, part_sets,
-        per_block, futs, window, needed,
+        self, chain_id, state, val_hash, window, block_ids, part_sets,
+        per_block, speculated, futs, needed, scheduler,
     ):
         """Apply the window with verification overlapped: every block's
         commit was already submitted (_submit_window_commits), so while
@@ -456,6 +573,12 @@ class BlocksyncReactor(Reactor):
         current block's apply. A failed verdict or quorum only costs the
         suffix: the verified prefix stays applied and the reference
         single-block path re-attributes the failure from there.
+
+        The first ``len(per_block)`` blocks were built under the state's
+        set and need every lane of their request valid; the blocks after
+        them were speculated by address, and their quorum is walked here
+        against the set the state holds once the blocks before them are
+        applied (_tally_speculated).
 
         ``futs[i].result()`` takes no timeout, and needs none: a device
         dispatch that dies does not leave its flush unanswered. Under the
@@ -470,17 +593,26 @@ class BlocksyncReactor(Reactor):
         the scheduler alike, ``validate_block``'s ``verify_commit`` and
         ``_sync_one`` among them: a timeout here would only move the
         hang to the fallback it fell to."""
-        for i, first in enumerate(firsts):
-            # a validator-set change mid-window invalidates the batch
-            # assumption from this point on — re-verify individually
-            if state.validators.hash() != val_hash:
+        batchable = len(per_block)
+        for i, fut in enumerate(futs):
+            first, commit = window[i], window[i + 1].last_commit
+            # a validator-set change the headers did not announce
+            # invalidates the batch assumption from this point on —
+            # re-verify individually
+            if i < batchable and state.validators.hash() != val_hash:
                 return state
             with self._stages.stage("sync.verdict_wait"):
-                ok_all, _ = futs[i].result()
-            if not ok_all:
-                return self._sync_one(chain_id, state)
-            tallied = sum(val.voting_power for _, val in per_block[i])
-            if tallied <= needed:
+                ok_all, mask = fut.result()
+            if i < batchable:
+                tallied = sum(val.voting_power for _, val in per_block[i])
+                accepted = ok_all and tallied > needed
+            else:
+                with self._stages.stage("sync.tally"):
+                    accepted = self._tally_speculated(
+                        chain_id, state, block_ids[i], first, commit,
+                        speculated[i - batchable][0], mask, scheduler,
+                    )
+            if not accepted:
                 return self._sync_one(chain_id, state)
             try:
                 with self._stages.stage("sync.validate"):
@@ -489,10 +621,63 @@ class BlocksyncReactor(Reactor):
                 # single-block path re-verifies and attributes the failure
                 return self._sync_one(chain_id, state)
             state = self._apply_one(
-                state, block_ids[i], first, part_sets[i],
-                window[i + 1].last_commit,
+                state, block_ids[i], first, part_sets[i], commit
             )
         return state
+
+    def _tally_speculated(
+        self, chain_id: str, state, block_id: BlockID, first: Block,
+        commit, lanes: dict, mask, scheduler,
+    ) -> bool:
+        """verify_commit_light's walk for a block whose lanes were chosen
+        before its validator set was known (_speculate_window), made
+        against ``state.validators``, the set the applied chain gives
+        this height: the commit's shape, then the signatures for the
+        block in the set's order until their power passes 2/3 of its
+        total. A lane of the walk that was speculated under the key the
+        set holds at that index takes the device's verdict; any other (a
+        seat that joined or changed hands inside the window, a
+        speculation that stopped short) is verified now, through the
+        scheduler. → whether the quorum verified; signatures the walk
+        does not reach are not looked at, as upstream does not."""
+        try:
+            self._check_commit_shape(
+                state, block_id, first.header.height, commit
+            )
+        except ValueError:
+            return False
+        vals = state.validators.validators
+        needed = state.validators.total_voting_power() * 2 // 3
+        walk, missed, tallied = [], [], 0
+        for idx, csig in enumerate(commit.signatures):
+            if not csig.for_block():
+                continue
+            walk.append(idx)
+            lane = lanes.get(idx)
+            if lane is None or lane[1] != vals[idx].pub_key:
+                missed.append(idx)
+            elif not mask[lane[0]]:
+                return False
+            tallied += vals[idx].voting_power
+            if tallied > needed:
+                break
+        self._counts["tally_lanes"] += len(walk)
+        self._counts["speculation_miss_lanes"] += len(missed)
+        if tallied <= needed:
+            return False
+        if not missed:
+            return True
+        ok_all, _ = scheduler.submit(
+            [
+                (vals[idx].pub_key, msg, cs_sig(commit, idx))
+                for idx, msg in zip(
+                    missed, commit.vote_sign_bytes_many(chain_id, missed)
+                )
+            ],
+            subsystem="blocksync",
+            height=first.header.height,
+        ).result()
+        return ok_all
 
     def _verify_window_lanes(self, per_block, lanes_per_block, state):
         """Verify every window block's quorum prefix → one flat bool per

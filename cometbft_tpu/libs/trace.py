@@ -344,6 +344,13 @@ class _BookedStage:
         return out
 
 
+def booked(book: Optional[StageSeconds], name: str, **tags: Any):
+    """``book.stage(name)`` where the caller was handed a book, the plain
+    ``stage(name)`` where it was not: code below the layer that owns the
+    book (a pure transition, a store) opens its stage either way."""
+    return book.stage(name, **tags) if book is not None else stage(name, **tags)
+
+
 class background:
     """Marks this thread's work as nothing a request waits for (audit,
     probe, canary): stages opened inside keep their flight-recorder span

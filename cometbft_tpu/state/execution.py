@@ -160,7 +160,8 @@ class BlockExecutor:
         ]
 
         new_state = update_state(
-            state, block_id, block.header, abci_responses, validator_updates
+            state, block_id, block.header, abci_responses, validator_updates,
+            stages=stages,
         )
 
         # Lock mempool, commit app state, update mempool.
@@ -175,7 +176,7 @@ class BlockExecutor:
 
         new_state.app_hash = app_hash
         with stages.stage("exec.save_state"):
-            self._store.save(new_state)
+            self._store.save(new_state, stages=stages)
         fail.fail()  # state saved
 
         self._fire_events(block, block_id, abci_responses, validator_updates)
@@ -343,14 +344,19 @@ def update_state(
     header,
     abci_responses: ABCIResponses,
     validator_updates: List[Validator],
+    stages: Optional[tracelib.StageSeconds] = None,
 ) -> State:
     """Pure state transition (reference: state/execution.go updateState
-    :403-471)."""
+    :403-471). ``stages`` books a change set applied to the next
+    validators as ``exec.valset_update``."""
     n_val_set = state.next_validators.copy()
 
     last_height_vals_changed = state.last_height_validators_changed
     if validator_updates:
-        n_val_set.update_with_change_set(validator_updates)
+        with tracelib.booked(
+            stages, "exec.valset_update", changes=len(validator_updates)
+        ):
+            n_val_set.update_with_change_set(validator_updates)
         last_height_vals_changed = header.height + 1 + 1
 
     n_val_set.increment_proposer_priority(1)

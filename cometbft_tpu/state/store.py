@@ -9,12 +9,14 @@ checkpoint heights), ABCI responses :88 (DiscardABCIResponses option).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from dataclasses import dataclass, field
 from typing import List, Optional
 
 from cometbft_tpu.abci import types as abci
 from cometbft_tpu.libs import protoio
+from cometbft_tpu.libs import trace as tracelib
 from cometbft_tpu.libs.db import DB
 from cometbft_tpu.state import State
 from cometbft_tpu.types.params import ConsensusParams
@@ -173,19 +175,28 @@ class Store:
             return None
         return State.decode(raw)
 
-    def save(self, state: State) -> None:
+    def save(self, state: State, stages=None) -> None:
         """Reference semantics (store.go:178-204): persist next validators
-        at H+2's slot, params at H+1, then the snapshot."""
+        at H+2's slot, params at H+1, then the snapshot. ``stages`` (a
+        ``libs/trace.StageSeconds``) books the write of a set that
+        changed in this block, the whole set encoded, as
+        ``exec.valset_update``."""
         with self._mtx:
             next_height = state.last_block_height + 1
             if next_height == 1:
                 next_height = state.initial_height
                 self._save_validators_info(next_height, next_height, state.validators)
-            self._save_validators_info(
-                next_height + 1,
-                state.last_height_validators_changed,
-                state.next_validators,
-            )
+            changed = state.last_height_validators_changed == next_height + 1
+            with (
+                tracelib.booked(stages, "exec.valset_update")
+                if changed
+                else contextlib.nullcontext()
+            ):
+                self._save_validators_info(
+                    next_height + 1,
+                    state.last_height_validators_changed,
+                    state.next_validators,
+                )
             self._save_params_info(
                 next_height,
                 state.last_height_consensus_params_changed,

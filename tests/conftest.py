@@ -51,6 +51,26 @@ def blocksync_apply_toy() -> tuple:
     )
 
 
+def blocksync_churn_toy() -> tuple:
+    """(toy config, toy params) of the ``blocksync_churn`` generator: 7
+    validators, 24 blocks of 3 transactions, a power change at every
+    height, a seat replaced every 4th."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "traffic",
+        "sync-churn-closed.json")
+    with open(path) as fh:
+        params = json.load(fh)["params"]
+    return (
+        {"chain_id": "toy-churn", "validators": 7, "replay_blocks": 24,
+         "txs_per_block": 3, "tx_bytes": 64,
+         "schedule": {"power_changes": 1, "power_range": [8, 12],
+                      "seat_every": 4, "joiner_power": 10}},
+        dict(params, forged=dict(params["forged"], tail_lane=6,
+                                 seat_block=7),
+             request_timeout_s=10),
+    )
+
+
 def sync_plane(backend):
     """What the ``blocksync_apply`` generator is handed, with no node
     behind it: ``backend`` stands where ``node.crypto_backend`` stands,
@@ -70,4 +90,5 @@ def _the_sync_generator_has_toy_sizes(request):
     toy = getattr(request.module, "TOY", None)
     if isinstance(toy, dict):
         toy.setdefault("blocksync_apply", blocksync_apply_toy())
+        toy.setdefault("blocksync_churn", blocksync_churn_toy())
     yield
