@@ -857,7 +857,69 @@ def bench_pack() -> dict:
     }
 
 
+def bench_challenges() -> dict:
+    """The challenge call of a launch's pack (native.ed25519_challenges,
+    PR 37) by lanes and threads: the probe that fixed
+    native._CHALLENGE_GRAIN and ed25519_batch._NATIVE_CHALLENGE_MIN on
+    the chip's host (``python3 bench_micro.py challenges`` there; the
+    chip is not touched). Medians of 11 calls, ms: ``ms_<lanes>_t<threads>``
+    for 1 to 13 threads (at most the usable cores), ``threads_<lanes>``
+    the count the grain gives, and the hashlib loop (``py_ms_<lanes>``)
+    beside the native call on one thread (``native_ms_<lanes>``) around
+    the gate."""
+    import os
+    import random
+    import statistics
+
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+    import numpy as np
+
+    from cometbft_tpu import native
+    from cometbft_tpu.crypto.tpu import ed25519_batch as eb
+
+    if native.load_challenges() is None:
+        raise RuntimeError("native challenges unavailable")
+    rng = random.Random(37)
+
+    def lanes(n):
+        pk = np.frombuffer(rng.randbytes(32 * n), np.uint8).reshape(n, 32)
+        r = np.frombuffer(rng.randbytes(32 * n), np.uint8).reshape(n, 32)
+        msgs = [rng.randbytes(100 + i % 23) for i in range(n)]
+        return pk, r, msgs, np.ones(n, bool)
+
+    def ms(fn) -> float:
+        fn()
+        runs = []
+        for _ in range(11):
+            t0 = time.perf_counter()
+            fn()
+            runs.append(time.perf_counter() - t0)
+        return round(statistics.median(runs) * 1e3, 4)
+
+    out = {"grain": native._CHALLENGE_GRAIN}
+    cores = min(13, len(os.sched_getaffinity(0)))
+    for n in (1024, 2048, 8192):
+        pk, r, msgs, valid = lanes(n)
+        out[f"threads_{n}"] = native.challenge_threads(n)
+        for t in range(1, cores + 1):
+            out[f"ms_{n}_t{t}"] = ms(
+                lambda: native.ed25519_challenges(pk, r, msgs, valid, t)
+            )
+    for n in (8, eb._NATIVE_CHALLENGE_MIN):
+        pk, r, msgs, valid = lanes(n)
+        sig = np.concatenate([r, np.zeros_like(r)], axis=1)
+        out[f"py_ms_{n}"] = ms(
+            lambda: eb._challenge_scalars_py(pk, sig, msgs, valid)
+        )
+        out[f"native_ms_{n}"] = ms(
+            lambda: native.ed25519_challenges(pk, r, msgs, valid, 1)
+        )
+    return out
+
+
 SECTIONS = {
+    "challenges": bench_challenges,
     "coldboot": bench_coldboot,
     "decisions": bench_decisions,
     "pack": bench_pack,

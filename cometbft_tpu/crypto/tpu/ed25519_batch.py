@@ -22,8 +22,9 @@ serializing) dynamic-gather on TPU. Everything is uniform across the
 batch — no data-dependent control flow, ideal for SIMD lanes.
 
 Two hashing modes (CBFT_TPU_HASH):
-  * ``host`` — h = SHA-512(R ‖ A ‖ M) mod L per signature via hashlib (C)
-    on the host while packing; the device runs only the group math.
+  * ``host`` — h = SHA-512(R ‖ A ‖ M) mod L per signature on the host
+    while packing (one native call a launch, _challenge_scalars); the
+    device runs only the group math.
   * ``device`` — the full pipeline is ONE dispatch: batched SHA-512
     (sha512.py, 64-bit lanes in 2×u32), exact mod-L reduction
     (scalar.sc_reduce — ref10 sc_reduce semantics, required for parity on
@@ -40,7 +41,7 @@ Semantics contract: accept/reject is bit-identical to the CPU backend
   * x = 0 with sign bit set yields -0 = 0 (no special rejection), as ref10;
   * non-canonical R never matches (raw-limb compare = byte compare).
 
-SHA-512(R ‖ A ‖ M) mod L runs host-side (hashlib/C): messages are short and
+SHA-512(R ‖ A ‖ M) mod L runs host-side (C): messages are short and
 variable-length, hashing is ~1% of the work; the 253-doubling scalar
 multiplication — >99% of the FLOPs — is what the TPU executes.
 """
@@ -498,31 +499,47 @@ def _parse_inputs(pub_keys, sigs):
     return pk_arr, sig_arr, valid
 
 
+# Lanes from which a pack's challenge scalars are one native call
+# (native.ed25519_challenges) rather than the hashlib + big-int loop
+# below, whatever the cores: on the v5e's host (PR 37) the loop took 8
+# lanes 29 us and 16 lanes 57, the native call on one thread 38 and 44.
+_NATIVE_CHALLENGE_MIN = 16
+
+
 def _challenge_scalars(
     pk_arr: np.ndarray, sig_arr: np.ndarray, msgs, valid: np.ndarray
 ) -> np.ndarray:
     """h = SHA-512(R ‖ A ‖ M) mod L per valid lane → u8[B,32]
-    little-endian. On multicore hosts one native C call chunks the
-    batch across threads (native/ed25519_batch.c
-    cbft_ed25519_challenges); on one core the hashlib +
-    CPython-big-int loop below is measured marginally FASTER (1.5 vs
-    1.8 µs/lane — both are C underneath, and the native wrapper pays
-    ctypes marshalling), so the native path gates on cpu_count like
-    ed25519.verify_many. The Python loop stays the parity oracle."""
-    import os as _os
+    little-endian. From _NATIVE_CHALLENGE_MIN lanes up one native C
+    call hashes the launch on native.challenge_threads(B) threads
+    (native/ed25519_batch.c cbft_ed25519_challenges); below it, or
+    where the native library is missing or stale, the hashlib loop
+    (_challenge_scalars_py). The wire ledger books each call's lanes
+    by path and its threads."""
+    from cometbft_tpu import native
+    from cometbft_tpu.crypto import wire
 
     n = len(msgs)
-    if (_os.cpu_count() or 1) > 1 and n >= 256:
-        from cometbft_tpu import native
-
-        raw = native.ed25519_challenges(
-            pk_arr.tobytes(),
-            sig_arr[:, :32].tobytes(),
-            msgs,
-            [bool(v) for v in valid],
+    h_arr = None
+    if n >= _NATIVE_CHALLENGE_MIN:
+        threads = native.challenge_threads(n)
+        h_arr = native.ed25519_challenges(
+            pk_arr, sig_arr[:, :32], msgs, valid, threads
         )
-        if raw is not None:
-            return np.frombuffer(raw, np.uint8).reshape(n, 32).copy()
+    path = "native"
+    if h_arr is None:
+        path, threads = "python", 1
+        h_arr = _challenge_scalars_py(pk_arr, sig_arr, msgs, valid)
+    ledger = wire.default_ledger()
+    if ledger is not None:
+        ledger.note_challenges(path, n, threads)
+    return h_arr
+
+
+def _challenge_scalars_py(pk_arr, sig_arr, msgs, valid) -> np.ndarray:
+    """_challenge_scalars lane by lane with hashlib and CPython's big
+    ints: the small launches' path and the parity oracle."""
+    n = len(msgs)
     h_arr = np.zeros((n, 32), np.uint8)
     sha = hashlib.sha512
     for i in range(n):
@@ -932,21 +949,39 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
     return rv
 
 
+def _parse_sigs(msgs, sigs):
+    """→ (sig_arr u8[B,64], valid) of a resident or indexed launch:
+    a lane is present where its signature is 64 bytes and its message
+    is there (None = absent: zeros, masked), and valid where s < L
+    besides. One join; the lengths are read as a set first, so the
+    placeholder loop runs only for a launch that holds an absent or
+    malformed lane."""
+    n = len(msgs)
+    valid = np.ones(n, bool)
+    try:
+        whole = set(map(len, sigs)) <= {64} and None not in msgs
+    except TypeError:  # an absent lane's None signature
+        whole = False
+    if whole:
+        sig_parts = sigs
+    else:
+        sig_parts = []
+        for i in range(n):
+            s = sigs[i]
+            if s is None or msgs[i] is None or len(s) != 64:
+                valid[i] = False
+                sig_parts.append(b"\x00" * 64)
+            else:
+                sig_parts.append(s)
+    sig_arr = np.frombuffer(b"".join(sig_parts), np.uint8).reshape(n, 64)
+    valid &= _s_below_l(sig_arr[:, 32:])
+    return sig_arr, valid
+
+
 def _prepare_rsh(pk_arr: np.ndarray, msgs, sigs):
     """Per-commit host packing for one resident chunk: msgs[i]/sigs[i]
     None = absent lane (zeros, masked). → (rsh u32[24,B], valid)."""
-    n = len(msgs)
-    valid = np.ones(n, bool)
-    sig_parts = []
-    for i in range(n):
-        s = sigs[i]
-        if s is None or msgs[i] is None or len(s) != 64:
-            valid[i] = False
-            sig_parts.append(b"\x00" * 64)
-        else:
-            sig_parts.append(bytes(s))
-    sig_arr = np.frombuffer(b"".join(sig_parts), np.uint8).reshape(n, 64)
-    valid &= _s_below_l(sig_arr[:, 32:])
+    sig_arr, valid = _parse_sigs(msgs, sigs)
     h_arr = _challenge_scalars(pk_arr, sig_arr, msgs, valid)
 
     rsh = np.concatenate(
@@ -964,18 +999,7 @@ def _prepare_rsh_compact(pk_arr: np.ndarray, msgs, sigs):
     """Compact per-flush staging for the indexed key-store path: same
     parse/hash as _prepare_rsh but packed as raw byte rows →
     (rsh u8[96,B]: rows 0:32 R, 32:64 S, 64:96 h, valid)."""
-    n = len(msgs)
-    valid = np.ones(n, bool)
-    sig_parts = []
-    for i in range(n):
-        s = sigs[i]
-        if s is None or msgs[i] is None or len(s) != 64:
-            valid[i] = False
-            sig_parts.append(b"\x00" * 64)
-        else:
-            sig_parts.append(bytes(s))
-    sig_arr = np.frombuffer(b"".join(sig_parts), np.uint8).reshape(n, 64)
-    valid &= _s_below_l(sig_arr[:, 32:])
+    sig_arr, valid = _parse_sigs(msgs, sigs)
     h_arr = _challenge_scalars(pk_arr, sig_arr, msgs, valid)
     rsh = pack_compact_rows(sig_arr[:, :32], sig_arr[:, 32:], h_arr)
     return rsh, valid

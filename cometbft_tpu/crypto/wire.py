@@ -446,6 +446,11 @@ class WireLedger:
         self._lanes: Dict[str, int] = {}
         self._padded: Dict[str, int] = {}
         self._link = dict(link) if link else None
+        # the challenge call of a launch's pack (ed25519_batch
+        # _challenge_scalars): calls, lanes by path, threads summed
+        self.challenge_calls = 0
+        self.challenge_threads = 0
+        self._challenge_lanes: Dict[str, int] = {}
 
     # --- cold-boot link seed -------------------------------------------------
 
@@ -617,6 +622,18 @@ class WireLedger:
         self.metrics.phase_seconds.with_labels(
             phase="demux", route=route
         ).observe(demux_s)
+
+    def note_challenges(self, path: str, lanes: int, threads: int) -> None:
+        """One challenge call of a pack: ``lanes`` hashed by ``path``
+        (``native``: the C call; ``python``: the hashlib loop, where a
+        launch is under the native gate or the library is missing or
+        stale) on ``threads`` threads."""
+        with self._lock:
+            self.challenge_calls += 1
+            self.challenge_threads += threads
+            self._challenge_lanes[path] = (
+                self._challenge_lanes.get(path, 0) + lanes
+            )
 
     def _series_of(self, route: str) -> Dict[str, Any]:
         """{phase: its ``phase_seconds`` series} for ``route``, made once
@@ -829,6 +846,8 @@ class WireLedger:
             recent = list(self._recent)[-8:]
             link = dict(self._link) if self._link else None
             counters = (self.chunks, self.n_dispatches, self.demux_notes)
+            challenges = (self.challenge_calls, dict(self._challenge_lanes),
+                          self.challenge_threads)
         prof_rows = []
         for (route, bucket, device), n, ewma, samples, b_ewma, l_ewma, \
                 bw, overlap in sorted(profiles, key=lambda t: t[0]):
@@ -882,6 +901,9 @@ class WireLedger:
             "recent": recent,
             "flush_notes": self.flush_notes,
             "flushes": self.flushes(),
+            "challenge_calls": challenges[0],
+            "challenge_lanes": challenges[1],
+            "challenge_threads": challenges[2],
         }
 
 

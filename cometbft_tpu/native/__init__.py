@@ -19,9 +19,16 @@ import subprocess
 import threading
 from typing import List, Optional, Sequence
 
+import numpy as np
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ed25519_batch.c")
 _SO = os.path.join(_HERE, "build", "libcbft_ed25519.so")
+
+# size_t, as the C entry points read offsets and lengths
+_SIZE_T = np.dtype(f"=u{ctypes.sizeof(ctypes.c_size_t)}")
+_U8_ARG = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+_SIZE_T_ARG = np.ctypeslib.ndpointer(_SIZE_T, flags="C_CONTIGUOUS")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -92,8 +99,8 @@ def load_ed25519() -> Optional[ctypes.CDLL]:
         lib.cbft_ed25519_verify_batch.argtypes = [
             ctypes.c_char_p,                  # pubs
             ctypes.c_char_p,                  # msgs
-            ctypes.POINTER(ctypes.c_size_t),  # msg_off
-            ctypes.POINTER(ctypes.c_size_t),  # msg_len
+            _SIZE_T_ARG,                      # msg_off
+            _SIZE_T_ARG,                      # msg_len
             ctypes.c_char_p,                  # sigs
             ctypes.POINTER(ctypes.c_ubyte),   # out
             ctypes.c_size_t,                  # n
@@ -105,20 +112,14 @@ def load_ed25519() -> Optional[ctypes.CDLL]:
 
 def _pack_msgs(msgs: Sequence[bytes]):
     """Concatenate variable-length messages into one buffer with
-    per-entry (offset, length) arrays — the shared ctypes marshalling
-    for both batch entry points."""
-    n = len(msgs)
-    offs = (ctypes.c_size_t * n)()
-    lens = (ctypes.c_size_t * n)()
-    parts = []
-    pos = 0
-    for i, m in enumerate(msgs):
-        b = bytes(m)
-        parts.append(b)
-        offs[i] = pos
-        lens[i] = len(b)
-        pos += len(b)
-    return b"".join(parts), offs, lens
+    per-entry offset and length ``size_t`` arrays — the shared
+    marshalling of both batch entry points: one join and one pass of
+    ``len``, no per-lane store. A None message raises TypeError."""
+    buf = b"".join(msgs)
+    lens = np.fromiter(map(len, msgs), _SIZE_T, len(msgs))
+    offs = np.zeros_like(lens)
+    np.cumsum(lens[:-1], out=offs[1:])
+    return buf, offs, lens
 
 
 def ed25519_verify_batch(
@@ -209,6 +210,25 @@ def ed25519_pub_from_seed(seed: bytes) -> Optional[bytes]:
     return out.raw
 
 
+# Lanes a thread of the challenge call hashes: an n-lane call runs on
+# n // _CHALLENGE_GRAIN threads, at most the cores this process may run
+# on, and on the caller's thread below two grains. Not a knob. Fixed on
+# the v5e's one-chip host (13 cores; PR 37's probe, PERF.md section 7;
+# `python3 bench_micro.py challenges` re-reads the whole call by lanes
+# and threads), where a lane costs 0.60 us on one thread and a thread
+# started costs ~0.2 ms: the C loop took 1,024 lanes 0.62 ms on
+# 1 thread / 0.55 on 3; 2,048 lanes 1.23 / 0.96 on 2 / 0.73 on 4; 8,192
+# lanes 4.91 / 1.73 on 4 / 1.52 on 5 / 2.15 on 8 / 3.63 on 13. 1,536
+# puts 8,192 lanes (a four-chip commit's first launch) on 5 threads and
+# a one-chip launch of 2,048 on 1.
+_CHALLENGE_GRAIN = 1536
+
+
+def challenge_threads(n: int) -> int:
+    """Threads an n-lane ``ed25519_challenges`` call runs on."""
+    return max(1, min(n // _CHALLENGE_GRAIN, len(os.sched_getaffinity(0))))
+
+
 def load_challenges():
     """ctypes binding for cbft_ed25519_challenges (same .so); None on
     any load failure."""
@@ -221,56 +241,62 @@ def load_challenges():
     if not getattr(fn, "_cbft_typed", False):
         fn.restype = ctypes.c_int
         fn.argtypes = [
-            ctypes.c_char_p,                  # pubs (A)
-            ctypes.c_char_p,                  # rs (R)
-            ctypes.c_char_p,                  # msgs
-            ctypes.POINTER(ctypes.c_size_t),  # msg_off
-            ctypes.POINTER(ctypes.c_size_t),  # msg_len
-            ctypes.POINTER(ctypes.c_ubyte),   # valid
-            ctypes.c_char_p,                  # out (n*32 LE)
-            ctypes.c_size_t,                  # n
-            ctypes.c_int,                     # nthreads
+            _U8_ARG,          # pubs (A), n*32
+            _U8_ARG,          # rs (R), n*32
+            ctypes.c_char_p,  # msgs
+            _SIZE_T_ARG,      # msg_off
+            _SIZE_T_ARG,      # msg_len
+            _U8_ARG,          # valid, n
+            _U8_ARG,          # out, n*32 LE
+            ctypes.c_size_t,  # n
+            ctypes.c_int,     # nthreads
         ]
         fn._cbft_typed = True
     return fn
 
 
 def ed25519_challenges(
-    pubs: bytes,
-    rs: bytes,
+    pubs: np.ndarray,
+    rs: np.ndarray,
     msgs: Sequence[Optional[bytes]],
-    valid: Sequence[bool],
+    valid: np.ndarray,
     nthreads: Optional[int] = None,
-) -> Optional[bytes]:
+) -> Optional[np.ndarray]:
     """h = SHA-512(R ‖ A ‖ M) mod L per valid lane, one native call.
 
-    pubs/rs are the concatenated n*32-byte A and R rows; lanes with
-    valid[i] False are skipped (zeros in the output). A valid lane with
-    msgs[i] None is a caller bug and returns None (the Python oracle
-    would raise — silent empty-message hashing would be a parity
-    break). Returns the n*32 little-endian output buffer, or None when
-    the native path is unavailable (callers fall back to the Python
-    loop)."""
+    pubs / rs are the u8[n,32] rows of A and R; ``valid`` is a bool or
+    uint8 array, passed as its buffer: lanes with valid[i] False are
+    skipped (zeros in the output) and their message may be None. A
+    valid lane with msgs[i] None is a caller bug and returns None (the
+    Python oracle would raise — silent empty-message hashing would be a
+    parity break). ``nthreads`` None: challenge_threads(n). Returns the
+    u8[n,32] little-endian scalars, or None when the native path is
+    unavailable (callers fall back to the Python loop)."""
     fn = load_challenges()
     if fn is None:
         return None
-    n = len(valid)
-    if n == 0:
-        return b""
-    if len(pubs) != 32 * n or len(rs) != 32 * n:
+    n = len(msgs)
+    pk = np.ascontiguousarray(pubs, np.uint8)
+    r = np.ascontiguousarray(rs, np.uint8)
+    ok = np.ascontiguousarray(valid)
+    if ok.dtype != np.uint8:
+        ok = ok.astype(bool, copy=False).view(np.uint8)
+    if pk.shape != (n, 32) or r.shape != (n, 32) or ok.shape != (n,):
         return None  # shape mismatch must not reach the C reader
-    if any(valid[i] and msgs[i] is None for i in range(n)):
-        return None
-    vbuf = (ctypes.c_ubyte * n)()
-    for i in range(n):
-        vbuf[i] = 1 if valid[i] else 0
-    msg_buf, offs, lens = _pack_msgs(
-        [msgs[i] if valid[i] else b"" for i in range(n)]
-    )
-    out = ctypes.create_string_buffer(32 * n)
+    out = np.zeros((n, 32), np.uint8)
+    if n == 0:
+        return out
+    try:
+        buf, offs, lens = _pack_msgs(msgs)
+    except TypeError:  # a None message: fine only on a skipped lane
+        absent = np.fromiter((m is None for m in msgs), bool, n)
+        if (absent & (ok != 0)).any():
+            return None
+        buf, offs, lens = _pack_msgs(
+            [b"" if m is None else m for m in msgs]
+        )
     if nthreads is None:
-        nthreads = min(os.cpu_count() or 1, 16)
-    rc = fn(pubs, rs, msg_buf, offs, lens, vbuf, out, n, nthreads)
-    if rc != 0:
+        nthreads = challenge_threads(n)
+    if fn(pk, r, buf, offs, lens, ok, out, n, nthreads) != 0:
         return None
-    return out.raw
+    return out

@@ -172,34 +172,107 @@ int cbft_ed25519_pub_from_seed(const unsigned char *seed,
 /* --- batch challenge scalars: h = SHA-512(R ‖ A ‖ M) mod L ------------
  *
  * Host-side packing cost of the TPU batch/resident verify paths
- * (crypto/tpu/ed25519_batch.py _challenge_scalars): the pure-Python
- * loop pays ~6 us/sig (hashlib call + 512-bit int mod); this native
- * loop is one call per batch with the same pthread chunking as the
- * verifier above. Output is 32 little-endian bytes per lane; lanes
- * with valid[i] == 0 are skipped (left zeroed). */
+ * (crypto/tpu/ed25519_batch.py _challenge_scalars): one call per launch,
+ * chunked over the threads the caller asks for (native/__init__.py
+ * challenge_threads). A lane is four plain SHA512_* calls on a context
+ * on the thread's stack (no EVP dispatch, no provider fetch, no lock:
+ * OpenSSL 3's EVP_DigestInit_ex(EVP_sha512()) fetched the digest anew
+ * every lane) and the fixed 512 -> 253-bit reduction below (no BIGNUM).
+ * Output is 32 little-endian bytes per lane; lanes with valid[i] == 0
+ * are skipped (left as the caller's zeros). */
 
-typedef struct bignum_st BIGNUM;
-typedef struct bignum_ctx BN_CTX;
-BIGNUM *BN_lebin2bn(const unsigned char *s, size_t len, BIGNUM *ret);
-int BN_bn2lebinpad(const BIGNUM *a, unsigned char *to, size_t tolen);
-int BN_div(BIGNUM *dv, BIGNUM *rem, const BIGNUM *m, const BIGNUM *d,
-           BN_CTX *ctx);
-BIGNUM *BN_new(void);
-void BN_free(BIGNUM *a);
-BN_CTX *BN_CTX_new(void);
-void BN_CTX_free(BN_CTX *c);
-const EVP_MD *EVP_sha512(void);
-int EVP_DigestInit_ex(EVP_MD_CTX *ctx, const EVP_MD *type, ENGINE *impl);
-int EVP_DigestUpdate(EVP_MD_CTX *ctx, const void *d, size_t cnt);
-int EVP_DigestFinal_ex(EVP_MD_CTX *ctx, unsigned char *md, unsigned int *s);
+/* SHA512_CTX is 216 bytes on every libcrypto since 0.9.8 (8 + 2 + 16
+ * u64 words, two ints); declared with room to spare, as the image has
+ * no headers. */
+typedef union {
+    unsigned long long words[32];
+} cbft_sha512_ctx;
+int SHA512_Init(cbft_sha512_ctx *c);
+int SHA512_Update(cbft_sha512_ctx *c, const void *data, size_t len);
+int SHA512_Final(unsigned char *md, cbft_sha512_ctx *c);
 
-/* L = 2^252 + 27742317777372353535851937790883648493, little-endian */
-static const unsigned char CBFT_L_LE[32] = {
-    0xed, 0xd3, 0xf5, 0x5c, 0x1a, 0x63, 0x12, 0x58,
-    0xd6, 0x9c, 0xf7, 0xa2, 0xde, 0xf9, 0xde, 0x14,
-    0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x00,
-    0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x10,
+static long long load21(const unsigned char *in, unsigned bit)
+{
+    const unsigned char *p = in + (bit >> 3);
+    unsigned long long w = (unsigned long long)p[0] |
+                           ((unsigned long long)p[1] << 8) |
+                           ((unsigned long long)p[2] << 16) |
+                           ((unsigned long long)p[3] << 24);
+    return (long long)(w >> (bit & 7));
+}
+
+/* 2^252 = L - c == -c (mod L) in signed radix-2^21 digits: ref10's
+ * sc_reduce constants. */
+static const long long CBFT_MINUS_C[6] = {
+    666643, 470296, 654183, -997805, 136657, -683901,
 };
+
+/* s[hi] * 2^(21 hi) folded into s[hi - 12 .. hi - 7], for hi from
+ * ``top`` down to ``bottom``. */
+static void fold(long long *s, int top, int bottom)
+{
+    for (int hi = top; hi >= bottom; hi--) {
+        for (int j = 0; j < 6; j++)
+            s[hi - 12 + j] += s[hi] * CBFT_MINUS_C[j];
+        s[hi] = 0;
+    }
+}
+
+/* One carry from s[i] into s[i + 1]: ``round`` to the nearest (the
+ * limb lands in [-2^20, 2^20)), else the floor (in [0, 2^21)). */
+static void carry(long long *s, int i, int round)
+{
+    long long c = (s[i] + (round ? 1LL << 20 : 0)) >> 21;
+    s[i + 1] += c;
+    s[i] -= c * (1LL << 21);
+}
+
+/* out = in mod L, in: 64 little-endian bytes (a SHA-512 digest), out:
+ * 32. ref10's sc_reduce in loops: 24 limbs of 21 bits (the top one 29),
+ * folded down twice with carries between, then twice more by the one
+ * limb the carries push past 2^252. */
+static void sc_reduce64(unsigned char out[32], const unsigned char in[64])
+{
+    long long s[24];
+    for (int i = 0; i < 23; i++)
+        s[i] = load21(in, 21 * i) & 2097151;
+    s[23] = load21(in, 483);
+
+    fold(s, 23, 18);
+    for (int i = 6; i <= 16; i += 2)
+        carry(s, i, 1);
+    for (int i = 7; i <= 15; i += 2)
+        carry(s, i, 1);
+    fold(s, 17, 12);
+    for (int i = 0; i <= 10; i += 2)
+        carry(s, i, 1);
+    for (int i = 1; i <= 11; i += 2)
+        carry(s, i, 1);
+    fold(s, 12, 12);
+    for (int i = 0; i <= 11; i++)
+        carry(s, i, 0);
+    fold(s, 12, 12);
+    for (int i = 0; i <= 10; i++)
+        carry(s, i, 0);
+
+    unsigned long long acc = 0;
+    int bits = 0, pos = 0;
+    for (int i = 0; i < 12; i++) {
+        acc |= (unsigned long long)s[i] << bits;
+        for (bits += 21; bits >= 8; bits -= 8) {
+            out[pos++] = (unsigned char)acc;
+            acc >>= 8;
+        }
+    }
+    out[pos] = (unsigned char)acc;
+}
+
+/* Test entry: the reduction alone, n digests of 64 bytes -> n * 32. */
+void cbft_sc_reduce64(const unsigned char *in, unsigned char *out, size_t n)
+{
+    for (size_t i = 0; i < n; i++)
+        sc_reduce64(out + 32 * i, in + 64 * i);
+}
 
 typedef struct {
     const unsigned char *pubs;   /* n * 32 (A) */
@@ -216,45 +289,28 @@ typedef struct {
 static void *challenge_chunk(void *arg)
 {
     hchunk_t *c = (hchunk_t *)arg;
-    EVP_MD_CTX *ctx = EVP_MD_CTX_new();
-    BIGNUM *L = BN_lebin2bn(CBFT_L_LE, 32, NULL);
-    BIGNUM *h = BN_new();
-    BIGNUM *rem = BN_new();
-    BN_CTX *bctx = BN_CTX_new();
-    if (ctx == NULL || L == NULL || h == NULL || rem == NULL ||
-        bctx == NULL) {
-        c->rc = 1;
-        goto done;
-    }
+    cbft_sha512_ctx ctx;
+    unsigned char digest[64];
     for (size_t i = c->begin; i < c->end; i++) {
-        unsigned char digest[64];
-        unsigned int dlen = 0;
         if (!c->valid[i])
             continue;
-        if (EVP_DigestInit_ex(ctx, EVP_sha512(), NULL) != 1 ||
-            EVP_DigestUpdate(ctx, c->rs + 32 * i, 32) != 1 ||
-            EVP_DigestUpdate(ctx, c->pubs + 32 * i, 32) != 1 ||
-            EVP_DigestUpdate(ctx, c->msgs + c->msg_off[i],
-                             c->msg_len[i]) != 1 ||
-            EVP_DigestFinal_ex(ctx, digest, &dlen) != 1 || dlen != 64 ||
-            BN_lebin2bn(digest, 64, h) == NULL ||
-            BN_div(NULL, rem, h, L, bctx) != 1 ||
-            BN_bn2lebinpad(rem, c->out + 32 * i, 32) != 32) {
+        if (SHA512_Init(&ctx) != 1 ||
+            SHA512_Update(&ctx, c->rs + 32 * i, 32) != 1 ||
+            SHA512_Update(&ctx, c->pubs + 32 * i, 32) != 1 ||
+            SHA512_Update(&ctx, c->msgs + c->msg_off[i],
+                          c->msg_len[i]) != 1 ||
+            SHA512_Final(digest, &ctx) != 1) {
             c->rc = 1;
-            goto done;
+            return NULL;
         }
+        sc_reduce64(c->out + 32 * i, digest);
     }
-done:
-    if (ctx) EVP_MD_CTX_free(ctx);
-    if (L) BN_free(L);
-    if (h) BN_free(h);
-    if (rem) BN_free(rem);
-    if (bctx) BN_CTX_free(bctx);
     return NULL;
 }
 
 /* Returns 0 on success (any lane failure poisons the call — callers
- * fall back to the Python path rather than trust partial output). */
+ * fall back to the Python path rather than trust partial output).
+ * nthreads <= 1 runs on the caller's thread. */
 int cbft_ed25519_challenges(const unsigned char *pubs,
                             const unsigned char *rs,
                             const unsigned char *msgs,

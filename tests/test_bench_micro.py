@@ -13,7 +13,7 @@ import pytest
 @pytest.mark.parametrize(
     "section",
     [
-        "coldboot", "ed25519", "validator_set", "light", "mempool",
+        "challenges", "coldboot", "ed25519", "validator_set", "light", "mempool",
         "routing", "scheduler", "telemetry", "wal",
     ],
 )

@@ -220,10 +220,28 @@ class TestBatchVerifier:
 
 
 
-    def test_native_challenges_parity(self):
+    # (n, seed, longest message, share of absent lanes): the first is
+    # the original case; the others cross the old 256-lane gate, a
+    # thread's grain and an odd count; the message lengths include
+    # 64 + len = 111, 112, 127, 128, 239, 240 (SHA-512's padding edges)
+    @pytest.mark.parametrize(
+        "n,seed,max_len,absent",
+        [
+            (120, 5, 150, 0.15),
+            (1, 11, 300, 0.0),
+            (255, 12, 300, 0.1),
+            (256, 13, 300, 0.1),
+            (2048, 14, 300, 0.1),
+            (8193, 15, 300, 0.05),
+            (64, 16, 300, 1.0),
+        ],
+    )
+    def test_native_challenges_parity(self, n, seed, max_len, absent):
         """cbft_ed25519_challenges vs the hashlib + big-int oracle,
-        including skipped (absent) lanes and empty messages."""
+        including skipped (absent) lanes, all lanes absent and empty
+        messages, on every thread count from 1 to the usable cores."""
         import hashlib
+        import os
         import random
 
         import numpy as np
@@ -231,39 +249,86 @@ class TestBatchVerifier:
         from cometbft_tpu import native
 
         L = 2**252 + 27742317777372353535851937790883648493
-        rng = random.Random(5)
-        n = 120
-        pk = np.frombuffer(
-            bytes(rng.randrange(256) for _ in range(n * 32)), np.uint8
-        ).reshape(n, 32)
-        r = np.frombuffer(
-            bytes(rng.randrange(256) for _ in range(n * 32)), np.uint8
-        ).reshape(n, 32)
-        valid = [rng.random() > 0.15 for _ in range(n)]
+        rng = random.Random(seed)
+        edges = [47, 48, 63, 64, 175, 176, 0]
+        pk = np.frombuffer(rng.randbytes(n * 32), np.uint8).reshape(n, 32)
+        r = np.frombuffer(rng.randbytes(n * 32), np.uint8).reshape(n, 32)
+        valid = np.array([rng.random() >= absent for _ in range(n)])
         msgs = [
-            bytes(rng.randrange(256) for _ in range(rng.randrange(0, 150)))
+            rng.randbytes(
+                edges[i] if i < len(edges) else rng.randrange(0, max_len + 1)
+            )
             if v
             else None
-            for v in valid
+            for i, v in enumerate(valid)
         ]
-        raw = native.ed25519_challenges(pk.tobytes(), r.tobytes(), msgs, valid)
-        if raw is None:
-            pytest.skip("native challenges unavailable")
-        got = np.frombuffer(raw, np.uint8).reshape(n, 32)
+        want = np.zeros((n, 32), np.uint8)
         for i in range(n):
-            if not valid[i]:
-                assert not got[i].any()
-                continue
-            h = (
-                int.from_bytes(
+            if valid[i]:
+                h = int.from_bytes(
                     hashlib.sha512(
                         r[i].tobytes() + pk[i].tobytes() + msgs[i]
                     ).digest(),
                     "little",
-                )
-                % L
-            )
-            assert got[i].tobytes() == h.to_bytes(32, "little"), i
+                ) % L
+                want[i] = np.frombuffer(h.to_bytes(32, "little"), np.uint8)
+        for threads in range(1, len(os.sched_getaffinity(0)) + 1):
+            got = native.ed25519_challenges(pk, r, msgs, valid, threads)
+            if got is None:
+                pytest.skip("native challenges unavailable")
+            assert got.tobytes() == want.tobytes(), threads
+
+    def test_native_challenges_refuse_a_valid_lane_without_message(self):
+        import numpy as np
+
+        from cometbft_tpu import native
+
+        if native.load_challenges() is None:
+            pytest.skip("native challenges unavailable")
+        rows = np.zeros((3, 32), np.uint8)
+        msgs = [b"a", None, b"c"]
+        assert native.ed25519_challenges(
+            rows, rows, msgs, np.array([1, 1, 1], np.uint8)
+        ) is None
+        got = native.ed25519_challenges(
+            rows, rows, msgs, np.array([True, False, True])
+        )
+        assert got is not None and not got[1].any()
+
+    @pytest.mark.parametrize(
+        "digest",
+        ["0", "L-1", "L", "L+1", "2L", "2^512-1", "2^252", "random"],
+    )
+    def test_native_sc_reduce64(self, digest):
+        """The C reduction alone (test-only entry cbft_sc_reduce64)
+        against Python's big-int mod L."""
+        import ctypes
+        import random
+
+        from cometbft_tpu import native
+
+        L = 2**252 + 27742317777372353535851937790883648493
+        lib = native.load_ed25519()
+        fn = getattr(lib, "cbft_sc_reduce64", None) if lib else None
+        if fn is None:
+            pytest.skip("native library unavailable")
+        fn.restype = None
+        fn.argtypes = [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t]
+        named = {
+            "0": 0, "L-1": L - 1, "L": L, "L+1": L + 1, "2L": 2 * L,
+            "2^512-1": 2**512 - 1, "2^252": 2**252,
+        }
+        if digest == "random":
+            rng = random.Random(37)
+            vals = [rng.getrandbits(512) for _ in range(20000)] + [
+                k * L + d for k in range(1, 64) for d in (-1, 0, 1)
+            ]
+        else:
+            vals = [named[digest]]
+        out = ctypes.create_string_buffer(32 * len(vals))
+        fn(b"".join(v.to_bytes(64, "little") for v in vals), out, len(vals))
+        want = b"".join((v % L).to_bytes(32, "little") for v in vals)
+        assert out.raw == want
 
     def test_floor_says_where_lanes_ran(self):
         """The tpu backend reports where a verify()'s lanes actually ran,
