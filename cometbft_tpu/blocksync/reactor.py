@@ -451,13 +451,12 @@ class BlocksyncReactor(Reactor):
             return None
         from cometbft_tpu.crypto import ed25519 as ed
 
-        vals = state.validators.validators
         if all(
             cryptobatch.resident_commit_eligible(
                 len(entries), self.crypto_backend
             )
             for entries in per_block
-        ) and all(isinstance(v.pub_key, ed.PubKeyEd25519) for v in vals):
+        ) and state.validators.curve_index().single == ed.KEY_TYPE:
             return None  # device-resident fixed executable wins at scale
         return scheduler
 
@@ -694,20 +693,20 @@ class BlocksyncReactor(Reactor):
         the whole window. Accept/reject is identical either way."""
         from cometbft_tpu.crypto import ed25519 as ed
 
-        vals = state.validators.validators
-        if all(
+        eligible = all(
             cryptobatch.resident_commit_eligible(
                 len(entries), self.crypto_backend
             )
             for entries in per_block
-        ) and all(isinstance(v.pub_key, ed.PubKeyEd25519) for v in vals):
-            pub_keys = [v.pub_key.bytes() for v in vals]
+        )
+        curves = state.validators.curve_index() if eligible else None
+        if curves is not None and curves.single == ed.KEY_TYPE:
             flat: List[bool] = []
             for entries, (lane_msgs, lane_sigs) in zip(
                 per_block, lanes_per_block
             ):
                 full = cryptobatch.verify_commit_valset(
-                    pub_keys, lane_msgs, lane_sigs, self.crypto_backend
+                    curves, lane_msgs, lane_sigs, self.crypto_backend
                 )
                 if full is None:
                     break  # shape rejected after all — take the bv path

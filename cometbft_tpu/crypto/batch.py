@@ -13,6 +13,7 @@ reject per signature bit-identical to the serial VerifySignature calls.
 
 from __future__ import annotations
 
+import hashlib
 import os
 import sys
 import threading
@@ -198,8 +199,13 @@ def curve_floors(
     """Per-curve CPU↔device routing floors, scaled to the speed of each
     curve's CPU fallback: ed25519 through ed25519_routing_floor (1024 by
     default — the round-5 on-chip crossover, SMALLBATCH_onchip.jsonl),
-    secp256k1 256 (OpenSSL ECDSA, ~3.7k sigs/s on the host), sr25519 4
-    (pure-Python fallback, ~ms/sig — the device wins almost at once).
+    secp256k1 256 (CBFT_TPU_SECP_MIN_BATCH; set at 128 from the ed25519
+    crossover scaled by the two CPU rates, OpenSSL ECDSA ~3.7k sigs/s on
+    the host, BENCH_NOTES.md "Per-curve routing floors", then doubled
+    with ed25519's 512 -> 1024 for the ~70 ms dispatch floor of round
+    5's slow sessions, commit 75c491a; no chip run has measured a
+    secp256k1 crossover), sr25519 4 (pure-Python fallback, ~ms/sig — the
+    device wins almost at once).
     THE one table: TPUBatchVerifier partitions by it and the scheduler's
     router reads it (clears_device_floor), so a flush the floor keeps on the
     host is routed and counted as ``cpu``, not as a device dispatch."""
@@ -373,7 +379,10 @@ class TPUBatchVerifier(BatchVerifier):
 def _verify_on_host(curve: str, keys, msgs, sigs) -> List[bool]:
     """A partition its floor keeps off the device (or of no batch
     curve): ed25519 through the host pool's one native call, any other
-    key serially."""
+    key serially. Booked in the wire ledger's book by curve."""
+    ledger = wirelib.default_ledger()
+    if ledger is not None:
+        ledger.note_host_lanes(curve, len(keys))
     if curve == ed.KEY_TYPE:
         return list(map(bool, ed.verify_many(list(zip(keys, msgs, sigs)))))
     return [
@@ -409,8 +418,8 @@ def resident_commit_eligible(
     n_present: int, backend: Backend = None
 ) -> bool:
     """Cheap pre-check for the resident commit path, so callers on the
-    cpu backend (or below the floor) never pay the O(n_validators)
-    key-type scan and pk-bytes build that verify_commit_valset needs."""
+    cpu backend (or below the floor) never pay for the set's curve index
+    (CurveIndex) that verify_commit_valset needs."""
     if backend_name(backend) != "tpu":
         return False
     spec = unwrap_backend(backend)
@@ -418,42 +427,149 @@ def resident_commit_eligible(
     return n_present >= ed25519_routing_floor(spec_floor)
 
 
+class CurvePart:
+    """The seats of one key type in a validator set: ``seats`` (set
+    positions, ascending; None: every seat), their keys and key bytes in
+    that order, and ``valset_id``, the sha256 of the key bytes joined:
+    the id the resident rows of those keys are stored under."""
+
+    __slots__ = ("curve", "seats", "keys", "key_bytes", "valset_id")
+
+    def __init__(self, curve: str, seats: Optional[List[int]], keys):
+        self.curve = curve
+        self.seats = seats
+        self.keys = keys
+        self.key_bytes = [k.bytes() for k in keys]
+        self.valset_id = hashlib.sha256(b"".join(self.key_bytes)).digest()
+
+
+class CurveIndex:
+    """A validator set's seats by key type, made once per set
+    (ValidatorSet.curve_index) rather than scanned every commit. ``of``
+    is the validator list it was made from; ``single`` the one key type
+    of a one-curve set (its one part has ``seats`` None), else None."""
+
+    __slots__ = ("of", "parts", "single")
+
+    def __init__(self, validators: list):
+        self.of = validators
+        by_curve: Dict[str, List[int]] = {}
+        for i, v in enumerate(validators):
+            by_curve.setdefault(v.pub_key.type(), []).append(i)
+        self.single = next(iter(by_curve)) if len(by_curve) == 1 else None
+        self.parts = [
+            CurvePart(
+                curve, None if self.single else seats,
+                [validators[i].pub_key for i in seats],
+            )
+            for curve, seats in by_curve.items()
+        ]
+
+
+# the key types a commit's stream carries on the device
+STREAM_CURVES = ("ed25519", "secp256k1")
+
+
 def verify_commit_valset(
-    pub_keys: List[bytes],
+    curves: CurveIndex,
     msgs,
     sigs: List[Optional[bytes]],
     backend: Backend = None,
 ) -> Optional[List[bool]]:
-    """Device-resident full-lane commit verification (the valset's
-    pubkey rows live on device across heights — ed25519_batch's
-    verify_valset_resident). Returns a per-lane mask, or None when the
-    shape is ineligible and the caller should fall back to the
-    add()/verify() protocol. ``msgs`` is one entry per validator, or a
-    callable ``(start, end) -> msgs[start:end]`` that builds a launch's
-    messages when that launch is next (then ``sigs`` says which lanes
-    are present).
+    """Full-lane commit verification as ONE stream of launches: the
+    ed25519 seats against their device-resident key rows
+    (ed25519_batch's verify_valset_resident / resident_part), the
+    secp256k1 seats on their kernel (secp256k1_batch.stream_part), a
+    launch of each in turn (mesh.launch_parts). Returns a per-seat mask,
+    or None when the shape is ineligible and the caller should fall back
+    to the add()/verify() protocol. ``curves`` is the set's CurveIndex;
+    ``msgs`` is one entry per seat, or a callable ``(start, end) ->
+    msgs[start:end]`` with ``at(seats)`` for a curve's seats that builds
+    a launch's messages when that launch is next (then ``sigs`` says
+    which lanes are present).
 
-    Eligibility: the tpu backend is selected and the PRESENT lane
-    count clears the ed25519 routing floor (below
-    it the CPU wins the round trip regardless — crypto/batch.py
-    min_batch rationale). Callers guarantee every pub_key is an ed25519
-    key (32 bytes); msgs[i]/sigs[i] None marks an absent lane, reported
-    False and skipped by the caller."""
+    Eligibility: the tpu backend is selected, the PRESENT lane count
+    clears the ed25519 routing floor (below it the CPU wins the round
+    trip regardless — crypto/batch.py min_batch rationale), and every
+    key type is one the stream carries. A one-curve ed25519 set takes
+    verify_valset_resident whole; in a mixed set a curve whose present
+    lanes are under its own floor (curve_floors) is verified on the host
+    pool. msgs[i]/sigs[i] None marks an absent lane, reported False and
+    skipped by the caller."""
     if backend_name(backend) != "tpu":
         return None
-    import hashlib
-
     with tracelib.stage("commit.valset_id"):
         lanes = sigs if callable(msgs) else msgs
         present = sum(1 for m in lanes if m is not None)
         spec = unwrap_backend(backend)
         spec_floor = spec.min_batch if isinstance(spec, BackendSpec) else None
-        if present < ed25519_routing_floor(spec_floor):
+        ed_floor = ed25519_routing_floor(spec_floor)
+        if present < ed_floor:
             return None
-        valset_id = hashlib.sha256(b"".join(pub_keys)).digest()
     from cometbft_tpu.crypto.tpu import ed25519_batch
 
-    return ed25519_batch.verify_valset_resident(valset_id, pub_keys, msgs, sigs)
+    if curves.single == ed.KEY_TYPE:
+        part = curves.parts[0]
+        return ed25519_batch.verify_valset_resident(
+            part.valset_id, part.key_bytes, msgs, sigs
+        )
+    if any(p.curve not in STREAM_CURVES for p in curves.parts):
+        return None
+    return _verify_commit_mixed(curves, msgs, sigs, curve_floors(ed_floor))
+
+
+def _verify_commit_mixed(curves: CurveIndex, msgs, sigs, floors):
+    """verify_commit_valset of a set whose seats hold more than one key
+    type: each curve's seats are a part of one stream, or, under their
+    curve's floor, a host-pool partition. → a per-seat mask."""
+    from cometbft_tpu.crypto.tpu import ed25519_batch, mesh, secp256k1_batch
+
+    seat_msgs = msgs.at if callable(msgs) else (
+        lambda seats: [msgs[i] for i in seats]
+    )
+    full = [False] * len(sigs)
+    on_device, on_host, plan = [], [], None
+    for cp in curves.parts:
+        part_sigs = [sigs[i] for i in cp.seats]
+        present = sum(1 for s in part_sigs if s is not None)
+        if present < floors[cp.curve]:
+            on_host.append((cp, part_sigs))
+            continue
+
+        def part_msgs(start, end, seats=cp.seats):
+            return seat_msgs(seats[start:end])
+
+        if cp.curve == ed.KEY_TYPE:
+            part, plan = ed25519_batch.resident_part(
+                cp.valset_id, cp.key_bytes, part_msgs, part_sigs
+            )
+            on_device.insert(0, (cp, part))  # the cheaper build leads
+        else:
+            on_device.append((cp, (cp.key_bytes, part_msgs, part_sigs)))
+    if plan is None and on_device:
+        plan = mesh.shard_plan()
+    nsh = plan.n_shards if plan is not None else 1
+    parts = [
+        p if isinstance(p, mesh.StreamPart)
+        else secp256k1_batch.stream_part(*p, n_shards=nsh)
+        for _, p in on_device
+    ]
+    masks, _ = ed25519_batch.run_commit_parts(parts, plan) if parts else (
+        [], None
+    )
+    for (cp, _), mask in zip(on_device, masks):
+        for seat, ok in zip(cp.seats, mask):
+            full[seat] = bool(ok)
+    for cp, part_sigs in on_host:
+        lanes = [k for k, s in enumerate(part_sigs) if s is not None]
+        seats = [cp.seats[k] for k in lanes]
+        got = _verify_on_host(
+            cp.curve, [cp.keys[k] for k in lanes], seat_msgs(seats),
+            [part_sigs[k] for k in lanes],
+        )
+        for seat, ok in zip(seats, got):
+            full[seat] = ok
+    return full
 
 
 # ---------------------------------------------------------------------------

@@ -179,11 +179,31 @@ def stable_kernel_name(kernel) -> str:
         return name
 
 
-def registered_kernels() -> List[_KernelReg]:
+# the key types a validator set may hold where the node's consensus
+# params say nothing else (types/params.py ValidatorParams' default)
+DEFAULT_KEY_TYPES = ("ed25519",)
+# the key types a kernel's name may start with (``secp256k1.verify``)
+CURVES = ("ed25519", "secp256k1", "sr25519")
+
+
+def registered_kernels(
+    key_types: Sequence[str] = DEFAULT_KEY_TYPES,
+) -> List[_KernelReg]:
     """Warmup-eligible registrations: those with a shape template that
-    the routing in force can reach."""
+    the routing in force can reach, but a curve's kernel (its name
+    starts with the key type) whose key type is not among ``key_types``:
+    no validator key of the node's chain can reach it, and it is not
+    worth a compile per bucket."""
+
+    def admitted(name: str) -> bool:
+        curve = name.split(".", 1)[0]
+        return curve not in CURVES or curve in key_types
+
     with _name_mtx:
-        regs = [r for r in _registered.values() if r.bucket_shapes]
+        regs = [
+            r for r in _registered.values()
+            if r.bucket_shapes and admitted(r.name)
+        ]
     return [r for r in regs if r.reachable is None or r.reachable()]
 
 
@@ -1043,6 +1063,7 @@ def warmup_plan(
     floor: Optional[int] = None,
     sizes: Optional[Sequence[int]] = None,
     include_single: Optional[bool] = None,
+    key_types: Sequence[str] = DEFAULT_KEY_TYPES,
 ) -> List[WarmTarget]:
     """Every executable the current topology's ROUTED dispatches need,
     in priority order: the canary's bucket of the ``forced`` kernels,
@@ -1053,9 +1074,12 @@ def warmup_plan(
     mesh that degrades to one visible device still boots warm). The
     ladder's single-device buckets above a kernel's ``launch`` are left
     out: no flush reaches them, its entry streams at that size. An
-    explicit ``sizes`` is taken as given."""
+    explicit ``sizes`` is taken as given. ``key_types`` are the node's
+    ``pub_key_types``: a curve's kernels are warmed only where the
+    chain admits its keys."""
     # registering the curve kernels is an import side effect
     from cometbft_tpu.crypto.tpu import ed25519_batch  # noqa: F401
+    from cometbft_tpu.crypto.tpu import secp256k1_batch  # noqa: F401
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
     ndev = mesh_mod.n_devices()
@@ -1074,11 +1098,12 @@ def warmup_plan(
                     reg.name, reg.kernel, reg.bucket_shapes(_MIN_PAD),
                     reg.donate_from, False, _MIN_PAD,
                 )
-                for reg in registered_kernels() if reg.forced
+                for reg in registered_kernels(key_types) if reg.forced
             )
     seen_sharded = set()
+    regs = registered_kernels(key_types)
     for bucket in buckets:
-        for reg in registered_kernels():
+        for reg in regs:
             if ndev > 1:
                 # the one rounding rule of every sharded launch
                 # (mesh.shard_chunks): a launch never carries more than
@@ -1107,6 +1132,7 @@ def run_warm_boot(
     registry: Optional[ExecutableRegistry] = None,
     stop_event: Optional[threading.Event] = None,
     tracer=None,
+    key_types: Sequence[str] = DEFAULT_KEY_TYPES,
 ) -> List[dict]:
     """Compile the whole warm-boot plan into ``registry`` (the process
     default when omitted), eagerly, on the calling thread. → one
@@ -1117,7 +1143,8 @@ def run_warm_boot(
     reg = registry if registry is not None else default_registry()
     tracer = tracer if tracer is not None else _trace.default_tracer()
     plan = warmup_plan(
-        floor=floor, sizes=sizes, include_single=include_single
+        floor=floor, sizes=sizes, include_single=include_single,
+        key_types=key_types,
     )
     topo_fp = topology_fingerprint()
     obs: List[dict] = []

@@ -1005,6 +1005,50 @@ def _prepare_rsh_compact(pk_arr: np.ndarray, msgs, sigs):
     return rsh, valid
 
 
+def resident_part(valset_id: bytes, pub_keys: Sequence[bytes], msgs, sigs):
+    """The commit's lanes against the device-resident rows of
+    ``pub_keys`` as one part of a stream (mesh.launch_parts): the rows'
+    chunks are its launches, ``_prepare_rsh`` its build. ``msgs`` as
+    verify_valset_resident takes it. → (part, the rows' shard plan or
+    None: where the launches must run)."""
+    from cometbft_tpu.crypto.tpu import mesh as mesh_mod
+    from cometbft_tpu.libs import trace as tracelib
+
+    n = len(pub_keys)
+    streamed = callable(msgs)
+    if len(sigs) != n or not (streamed or len(msgs) == n):
+        raise ValueError("msgs/sigs must have one entry per validator")
+    with tracelib.stage("commit.valset_id"):
+        rv = _get_resident(valset_id, pub_keys)
+    valid = np.ones(n, bool)
+    fetched = []  # the messages of the launch that is next
+
+    def fetch(chunk, start, end, inflight):
+        built = tracelib.stage("commit.msgs_chunk", chunk=chunk,
+                               lanes=end - start, inflight=inflight)
+        with built:
+            fetched.append(msgs(start, end))
+        return built.seconds  # the wire ledger's fetch phase
+
+    def build(start, end):
+        rsh, ok = _prepare_rsh(
+            rv.pk_arr[start:end],
+            fetched.pop() if streamed else msgs[start:end],
+            sigs[start:end],
+        )
+        valid[start:end] = ok & rv.pk_ok[start:end]
+        return [rsh]
+
+    # only the per-commit rsh staging is donated: the resident pubkey
+    # rows lead the call and must survive across commits
+    part = mesh_mod.StreamPart(
+        curve="ed25519", kernel=verify_kernel_resident, donate_from=1,
+        launches=rv.chunks, build=build, fetch=fetch if streamed else None,
+        n=n, valid=valid,
+    )
+    return part, rv.plan
+
+
 def verify_valset_resident(
     valset_id: bytes,
     pub_keys: Sequence[bytes],
@@ -1023,48 +1067,26 @@ def verify_valset_resident(
     pub_keys (the caller computes sha256 over their concatenation); the
     resident rows are trusted to match it. Accept/reject per present
     lane is bit-identical to verify_batch."""
-    n = len(pub_keys)
-    if n == 0:
+    if not pub_keys:
         return []
-    streamed = callable(msgs)
-    if len(sigs) != n or not (streamed or len(msgs) == n):
-        raise ValueError("msgs/sigs must have one entry per validator")
+    part, plan = resident_part(valset_id, pub_keys, msgs, sigs)
+    (mask,), _ = run_commit_parts([part], plan)
+    return list(mask)
+
+
+def run_commit_parts(parts, plan):
+    """A commit's parts (resident_part, secp256k1_batch.stream_part) as
+    one stream on the mesh the resident rows were placed on (``plan``,
+    a mesh.ShardPlan, or None: the default chip). This path runs beside
+    the scheduler (no flush, no supervisor), so the wire ledger is the
+    only place its device lanes are on record, under the route
+    ``resident`` (its flush record is verify_commit*'s own:
+    wire.own_flush). → (a mask a part, the stream's phase totals)."""
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
-    from cometbft_tpu.libs import trace as tracelib
 
-    with tracelib.stage("commit.valset_id"):
-        rv = _get_resident(valset_id, pub_keys)
-
-    # the mesh the rows were placed on: a launch runs where they live
-    plan = rv.plan
     nsh = plan.n_shards if plan is not None else 1
-    valid = np.ones(n, bool)
-    fetched = []  # the messages of the launch that is next
-
-    def fetch(chunk, start, end, inflight):
-        built = tracelib.stage("commit.msgs_chunk", chunk=chunk,
-                               lanes=end - start, inflight=inflight)
-        with built:
-            fetched.append(msgs(start, end))
-        return built.seconds  # the wire ledger's fetch phase
-
-    def build(start, end):
-        rsh, valid[start:end] = _prepare_rsh(
-            rv.pk_arr[start:end],
-            fetched.pop() if streamed else msgs[start:end],
-            sigs[start:end],
-        )
-        return [rsh]
-
-    # this path runs beside the scheduler (no flush, no supervisor), so
-    # the wire ledger is the only place its device lanes are on record
-    # (its flush record is verify_commit*'s own: wire.own_flush);
-    # only the per-commit rsh staging is donated — the resident pubkey
-    # rows lead the call and must survive across commits
-    out, _ = mesh_mod.launch_stream(
-        verify_kernel_resident, rv.chunks, build, n,
-        where=plan.mesh if plan is not None else None, prefix="resident",
-        route="resident", device_label=f"mesh:{nsh}" if nsh > 1 else "dev0",
-        donate_from=1, fetch=fetch if streamed else None,
+    return mesh_mod.launch_parts(
+        parts, where=plan.mesh if plan is not None else None,
+        prefix="resident", route="resident",
+        device_label=f"mesh:{nsh}" if nsh > 1 else "dev0",
     )
-    return list(out & valid & rv.pk_ok)

@@ -27,7 +27,7 @@ import os
 import threading
 import time
 import warnings
-from typing import Optional
+from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 from cometbft_tpu.libs import trace as _trace
 
@@ -469,7 +469,8 @@ def run_single(kernel, args, donate_from: int = 0, device=None):
 
 def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
                   route: str, device_label: str, domains=(),
-                  donate_from: int = 0, fetch=None):
+                  donate_from: int = 0, fetch=None, curve: str = "ed25519",
+                  programs=None):
     """THE loop from "here are lanes" to "here is the mask": every device
     dispatch of the crypto plane (dispatch_batch, dispatch_sharded, the
     resident commit, the indexed key store, verifyd's rows) is this
@@ -487,7 +488,11 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
     end, inflight)``, where given, gathers what build will pack and is
     no packing itself (a commit's sign-bytes, under a stage of their
     own) and returns that stage's seconds: the wire ledger's ``fetch``
-    phase. ``where`` is the placement: a jax Mesh (arguments sharded on
+    phase. ``curve`` names whose lanes the launches carry (the wire
+    ledger's book by curve); ``programs``, where a stream mixes curves
+    (launch_parts), gives each launch its own ``(kernel, donate_from,
+    curve)`` in place of those three. ``where`` is the placement: a jax
+    Mesh (arguments sharded on
     the trailing axis, the sharded executable) or one jax device / None
     for jax's default (run_single). ``domains`` are the labels of the
     fault domains the lanes are attributed to, in shard order: a shard
@@ -532,7 +537,7 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
                 where, PS(*([None] * (a.ndim - 1) + ["batch"]))
             ))
 
-        def call(args):
+        def call(kernel, donate_from, args):
             return registry.call(
                 kernel, args, donate_from=donate_from, sharded=True,
                 mesh=where,
@@ -543,7 +548,7 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         def put(a):
             return jax.device_put(jnp.asarray(a), where)
 
-        def call(args):
+        def call(kernel, donate_from, args):
             return run_single(
                 kernel, args, donate_from=donate_from, device=where
             )
@@ -586,7 +591,8 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
 
     def retire(slot):
         nonlocal last_retire_ns
-        chunk, start, end, size, mask, span, shard_spans, phases = slot
+        chunk, start, end, size, mask, span, shard_spans, phases, \
+            lane_curve = slot
         # np.asarray blocks until the device finishes this launch (and,
         # sharded, gathers the mask's slices from the chips): the wait
         # the stage reads IS the device-time attribution of the span
@@ -602,7 +608,8 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         if ledger is not None:
             ledger.note_chunk(
                 route, device_label, size // n_domains, end - start,
-                d2h_s=wait_ns / 1e9, padded_lanes=size, **phases,
+                d2h_s=wait_ns / 1e9, padded_lanes=size, curve=lane_curve,
+                **phases,
             )
         for s in (*shard_spans, span):
             s.end(device_wait_ns=wait_ns)
@@ -611,7 +618,7 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         while len(inflight) > keep:
             retire(inflight.popleft())
 
-    def issue(chunk, start, end, size, lead):
+    def issue(chunk, start, end, size, lead, program):
         """Builds and issues one launch -> its in-flight slot. A function
         of its own, so that the launch's staging (the built and padded
         host arrays, the donated device buffers) is released when it
@@ -647,7 +654,7 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
                 # the one reading no stage takes: it splits the launch
                 # stage between the transfer's issue and the call's
                 t_h2d = time.perf_counter_ns()
-                mask = call(lead + placed)
+                mask = call(program[0], program[1], lead + placed)
         if flush is not None:
             if chunk == 0:
                 flush.note_issue(issued.t1_ns, route)
@@ -689,15 +696,19 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         span.set_tag("host_ns", int(
             (phases["pack_s"] + h2d_s + phases["compute_s"]) * 1e9
         ))
-        return chunk, start, end, size, mask, span, shard_spans, phases
+        return (chunk, start, end, size, mask, span, shard_spans, phases,
+                program[2])
 
-    for chunk, (start, end, size, *lead) in enumerate(launches):
+    if programs is None:
+        programs = [(kernel, donate_from, curve)] * len(launches)
+    for chunk, ((start, end, size, *lead), program) in enumerate(
+            zip(launches, programs)):
         if cancel is not None and cancel.is_set():
             raise DispatchCancelled(
                 f"{route} dispatch cancelled before chunk {chunk} "
                 f"(sigs [{start}:{n}] undone)"
             )
-        inflight.append(issue(chunk, start, end, size, lead))
+        inflight.append(issue(chunk, start, end, size, lead, program))
         drain(depth)
     drain(0)
     if flush is not None and tot["chunks"]:
@@ -706,6 +717,72 @@ def launch_stream(kernel, launches, build, n: int, *, where, prefix: str,
         0.0, time.perf_counter() - t_wall0 - (clock.total() - built0)
     )
     return out, tot
+
+
+class StreamPart(NamedTuple):
+    """One curve's lanes of a stream that mixes curves (launch_parts):
+    its program (``kernel``, ``donate_from`` counted past the launch's
+    ``lead``), its ``launches`` [(start, end, size, *lead)] over its own
+    ``n`` lanes, launch_stream's ``build`` and ``fetch`` over the same
+    lanes, and ``valid``, bool[n], which ``build`` clears for the lanes
+    it found malformed."""
+
+    curve: str
+    kernel: Any
+    donate_from: int
+    launches: list
+    build: Callable
+    fetch: Optional[Callable]
+    n: int
+    valid: Any
+
+
+def launch_parts(parts: Sequence[StreamPart], *, where, prefix: str,
+                 route: str, device_label: str, domains=()):
+    """The parts' launches as ONE launch_stream, a launch of each part in
+    turn (round robin, in the order given), so that one curve's build
+    runs behind the device's work on the other's launches; the curve is
+    a dimension of the launch. → ([mask bool[part.n] & part.valid per
+    part], the stream's phase totals)."""
+    import numpy as np
+
+    offsets, total = [], 0
+    for part in parts:
+        offsets.append(total)
+        total += part.n
+    queues = [[(offsets[i] + start, offsets[i] + end, size, *lead)
+               for start, end, size, *lead in part.launches]
+              for i, part in enumerate(parts)]
+    launches, owners = [], []
+    for turn in range(max((len(q) for q in queues), default=0)):
+        for i, q in enumerate(queues):
+            if turn < len(q):
+                launches.append(q[turn])
+                owners.append(i)
+    by_start = {launch[0]: i for launch, i in zip(launches, owners)}
+
+    def build(start, end):
+        i = by_start[start]
+        return parts[i].build(start - offsets[i], end - offsets[i])
+
+    def fetch(chunk, start, end, inflight):
+        i = by_start[start]
+        part = parts[i]
+        if part.fetch is None:
+            return 0.0
+        return part.fetch(chunk, start - offsets[i], end - offsets[i],
+                          inflight)
+
+    programs = [(parts[i].kernel, parts[i].donate_from, parts[i].curve)
+                for i in owners]
+    out, tot = launch_stream(
+        parts[0].kernel if parts else None, launches, build, total,
+        where=where, prefix=prefix, route=route, device_label=device_label,
+        domains=domains, fetch=fetch, programs=programs,
+    )
+    masks = [np.asarray(out[offsets[i]:offsets[i] + part.n]) & part.valid
+             for i, part in enumerate(parts)]
+    return masks, tot
 
 
 def _slices_of(packed):
@@ -726,7 +803,8 @@ def _note_dispatch(route: str, device_label: str, n: int, tot: dict) -> None:
 
 
 def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
-                   device=None, launch: Optional[int] = None):
+                   device=None, launch: Optional[int] = None,
+                   curve: str = "ed25519"):
     """Chunk-pad-dispatch of a batch verify kernel (used by all three
     curve entries): cuts the batch into launches of at most the chunk
     cap, pads each launch's trailing batch axis to a power of two
@@ -743,6 +821,7 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
     ``short_floor``). With none given a launch is as large as the
     ceiling allows.
 
+    ``curve`` is whose lanes they are (the wire ledger's book by curve).
     ``device`` is an optional topology.DeviceHandle naming the fault
     domain this dispatch runs against; when omitted the thread's
     device_scope (installed by the supervisor) is consulted, and with
@@ -812,6 +891,7 @@ def dispatch_batch(kernel, packed, n: int, max_chunk: int, min_pad: int,
     out, tot = launch_stream(
         kernel, chunks, _slices_of(packed), n, where=where, prefix="mesh",
         route=_wire_route, device_label=_dev_label, domains=(_dev_label,),
+        curve=curve,
     )
     _note_dispatch(_wire_route, _dev_label, n, tot)
     if _plane is not None and chunks:

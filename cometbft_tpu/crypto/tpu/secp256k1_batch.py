@@ -26,9 +26,10 @@ Semantics contract — bit-identical accept/reject with the CPU verifier
   * accept iff R' = u1·G + u2·Q is not infinity and R'.x ≡ r (mod n),
     i.e. affine x == r or x == r + n (when r + n < p).
 
-u1 = e·s⁻¹, u2 = r·s⁻¹ mod n are host-side CPython big-int (~3 µs/sig,
-like the ed25519 host-hash mode); the ~4600 field muls of the scalar
-multiplication are the device's work.
+u1 = e·s⁻¹, u2 = r·s⁻¹ mod n are the host's (prepare_batch: one native
+call a launch, one inversion for all its lanes); the ~6,550 field
+multiplications of decompression, the table, the ladder and the final
+inversion are the device's work (benchmark/secp_opcount.py).
 """
 
 from __future__ import annotations
@@ -176,7 +177,9 @@ def unpack_digits(words: jnp.ndarray) -> jnp.ndarray:
 _N_FE = fe.const_fe(N)
 
 
-def _verify_core(wire: jnp.ndarray, flags: jnp.ndarray) -> jnp.ndarray:
+def _secp256k1_verify_core(
+    wire: jnp.ndarray, flags: jnp.ndarray
+) -> jnp.ndarray:
     """bool[B] from the compact wire — u32[32,B] (rows 0:8 qx, 8:16 r,
     16:24 u1, 24:32 u2, all LE words) + int32[B] flags (bit 0 = pubkey
     prefix parity, bit 1 = r + n < p). 132 bytes/sig on the link instead
@@ -195,7 +198,10 @@ def _verify_core(wire: jnp.ndarray, flags: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-verify_kernel = jax.jit(_verify_core)
+# the jitted program is named for its curve (the trace's module line reads
+# jit__secp256k1_verify_core), so a device trace tells its seconds from
+# the ed25519 programs' (benchmark/layers/secp256k1_kernel_us_per_lane.py)
+verify_kernel = jax.jit(_secp256k1_verify_core)
 
 
 def _verify_math(
@@ -246,9 +252,27 @@ def _verify_math(
 # --- host glue -------------------------------------------------------------
 
 _MIN_PAD = 64
+# The CEILING of a launch's lanes in total (mesh.chunk_cap may lower it).
 _MAX_CHUNK = 4096
+# The SIZE of one launch in lanes a chip (register_kernel's ``launch``):
+# a batch of more lanes is a stream of such launches, the short one
+# first, padded to at least half a launch (mesh.shard_chunks), so the
+# executables a batch of any size needs are @1024 and @2048 a chip. The
+# ed25519 entries' size (ed25519_batch._LAUNCH_LANES), and the one this
+# kernel was measured at on the v5e in the mixed commit (PERF.md, PR 38).
+_LAUNCH_LANES = 2048
+
+KEY_TYPE = "secp256k1"
 
 
+def _be(v: int) -> np.ndarray:
+    return np.frombuffer(v.to_bytes(32, "big"), np.uint8)
+
+
+_P_BE = _be(P)
+_N_BE = _be(N)
+_N_HALF_PLUS1_BE = _be(N // 2 + 1)  # s <= n/2: s < n/2 + 1
+_P_MINUS_N_BE = _be(P - N)  # r + n < p: r < p - n
 
 
 def _le_words(arr_u8: np.ndarray) -> np.ndarray:
@@ -256,49 +280,129 @@ def _le_words(arr_u8: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(np.ascontiguousarray(arr_u8).view("<u4").T)
 
 
+def _below(rows: np.ndarray, bound: np.ndarray) -> np.ndarray:
+    """bool[B]: each big-endian u8[B,32] row < ``bound`` (u8[32])."""
+    diff = rows.astype(np.int16) - bound.astype(np.int16)
+    nz = diff != 0
+    first = nz.argmax(axis=1)
+    return nz.any(axis=1) & (diff[np.arange(rows.shape[0]), first] < 0)
+
+
+def _parse_inputs(pub_keys, sigs):
+    """→ (pk_arr u8[B,33], sig_arr u8[B,64], valid) with every check the
+    CPU verifier makes before any curve math, over the launch at once:
+    lengths (a None signature, an absent lane, is malformed), prefix 2
+    or 3, x < p, r and s in [1, n), s ≤ n/2 (high-S refused). Malformed
+    lanes hold zeros."""
+    n = len(pub_keys)
+    valid = np.ones(n, bool)
+    try:
+        whole = (set(map(len, pub_keys)) <= {33}
+                 and set(map(len, sigs)) <= {64})
+    except TypeError:  # an absent lane's None signature
+        whole = False
+    if whole:
+        pk_parts, sig_parts = pub_keys, sigs
+    else:
+        pk_parts, sig_parts = [], []
+        for i in range(n):
+            pk, sig = pub_keys[i], sigs[i]
+            if sig is None or len(pk) != 33 or len(sig) != 64:
+                valid[i] = False
+                pk_parts.append(b"\x02" + bytes(32))
+                sig_parts.append(bytes(64))
+            else:
+                pk_parts.append(pk)
+                sig_parts.append(sig)
+    pk_arr = np.frombuffer(b"".join(pk_parts), np.uint8).reshape(n, 33)
+    sig_arr = np.frombuffer(b"".join(sig_parts), np.uint8).reshape(n, 64)
+    r_be, s_be = sig_arr[:, :32], sig_arr[:, 32:]
+    valid &= (pk_arr[:, 0] == 2) | (pk_arr[:, 0] == 3)
+    valid &= _below(pk_arr[:, 1:], _P_BE)
+    valid &= r_be.any(axis=1) & _below(r_be, _N_BE)
+    valid &= s_be.any(axis=1) & _below(s_be, _N_HALF_PLUS1_BE)
+    return pk_arr, sig_arr, valid
+
+
+# Lanes from which a pack's scalars are one native call
+# (native.secp256k1_scalars) rather than the hashlib + big-int loop below:
+# ed25519_batch._NATIVE_CHALLENGE_MIN's gate, for the same per-call cost.
+_NATIVE_SCALARS_MIN = 16
+
+
+def _scalars(sig_arr: np.ndarray, msgs, valid: np.ndarray):
+    """u1 = e·w, u2 = r·w (w = s⁻¹, e = SHA-256(msg) mod n) of each valid
+    lane → (u1, u2) as u8[B,32] little-endian; zeros elsewhere. The
+    launch's inversions are one (Montgomery's trick: one inversion and
+    three products a lane): one native C call from _NATIVE_SCALARS_MIN
+    lanes up (native/ed25519_batch.c cbft_secp256k1_scalars), else, or
+    where the native library is missing or stale, _scalars_py."""
+    from cometbft_tpu import native
+
+    if len(msgs) >= _NATIVE_SCALARS_MIN:
+        out = native.secp256k1_scalars(sig_arr, msgs, valid)
+        if out is not None:
+            return out
+    return _scalars_py(sig_arr.tobytes(), msgs, valid)
+
+
+def _scalars_py(sig_buf: bytes, msgs, valid: np.ndarray):
+    """_scalars with hashlib and CPython's big ints: the small launches'
+    path and the parity oracle."""
+    n = len(msgs)
+    lanes = np.flatnonzero(valid).tolist()
+    sha, from_bytes, mv = hashlib.sha256, int.from_bytes, memoryview(sig_buf)
+    r_of = [from_bytes(mv[64 * i:64 * i + 32], "big") for i in lanes]
+    s_of = [from_bytes(mv[64 * i + 32:64 * i + 64], "big") for i in lanes]
+    e_of = [from_bytes(sha(msgs[i]).digest(), "big") for i in lanes]
+    prefix, acc = [], 1
+    for s in s_of:
+        prefix.append(acc)
+        acc = acc * s % N
+    inv = pow(acc, -1, N) if lanes else 1
+    u1_of, u2_of = [0] * len(lanes), [0] * len(lanes)
+    for k in range(len(lanes) - 1, -1, -1):
+        w = inv * prefix[k] % N
+        inv = inv * s_of[k] % N
+        u1_of[k] = e_of[k] * w % N
+        u2_of[k] = r_of[k] * w % N
+    u1 = np.zeros((n, 32), np.uint8)
+    u2 = np.zeros((n, 32), np.uint8)
+    if lanes:
+        u1[lanes] = np.frombuffer(
+            b"".join(u.to_bytes(32, "little") for u in u1_of), np.uint8
+        ).reshape(-1, 32)
+        u2[lanes] = np.frombuffer(
+            b"".join(u.to_bytes(32, "little") for u in u2_of), np.uint8
+        ).reshape(-1, 32)
+    return u1, u2
+
+
 def prepare_batch(
     pub_keys: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
 ):
-    """Host packing + the structural checks the CPU verifier applies
-    before any curve math (lengths, prefix, x < p, r/s ranges, low-S).
-    → (wire u32[32,B], flags int32[B], valid): raw little-endian words
-    of qx, r, u1, u2 — the limb/digit splits run on device
-    (unpack_fe_limbs / unpack_digits), so the link carries 132 bytes/sig
-    instead of ~1,257."""
-    n = len(pub_keys)
-    valid = np.ones(n, bool)
-    qx_b = np.zeros((n, 32), np.uint8)
-    r_b = np.zeros((n, 32), np.uint8)
-    u1_b = np.zeros((n, 32), np.uint8)
-    u2_b = np.zeros((n, 32), np.uint8)
-    flags = np.zeros(n, np.int32)
-    for i in range(n):
-        pk, sig = pub_keys[i], sigs[i]
-        if len(pk) != 33 or pk[0] not in (2, 3) or len(sig) != 64:
-            valid[i] = False
-            continue
-        x = int.from_bytes(pk[1:], "big")
-        r = int.from_bytes(sig[:32], "big")
-        s = int.from_bytes(sig[32:], "big")
-        if x >= P or not (1 <= r < N) or not (1 <= s < N) or s > N // 2:
-            valid[i] = False
-            continue
-        e = int.from_bytes(hashlib.sha256(bytes(msgs[i])).digest(), "big") % N
-        w = pow(s, -1, N)
-        u1_b[i] = np.frombuffer((e * w % N).to_bytes(32, "little"), np.uint8)
-        u2_b[i] = np.frombuffer((r * w % N).to_bytes(32, "little"), np.uint8)
-        qx_b[i] = np.frombuffer(x.to_bytes(32, "little"), np.uint8)
-        r_b[i] = np.frombuffer(r.to_bytes(32, "little"), np.uint8)
-        flags[i] = (pk[0] & 1) | (2 if r + N < P else 0)
-
+    """Host packing of one launch + the structural checks the CPU
+    verifier applies before any curve math (_parse_inputs). → (wire
+    u32[32,B], flags int32[B], valid): raw little-endian words of qx, r,
+    u1, u2 (the limb/digit splits run on device: unpack_fe_limbs /
+    unpack_digits), 132 bytes/sig. Bulk: the checks, the byte order and
+    the word layout are array operations, the scalars one native call
+    (_scalars)."""
+    pk_arr, sig_arr, valid = _parse_inputs(pub_keys, sigs)
+    u1, u2 = _scalars(sig_arr, msgs, valid)
+    r_be = sig_arr[:, :32]
+    flags = (pk_arr[:, 0] & 1).astype(np.int32)
+    flags |= np.where(_below(r_be, _P_MINUS_N_BE), 2, 0).astype(np.int32)
+    flags[~valid] = 0
+    keep = valid[:, None]
     wire = np.concatenate(
         [
-            _le_words(qx_b),
-            _le_words(r_b),
-            _le_words(u1_b),
-            _le_words(u2_b),
+            _le_words(np.where(keep, pk_arr[:, :0:-1], 0).astype(np.uint8)),
+            _le_words(np.where(keep, r_be[:, ::-1], 0).astype(np.uint8)),
+            _le_words(u1),
+            _le_words(u2),
         ],
         axis=0,
     )
@@ -310,7 +414,9 @@ def verify_batch(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
 ) -> List[bool]:
-    """Public entry used by crypto.batch.TPUBatchVerifier for secp keys."""
+    """Public entry used by crypto.batch.TPUBatchVerifier for secp keys:
+    a stream of launches of _LAUNCH_LANES a chip (mesh.dispatch_batch),
+    each packed while the ones before it run."""
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
     n = len(pub_keys)
@@ -319,8 +425,6 @@ def verify_batch(
     valid_full = np.ones(n, bool)
 
     def chunk_pack(start: int, end: int):
-        # per-chunk packing: the host's scalar inversions for chunk i+1
-        # overlap the device's work on chunk i (dispatch is async)
         (*packed, valid) = prepare_batch(
             pub_keys[start:end], msgs[start:end], sigs[start:end]
         )
@@ -328,6 +432,61 @@ def verify_batch(
         return packed
 
     out = mesh_mod.dispatch_batch(
-        verify_kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD
+        verify_kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD,
+        launch=_LAUNCH_LANES, curve=KEY_TYPE,
     )
     return (out & valid_full).tolist()
+
+
+def stream_part(pub_keys: Sequence[bytes], msgs, sigs, n_shards: int):
+    """The secp256k1 seats of a commit as one part of a mixed stream
+    (mesh.launch_parts): launches of _LAUNCH_LANES a chip over
+    ``n_shards`` chips, the short one first (mesh.shard_chunks). ``msgs``
+    is ``(start, end) -> msgs[start:end]``, asked when the launch is next
+    (the commit's sign-bytes, built behind the device's work); ``sigs``
+    None marks an absent lane (False)."""
+    from cometbft_tpu.crypto.tpu import mesh as mesh_mod
+    from cometbft_tpu.libs import trace as tracelib
+
+    n = len(pub_keys)
+    cap = min(mesh_mod.chunk_cap(_MAX_CHUNK, _MIN_PAD),
+              _LAUNCH_LANES * n_shards)
+    launches = mesh_mod.shard_chunks(n, n_shards, cap, _MIN_PAD,
+                                     short_floor=cap // 2)
+    valid = np.ones(n, bool)
+    fetched = []
+
+    def fetch(chunk, start, end, inflight):
+        built = tracelib.stage("commit.msgs_chunk", chunk=chunk,
+                               lanes=end - start, inflight=inflight)
+        with built:
+            fetched.append(msgs(start, end))
+        return built.seconds
+
+    def build(start, end):
+        wire, flags, valid[start:end] = prepare_batch(
+            pub_keys[start:end], fetched.pop(), sigs[start:end]
+        )
+        return [wire, flags]
+
+    return mesh_mod.StreamPart(
+        curve=KEY_TYPE, kernel=verify_kernel, donate_from=0,
+        launches=launches, build=build, fetch=fetch, n=n, valid=valid,
+    )
+
+
+def _register_aot_kernels():
+    from cometbft_tpu.crypto.tpu import aot
+
+    # curve "secp256k1" (the name's prefix): a warm boot builds it only
+    # for a node whose consensus params admit secp256k1 validator keys
+    # (aot.warmup_plan's key_types)
+    aot.register_kernel(
+        "secp256k1.verify",
+        verify_kernel,
+        bucket_shapes=lambda b: [((32, b), np.uint32), ((b,), np.int32)],
+        launch=_LAUNCH_LANES,
+    )
+
+
+_register_aot_kernels()

@@ -233,6 +233,6 @@ def verify_batch(
         return packed
 
     out = mesh_mod.dispatch_batch(
-        verify_kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD
+        verify_kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD, curve="sr25519"
     )
     return (out & valid_full).tolist()

@@ -451,6 +451,11 @@ class WireLedger:
         self.challenge_calls = 0
         self.challenge_threads = 0
         self._challenge_lanes: Dict[str, int] = {}
+        # the lanes of each curve by where they ran ("device": a launch
+        # booked by note_chunk; "host": a partition the tpu backend kept
+        # on the host pool) and each curve's pack seconds
+        self._curve_lanes: Dict[Tuple[str, str], int] = {}
+        self._curve_pack_s: Dict[str, float] = {}
 
     # --- cold-boot link seed -------------------------------------------------
 
@@ -482,6 +487,7 @@ class WireLedger:
         hidden_s: float = 0.0,
         padded_lanes: Optional[int] = None,
         fetch_s: float = 0.0,
+        curve: str = "ed25519",
     ) -> None:
         """One chunk's phase attribution from the mesh dispatch loop.
         ``hidden_s`` is the portion of ``h2d_s`` spent while an earlier
@@ -490,7 +496,9 @@ class WireLedger:
         it is ``bucket`` (a loop that keys its profiles by the per-shard
         bucket says the total). ``fetch_s`` is what the stream's caller
         gathered for this launch before it was packed (launch_stream's
-        ``fetch``): a histogram phase of its own, in no cost profile."""
+        ``fetch``): a histogram phase of its own, in no cost profile.
+        ``curve`` is whose lanes the chunk carried: its lanes and pack
+        seconds go to that curve's book (``curve_books``) as well."""
         a = self._alpha
         bucket = int(bucket)
         padded = max(0, int(bucket if padded_lanes is None else padded_lanes))
@@ -509,6 +517,13 @@ class WireLedger:
                 0, int(lanes)
             )
             self._padded[route] = self._padded.get(route, 0) + padded
+            key = (curve, "device")
+            self._curve_lanes[key] = self._curve_lanes.get(key, 0) + max(
+                0, int(lanes)
+            )
+            self._curve_pack_s[curve] = (
+                self._curve_pack_s.get(curve, 0.0) + max(0.0, pack_s)
+            )
             key = (route, bucket, device)
             p = self._profiles.get(key)
             if p is None:
@@ -634,6 +649,30 @@ class WireLedger:
             self._challenge_lanes[path] = (
                 self._challenge_lanes.get(path, 0) + lanes
             )
+
+    def note_host_lanes(self, curve: str, lanes: int) -> None:
+        """``lanes`` of ``curve`` that the tpu backend verified on the host
+        pool: a partition under its curve's routing floor."""
+        key = (curve, "host")
+        with self._lock:
+            self._curve_lanes[key] = self._curve_lanes.get(key, 0) + lanes
+
+    def curve_books(self) -> Dict[str, Dict[str, float]]:
+        """{curve: {"device_lanes", "host_lanes", "pack_s"}}, monotonic."""
+        with self._lock:
+            lanes = dict(self._curve_lanes)
+            pack = dict(self._curve_pack_s)
+        out: Dict[str, Dict[str, float]] = {}
+        for (curve, where), n in lanes.items():
+            book = out.setdefault(curve, {
+                "device_lanes": 0, "host_lanes": 0, "pack_s": 0.0,
+            })
+            book[where + "_lanes"] = n
+        for curve, secs in pack.items():
+            out.setdefault(curve, {
+                "device_lanes": 0, "host_lanes": 0, "pack_s": 0.0,
+            })["pack_s"] = secs
+        return out
 
     def _series_of(self, route: str) -> Dict[str, Any]:
         """{phase: its ``phase_seconds`` series} for ``route``, made once
@@ -904,6 +943,7 @@ class WireLedger:
             "challenge_calls": challenges[0],
             "challenge_lanes": challenges[1],
             "challenge_threads": challenges[2],
+            "curves": self.curve_books(),
         }
 
 

@@ -300,3 +300,72 @@ def ed25519_challenges(
     if fn(pk, r, buf, offs, lens, ok, out, n, nthreads) != 0:
         return None
     return out
+
+
+def load_secp256k1_scalars():
+    """ctypes binding for cbft_secp256k1_scalars (same .so); None on any
+    load failure (a stale cached .so predating it included)."""
+    lib = load_ed25519()
+    if lib is None:
+        return None
+    fn = getattr(lib, "cbft_secp256k1_scalars", None)
+    if fn is None:
+        return None
+    if not getattr(fn, "_cbft_typed", False):
+        fn.restype = ctypes.c_int
+        fn.argtypes = [
+            _U8_ARG,          # sigs (r || s, big-endian), n*64
+            ctypes.c_char_p,  # msgs
+            _SIZE_T_ARG,      # msg_off
+            _SIZE_T_ARG,      # msg_len
+            _U8_ARG,          # valid, n
+            _U8_ARG,          # u1 out, n*32 LE
+            _U8_ARG,          # u2 out, n*32 LE
+            np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS"),
+            ctypes.c_size_t,  # n
+        ]
+        fn._cbft_typed = True
+    return fn
+
+
+def secp256k1_scalars(
+    sigs: np.ndarray,
+    msgs: Sequence[Optional[bytes]],
+    valid: np.ndarray,
+) -> Optional[tuple]:
+    """u1 = e/s, u2 = r/s mod n (e = SHA-256(msg) mod n) per valid lane,
+    one native call with one inversion for the launch.
+
+    ``sigs`` is the u8[n,64] rows of r || s, big-endian, with r and s in
+    [1, n) on every valid lane (the caller's checks); ``valid`` a bool or
+    uint8 array: lanes with valid[i] False are skipped (zeros) and their
+    message may be None. Returns (u1, u2) as u8[n,32] little-endian, or
+    None when the native path is unavailable (callers fall back to the
+    Python loop)."""
+    fn = load_secp256k1_scalars()
+    if fn is None:
+        return None
+    n = len(msgs)
+    sig = np.ascontiguousarray(sigs, np.uint8)
+    ok = np.ascontiguousarray(valid)
+    if ok.dtype != np.uint8:
+        ok = ok.astype(bool, copy=False).view(np.uint8)
+    if sig.shape != (n, 64) or ok.shape != (n,):
+        return None  # shape mismatch must not reach the C reader
+    u1 = np.zeros((n, 32), np.uint8)
+    u2 = np.zeros((n, 32), np.uint8)
+    if n == 0:
+        return u1, u2
+    try:
+        buf, offs, lens = _pack_msgs(msgs)
+    except TypeError:  # a None message: fine only on a skipped lane
+        absent = np.fromiter((m is None for m in msgs), bool, n)
+        if (absent & (ok != 0)).any():
+            return None
+        buf, offs, lens = _pack_msgs(
+            [b"" if m is None else m for m in msgs]
+        )
+    prefix = np.zeros(4 * n, np.uint64)
+    if fn(sig, buf, offs, lens, ok, u1, u2, prefix, n) != 0:
+        return None
+    return u1, u2

@@ -354,3 +354,172 @@ int cbft_ed25519_challenges(const unsigned char *pubs,
         rc |= chunks[t].rc;
     return rc;
 }
+
+/* ------------------------------------------------------------------------
+ * secp256k1 ECDSA scalars of a launch (crypto/tpu/secp256k1_batch.py
+ * _scalars): per valid lane e = SHA-256(msg) mod n, w = s^-1 mod n,
+ * u1 = e*w, u2 = r*w mod n, out as 32 little-endian bytes each. The
+ * launch's inversions are one (Montgomery's trick: prefix products, one
+ * Fermat power, three products a lane back). Arithmetic mod n in four
+ * 64-bit limbs: a product of 512 bits folds twice through
+ * 2^256 = c (mod n), c = 2^256 - n (129 bits), then at most two
+ * subtractions. Lanes with valid[i] == 0 are skipped (the caller's
+ * zeros); r and s are the caller's, range-checked (1 <= r, s < n). */
+
+/* SHA256_CTX is 112 bytes on every libcrypto since 0.9.8; declared with
+ * room to spare, as the image has no headers (cbft_sha512_ctx above). */
+typedef union {
+    unsigned long long words[32];
+} cbft_sha256_ctx;
+int SHA256_Init(cbft_sha256_ctx *c);
+int SHA256_Update(cbft_sha256_ctx *c, const void *data, size_t len);
+int SHA256_Final(unsigned char *md, cbft_sha256_ctx *c);
+
+typedef unsigned long long u64;
+typedef unsigned __int128 u128;
+
+static const u64 SECP_N[4] = {0xBFD25E8CD0364141ULL, 0xBAAEDCE6AF48A03BULL,
+                              0xFFFFFFFFFFFFFFFEULL, 0xFFFFFFFFFFFFFFFFULL};
+static const u64 SECP_C[3] = {0x402DA1732FC9BEBFULL, 0x4551231950B75FC4ULL,
+                              1ULL};
+
+static void sn_load_be(u64 out[4], const unsigned char *b)
+{
+    for (int i = 0; i < 4; i++) {
+        u64 w = 0;
+        for (int k = 0; k < 8; k++)
+            w = (w << 8) | b[8 * (3 - i) + k];
+        out[i] = w;
+    }
+}
+
+static void sn_store_le(unsigned char *b, const u64 a[4])
+{
+    for (int i = 0; i < 4; i++)
+        for (int k = 0; k < 8; k++)
+            b[8 * i + k] = (unsigned char)(a[i] >> (8 * k));
+}
+
+static int sn_geq_n(const u64 a[4])
+{
+    for (int i = 3; i >= 0; i--)
+        if (a[i] != SECP_N[i])
+            return a[i] > SECP_N[i];
+    return 1;
+}
+
+static void sn_sub_n(u64 a[4])
+{
+    u64 borrow = 0;
+    for (int i = 0; i < 4; i++) {
+        u128 d = (u128)a[i] - SECP_N[i] - borrow;
+        a[i] = (u64)d;
+        borrow = (u64)(d >> 64) & 1;
+    }
+}
+
+/* x (len <= 8 limbs, little-endian) mod n -> out */
+static void sn_reduce(const u64 *x, int len, u64 out[4])
+{
+    u64 t[8] = {0};
+    for (int i = 0; i < len; i++)
+        t[i] = x[i];
+    while (len > 4) {
+        u64 r[8] = {t[0], t[1], t[2], t[3], 0, 0, 0, 0};
+        for (int i = 0; i < len - 4; i++) {
+            u64 carry = 0;
+            for (int j = 0; j < 3; j++) {
+                u128 p = (u128)t[4 + i] * SECP_C[j] + r[i + j] + carry;
+                r[i + j] = (u64)p;
+                carry = (u64)(p >> 64);
+            }
+            for (int k = i + 3; carry && k < 8; k++) {
+                u128 s = (u128)r[k] + carry;
+                r[k] = (u64)s;
+                carry = (u64)(s >> 64);
+            }
+        }
+        len = 8;
+        while (len > 4 && r[len - 1] == 0)
+            len--;
+        for (int i = 0; i < 8; i++)
+            t[i] = r[i];
+    }
+    for (int i = 0; i < 4; i++)
+        out[i] = t[i];
+    while (sn_geq_n(out))
+        sn_sub_n(out);
+}
+
+static void sn_mul(u64 out[4], const u64 a[4], const u64 b[4])
+{
+    u64 p[8] = {0};
+    for (int i = 0; i < 4; i++) {
+        u64 carry = 0;
+        for (int j = 0; j < 4; j++) {
+            u128 m = (u128)a[i] * b[j] + p[i + j] + carry;
+            p[i + j] = (u64)m;
+            carry = (u64)(m >> 64);
+        }
+        p[i + 4] = carry;
+    }
+    sn_reduce(p, 8, out);
+}
+
+/* a^(n-2) mod n: a^-1 for a != 0 */
+static void sn_inv(u64 out[4], const u64 a[4])
+{
+    u64 e[4] = {SECP_N[0] - 2, SECP_N[1], SECP_N[2], SECP_N[3]};
+    u64 acc[4] = {1, 0, 0, 0};
+    for (int i = 255; i >= 0; i--) {
+        sn_mul(acc, acc, acc);
+        if ((e[i / 64] >> (i % 64)) & 1)
+            sn_mul(acc, acc, a);
+    }
+    for (int i = 0; i < 4; i++)
+        out[i] = acc[i];
+}
+
+/* Returns 0 on success. scratch: 4 * n u64 of the caller's. */
+int cbft_secp256k1_scalars(const unsigned char *sigs,
+                           const unsigned char *msgs,
+                           const size_t *msg_off, const size_t *msg_len,
+                           const unsigned char *valid, unsigned char *u1,
+                           unsigned char *u2, u64 *prefix, size_t n)
+{
+    u64 acc[4] = {1, 0, 0, 0};
+    for (size_t i = 0; i < n; i++) {
+        if (!valid[i])
+            continue;
+        u64 s[4];
+        sn_load_be(s, sigs + 64 * i + 32);
+        for (int k = 0; k < 4; k++)
+            prefix[4 * i + k] = acc[k];
+        sn_mul(acc, acc, s);
+    }
+    u64 inv[4];
+    sn_inv(inv, acc);
+    for (size_t i = n; i-- > 0;) {
+        if (!valid[i])
+            continue;
+        unsigned char digest[32];
+        cbft_sha256_ctx ctx;
+        if (SHA256_Init(&ctx) != 1 ||
+            SHA256_Update(&ctx, msgs + msg_off[i], msg_len[i]) != 1 ||
+            SHA256_Final(digest, &ctx) != 1)
+            return 1;
+        u64 s[4], r[4], e[4], w[4], u[4];
+        sn_load_be(s, sigs + 64 * i + 32);
+        sn_load_be(r, sigs + 64 * i);
+        sn_load_be(e, digest);
+        while (sn_geq_n(e))
+            sn_sub_n(e);
+        sn_mul(w, inv, prefix + 4 * i);
+        sn_mul(inv, inv, s);
+        sn_mul(u, e, w);
+        sn_store_le(u1 + 32 * i, u);
+        sn_mul(u, r, w);
+        sn_store_le(u2 + 32 * i, u);
+    }
+    return 0;
+}

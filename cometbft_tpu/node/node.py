@@ -57,6 +57,7 @@ from cometbft_tpu.state.store import Store as StateStore
 from cometbft_tpu.store import BlockStore
 from cometbft_tpu.types.event_bus import EventBus
 from cometbft_tpu.types.genesis import GenesisDoc
+from cometbft_tpu.types.params import default_consensus_params
 
 
 def _parse_laddr(laddr: str):
@@ -1279,14 +1280,19 @@ def _warm_tpu_kernels(config: Config) -> None:
     The supervisor's warmup canary (on_start) joins the warm boot
     before declaring HEALTHY; on_stop stops it with a bounded join.
     [crypto] warm_boot = eager|background|off (CBFT_WARM_BOOT env wins)
-    selects blocking/threaded/disabled."""
+    selects blocking/threaded/disabled. A curve's kernels are warmed
+    only where the genesis consensus params' ``validator.pub_key_types``
+    admit its keys."""
     from cometbft_tpu.crypto.tpu import aot, calibrate
 
     calib_path = calibrate.table_path()
     floor = int(config.crypto.min_batch)
+    key_types = tuple(_genesis_key_types(config))
 
     def body(stop_event):
-        obs = aot.run_warm_boot(floor=floor, stop_event=stop_event)
+        obs = aot.run_warm_boot(
+            floor=floor, stop_event=stop_event, key_types=key_types
+        )
         if stop_event.is_set():
             return obs
         # routing reads the table lazily by mtime
@@ -1297,6 +1303,14 @@ def _warm_tpu_kernels(config: Config) -> None:
     aot.start_warm_boot(
         aot.warm_boot_mode(config.crypto.warm_boot), body=body
     )
+
+
+def _genesis_key_types(config: Config):
+    """The validator key types the genesis consensus params admit."""
+    with open(config.base.genesis_path(), "rb") as f:
+        doc = GenesisDoc.from_json(f.read().decode())
+    params = doc.consensus_params or default_consensus_params()
+    return params.validator.pub_key_types
 
 
 def default_new_node(config: Config, logger: Optional[Logger] = None) -> Node:

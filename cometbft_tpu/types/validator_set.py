@@ -99,6 +99,7 @@ class ValidatorSet:
         self.validators: List[Validator] = []
         self.proposer: Optional[Validator] = None
         self._total_voting_power = 0
+        self._curves: Optional[cryptobatch.CurveIndex] = None
         if validators:
             self._update_with_change_set(
                 [v.copy() for v in validators], allow_deletes=False
@@ -149,6 +150,17 @@ class ValidatorSet:
         new.proposer = self.proposer.copy() if self.proposer else None
         new._total_voting_power = self._total_voting_power
         return new
+
+    def curve_index(self) -> cryptobatch.CurveIndex:
+        """The seats of each key type, their key bytes and the ids their
+        resident rows are stored under (crypto/batch.CurveIndex): made
+        once for the validator list the set holds, and again where the
+        set changes (a new list: updates, copy, decode, a caller's
+        reassignment)."""
+        curves = self._curves
+        if curves is None or curves.of is not self.validators:
+            curves = self._curves = cryptobatch.CurveIndex(self.validators)
+        return curves
 
     def hash(self) -> bytes:
         """Merkle root over SimpleValidator encodings
@@ -283,6 +295,7 @@ class ValidatorSet:
         for addr in delete_addrs:
             merged.pop(addr, None)
         self.validators = list(merged.values())
+        self._curves = None
         self._total_voting_power = 0
         self._update_total_voting_power()
         # scale and center, then canonical sort: power desc, address asc
@@ -296,33 +309,23 @@ class ValidatorSet:
 
     def _verify_lanes(self, lane_msgs, lane_sigs, entries, backend):
         """Batch-verify the present lanes; returns one bool per entry
-        (entry order). Routes through the device-resident full-lane path
-        (crypto/batch.py verify_commit_valset — the valset's pubkey rows
-        stay on device across heights) when the whole set is ed25519 and
-        the backend/shape is eligible; otherwise the add()/verify()
-        protocol. Accept/reject is identical either way. ``lane_msgs``
-        is one entry per validator, or verify_commit's per-launch source
-        (_stream_lane_msgs)."""
+        (entry order). Routes through the full-lane commit stream
+        (crypto/batch.py verify_commit_valset — the ed25519 seats' key
+        rows stay on device across heights, the secp256k1 seats run on
+        their own kernel in the same stream) when the backend/shape is
+        eligible; otherwise the add()/verify() protocol. Accept/reject is
+        identical either way. ``lane_msgs`` is one entry per validator,
+        or verify_commit's per-launch source (_stream_lane_msgs)."""
         if not entries:
             return []
-        from cometbft_tpu.crypto import ed25519 as ed
-
         if cryptobatch.resident_commit_eligible(len(entries), backend):
             with tracelib.stage("commit.valset_id"):
-                pub_keys = (
-                    [v.pub_key.bytes() for v in self.validators]
-                    if all(
-                        isinstance(v.pub_key, ed.PubKeyEd25519)
-                        for v in self.validators
-                    )
-                    else None
-                )
-            if pub_keys is not None:
-                full = cryptobatch.verify_commit_valset(
-                    pub_keys, lane_msgs, lane_sigs, backend
-                )
-                if full is not None:
-                    return [bool(full[e[0]]) for e in entries]
+                curves = self.curve_index()
+            full = cryptobatch.verify_commit_valset(
+                curves, lane_msgs, lane_sigs, backend
+            )
+            if full is not None:
+                return [bool(full[e[0]]) for e in entries]
         if callable(lane_msgs):  # a streamed commit the device path refused
             lane_msgs = lane_msgs(0, self.size())
         bv = cryptobatch.new_batch_verifier(backend)
@@ -570,18 +573,25 @@ def _fill_lane_msgs(
     _tag_sign_bytes(span, commit, idxs)
 
 
-def _stream_lane_msgs(span, commit: Commit, chain_id: str, idxs: List[int]):
-    """→ ``msgs(start, end)``: the sign-bytes of lanes [start, end) of a
-    full commit (lane = commit signature index; ``idxs``, ascending, the
-    present ones), None where the lane is absent; the slices come from
-    the commit's one or two templates, built once
-    (Commit.sign_bytes_builder). ``span`` is tagged as _fill_lane_msgs
-    tags it, with what the slices will add up to."""
-    many = commit.sign_bytes_builder(chain_id)
+class _SeatMessages:
+    """verify_commit's per-launch source of sign-bytes (lane = commit
+    signature index; ``idxs``, ascending, the present ones), None where
+    the lane is absent; built from the commit's one or two templates,
+    made once (Commit.sign_bytes_builder). ``msgs(start, end)`` gives
+    lanes [start, end); ``msgs.at(seats)`` the lanes of one curve's
+    seats (ascending), for a set whose key types interleave."""
 
-    def msgs(start: int, end: int) -> list:
+    __slots__ = ("_many", "_idxs", "_absent")
+
+    def __init__(self, commit: Commit, chain_id: str, idxs: List[int]):
+        self._many = commit.sign_bytes_builder(chain_id)
+        self._idxs = idxs
+        self._absent = len(idxs) < len(commit.signatures)
+
+    def __call__(self, start: int, end: int) -> list:
+        idxs = self._idxs
         present = idxs[bisect_left(idxs, start):bisect_left(idxs, end)]
-        built = many(present)
+        built = self._many(present)
         if len(present) == end - start:
             return built
         out = [None] * (end - start)
@@ -589,8 +599,23 @@ def _stream_lane_msgs(span, commit: Commit, chain_id: str, idxs: List[int]):
             out[idx - start] = msg
         return out
 
+    def at(self, seats: List[int]) -> list:
+        if not self._absent:
+            return self._many(seats)
+        idxs = self._idxs
+        present = [
+            s for s in seats
+            if (k := bisect_left(idxs, s)) < len(idxs) and idxs[k] == s
+        ]
+        built = dict(zip(present, self._many(present)))
+        return [built.get(s) for s in seats]
+
+
+def _stream_lane_msgs(span, commit: Commit, chain_id: str, idxs: List[int]):
+    """→ the commit's _SeatMessages. ``span`` is tagged as
+    _fill_lane_msgs tags it, with what the slices will add up to."""
     _tag_sign_bytes(span, commit, idxs)
-    return msgs
+    return _SeatMessages(commit, chain_id, idxs)
 
 
 def _tag_sign_bytes(span, commit: Commit, idxs: List[int]) -> None:

@@ -71,6 +71,21 @@ def blocksync_churn_toy() -> tuple:
     )
 
 
+def mixed_commit_toy() -> tuple:
+    """(toy config, toy params) of the ``mixed_commit`` generator: 12
+    validators, 6 of them secp256k1, 2 commits in the pool."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "traffic",
+        "commit-mixed-closed1.json")
+    with open(path) as fh:
+        params = json.load(fh)["params"]
+    return (
+        {"chain_id": "toy-mixed", "validators": 12,
+         "secp256k1_validators": 6, "pool_commits": 2},
+        dict(params, first_height=100),
+    )
+
+
 def sync_plane(backend):
     """What the ``blocksync_apply`` generator is handed, with no node
     behind it: ``backend`` stands where ``node.crypto_backend`` stands,
@@ -91,4 +106,5 @@ def _the_sync_generator_has_toy_sizes(request):
     if isinstance(toy, dict):
         toy.setdefault("blocksync_apply", blocksync_apply_toy())
         toy.setdefault("blocksync_churn", blocksync_churn_toy())
+        toy.setdefault("mixed_commit", mixed_commit_toy())
     yield

@@ -237,11 +237,15 @@ def test_verify_commit_raises_what_the_eager_path_raised(
             assert f"(#{min(CASES[case][0])})" in want[1]
 
 
-def test_a_mixed_key_set_builds_every_message_and_takes_the_batch_verifier(
+def test_a_mixed_key_set_streams_its_ed25519_seats_and_builds_no_more(
         signed, shards, monkeypatch):
-    """The key-type scan comes after the source is made: the refused
-    commit's lanes are then built at once, and verified as before."""
+    """A set with one secp256k1 seat (absent here) is indexed again when
+    its list is replaced, and its ed25519 seats stream against their own
+    resident rows (crypto/batch.verify_commit_valset's mixed path, not
+    the one-curve entry): no message is built but per launch, the
+    absent secp256k1 seat's none."""
     from cometbft_tpu.crypto import secp256k1
+    from cometbft_tpu.types.block import Commit
 
     vals, privs, bid = signed
     commit = _commit(signed, "spoiled_in_every_launch")
@@ -252,8 +256,17 @@ def test_a_mixed_key_set_builds_every_message_and_takes_the_batch_verifier(
         b"odd one").pub_key()
     commit.signatures[299] = CommitSig.absent()
     monkeypatch.setattr(eb, "verify_valset_resident", None)  # never reached
+    built = []
+    real = Commit.sign_bytes_builder
+
+    def counting(self, chain_id):
+        many = real(self, chain_id)
+        return lambda idxs: built.append(len(idxs)) or many(idxs)
+
+    monkeypatch.setattr(Commit, "sign_bytes_builder", counting)
     with pytest.raises(ValueError, match=r"wrong signature \(#10\)"):
         mixed.verify_commit(CHAIN_ID, bid, HEIGHT, commit, backend=TPU)
+    assert sum(built) == 299 and max(built) <= LAUNCH * shards
 
 
 # -- the layout at the real sizes ---------------------------------------------

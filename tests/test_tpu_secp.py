@@ -237,3 +237,95 @@ class TestCompactWireUnpack:
             np.asarray(wire[8:16, 0]).astype("<u4").tobytes(), "little"
         )
         assert r_w == r
+
+
+class TestBulkPack:
+    """prepare_batch packs a launch in bulk (array checks, one native
+    call for the scalars with one inversion): held to the per-lane pack
+    it replaced, kept here as the oracle, on a launch with every kind of
+    malformed lane, and the native scalars to the Python loop."""
+
+    @staticmethod
+    def _per_lane_pack(pub_keys, msgs, sigs):
+        import hashlib
+
+        n = len(pub_keys)
+        valid = np.ones(n, bool)
+        rows = np.zeros((4, n, 32), np.uint8)
+        flags = np.zeros(n, np.int32)
+        for i in range(n):
+            pk, sig = pub_keys[i], sigs[i]
+            if (sig is None or len(pk) != 33 or pk[0] not in (2, 3)
+                    or len(sig) != 64):
+                valid[i] = False
+                continue
+            x = int.from_bytes(pk[1:], "big")
+            r = int.from_bytes(sig[:32], "big")
+            s = int.from_bytes(sig[32:], "big")
+            if x >= F.P or not (1 <= r < F.N) or not (1 <= s < F.N) \
+                    or s > F.N // 2:
+                valid[i] = False
+                continue
+            e = int.from_bytes(hashlib.sha256(msgs[i]).digest(), "big") % F.N
+            w = pow(s, -1, F.N)
+            for k, v in enumerate((x, r, e * w % F.N, r * w % F.N)):
+                rows[k, i] = np.frombuffer(v.to_bytes(32, "little"), np.uint8)
+            flags[i] = (pk[0] & 1) | (2 if r + F.N < F.P else 0)
+        wire = np.concatenate(
+            [secp256k1_batch._le_words(rows[k]) for k in range(4)], axis=0)
+        return wire, flags, valid
+
+    @pytest.fixture(scope="class")
+    def launch(self):
+        rng = random.Random(5)
+        keys = [secp.gen_priv_key_from_secret(bytes([i])) for i in range(4)]
+        pks, msgs, sigs = [], [], []
+        for i in range(96):
+            k = keys[i % 4]
+            m = bytes(rng.randrange(256) for _ in range(rng.randrange(140)))
+            sig = k.sign(m) if i < 24 else None
+            if sig is None:  # signing is slow: random in-range scalars
+                r = rng.randrange(1, F.N)
+                s = rng.randrange(1, F.N // 2 + 1)
+                sig = r.to_bytes(32, "big") + s.to_bytes(32, "big")
+            pks.append(k.pub_key().bytes())
+            msgs.append(m)
+            sigs.append(sig)
+        r0 = sigs[0][:32]
+        plant = {
+            30: (pks[30], b"\x05" + pks[30][1:], sigs[30]),
+            31: (pks[31], b"\x02" + F.P.to_bytes(32, "big"), sigs[31]),
+            32: (pks[32], pks[32], bytes(32) + sigs[32][32:]),
+            33: (pks[33], pks[33], sigs[33][:32] + bytes(32)),
+            34: (pks[34], pks[34], F.N.to_bytes(32, "big") + sigs[34][32:]),
+            35: (pks[35], pks[35], r0 + (F.N // 2 + 1).to_bytes(32, "big")),
+            36: (pks[36], pks[36], r0 + (F.N // 2).to_bytes(32, "big")),
+            37: (pks[37], pks[37], r0 + (1).to_bytes(32, "big")),
+            38: (pks[38], pks[38], (F.N - 1).to_bytes(32, "big") + sigs[38][32:]),
+            39: (pks[39], pks[39], sigs[39][:63]),
+            40: (pks[40], pks[40][:32], sigs[40]),
+            41: (pks[41], pks[41], None),
+        }
+        for i, (_, pk, sig) in plant.items():
+            pks[i], sigs[i] = pk, sig
+        msgs[41] = None  # an absent lane: no message, no signature
+        return pks, msgs, sigs
+
+    def test_the_bulk_pack_is_the_per_lane_pack(self, launch):
+        got = secp256k1_batch.prepare_batch(*launch)
+        want = self._per_lane_pack(*launch)
+        for g, w in zip(got, want):
+            assert (np.asarray(g) == w).all()
+        assert list(np.flatnonzero(~got[2])) == [30, 31, 32, 33, 34, 35,
+                                                 39, 40, 41]
+
+    def test_the_native_scalars_are_the_python_loops(self, launch):
+        from cometbft_tpu import native
+
+        pks, msgs, sigs = launch
+        pk_arr, sig_arr, valid = secp256k1_batch._parse_inputs(pks, sigs)
+        got = native.secp256k1_scalars(sig_arr, msgs, valid)
+        if got is None:
+            pytest.skip("no native library here")
+        want = secp256k1_batch._scalars_py(sig_arr.tobytes(), msgs, valid)
+        assert all((g == w).all() for g, w in zip(got, want))
