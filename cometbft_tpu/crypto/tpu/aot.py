@@ -345,8 +345,9 @@ class Metrics:
 # squaring (153 limb products), so every ed25519 / sr25519 program
 # changed, and the key holds nothing of a program: kernel name, shapes
 # and fingerprints are those of the executables a checkout upgraded in
-# place still has in its store.
-_STORE_FORMAT = 3
+# place still has in its store. 4: secp_field's product is slices and
+# adds off the CPU platform, so the secp256k1 program changed.
+_STORE_FORMAT = 4
 
 
 class ExecutableStore:

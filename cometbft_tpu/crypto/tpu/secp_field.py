@@ -9,10 +9,19 @@ V = 2^266 mod p = 2^42 + 977·2^10 whose radix-2^14 limbs are
 [1024, 61, 0, 1] — all tiny, which is what keeps fold-back carries from
 inflating limbs past the int32 product bound (a radix-15 layout was
 tried first: its fold limb 16384 is HALF the radix, and identity-heavy
-op chains overflowed). A multiply reduces in two stages: {0,1}-matrix
-scatter of the outer product into 38 columns (exact in int32 — unit
-weights), then two V-folds with lo/hi product splits (the scalar.py
-sc_reduce pattern).
+op chains overflowed).
+
+A multiply is three stages. (1) The 38 columns of a·b: every one of the
+361 limb products split into a 14-bit lo and a signed hi part, lo_ij on
+column i + j, hi_ij on i + j + 1. Their form follows the platform, as
+field.default_mul_impl() decides ed25519's: on the CPU platform two
+{0,1}-matrix products over the flattened outer product (a small graph,
+which is what XLA:CPU's compile time follows); on every other platform
+the outer product's rows padded into place, stacked and summed: slices
+and adds. The same integers either way (exact int32 sums,
+|col| < 2^21). (2) Two V-folds of columns 19..37 with lo/hi product
+splits (the scalar.py sc_reduce pattern), on [19, B] / [5, B] slices.
+(3) Four vectorized carry rounds.
 
 Verification-only: no constant-time requirements. Exactness is pinned
 by randomized chained-composition parity tests against CPython big-int
@@ -28,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from cometbft_tpu.crypto.tpu.field import default_mul_impl
+
 P = 2**256 - 2**32 - 977
 N = 0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFEBAAEDCE6AF48A03BBFD25E8CD0364141
 B3 = 21  # 3·b for the complete-addition formulas (b = 7)
@@ -38,6 +49,7 @@ _MASK = 0x3FFF
 
 _V = (1 << (RADIX * NUM_LIMBS)) % P  # 2^266 mod p = 2^42 + 977·2^10
 _V_LIMBS = [(_V >> (RADIX * i)) & _MASK for i in range(4)]
+_V_VEC = np.array(_V_LIMBS, np.int32)
 
 
 def int_to_limbs(n: int) -> List[int]:
@@ -70,18 +82,15 @@ _FOUR_P_COLS = _cols_of(4 * P)  # top column < 2^18
 
 
 def _carry_round(x: jnp.ndarray) -> jnp.ndarray:
-    """One vectorized carry round; the top carry (callers keep it
-    < 2^14) folds back through V's limbs [1024, 61, 0, 1] — products
-    < 2^24."""
+    """One vectorized carry round: each limb keeps its low 14 bits and
+    passes the signed carry one limb up; the top carry (callers keep it
+    < 2^14) folds back through V's limbs [1024, 61, 0, 1] as one [4, B]
+    product (< 2^24) onto limbs 0..3."""
     c = x >> RADIX
-    kept = x & _MASK
-    shifted = jnp.concatenate([jnp.zeros_like(c[:1]), c[:-1]], axis=0)
-    out = kept + shifted
-    top = c[NUM_LIMBS - 1]
-    for i, v in enumerate(_V_LIMBS):
-        if v:
-            out = out.at[i].add(top * jnp.int32(v))
-    return out
+    fold = c[NUM_LIMBS - 1 :] * _V_VEC.reshape((4,) + (1,) * (x.ndim - 1))
+    return (x & _MASK) + jnp.concatenate(
+        [fold[:1], c[:3] + fold[1:], c[3 : NUM_LIMBS - 1]], axis=0
+    )
 
 
 def _reduce(cols: jnp.ndarray) -> jnp.ndarray:
@@ -114,8 +123,6 @@ def mul_small(a: jnp.ndarray, c: int) -> jnp.ndarray:
 
 def _scatter_matrices():
     """{0,1} matrices [38, 361]: position of each outer-product part."""
-    import numpy as np
-
     width = 2 * NUM_LIMBS
     m_lo = np.zeros((width, NUM_LIMBS * NUM_LIMBS), np.int32)
     m_hi = np.zeros((width, NUM_LIMBS * NUM_LIMBS), np.int32)
@@ -123,61 +130,113 @@ def _scatter_matrices():
         for j in range(NUM_LIMBS):
             idx = i * NUM_LIMBS + j
             m_lo[i + j, idx] = 1
-            if i + j + 1 < width:
-                m_hi[i + j + 1, idx] = 1
+            m_hi[i + j + 1, idx] = 1
     return m_lo, m_hi
 
 
 _M_LO, _M_HI = _scatter_matrices()
 
 
-def _carry_signed_list(cols: List[jnp.ndarray]) -> List[jnp.ndarray]:
+def _cols_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The 38 columns of a·b by two [38, 361] @ [361, B] int32 matrix
+    products over the flattened outer product's lo / hi parts: the
+    small graph the CPU platform compiles quickly."""
+    flat = NUM_LIMBS * NUM_LIMBS
+    prod = a[:, None] * b[None, :]  # [19, 19, B]
+    lo = (prod & _MASK).reshape((flat,) + prod.shape[2:])
+    hi = (prod >> RADIX).reshape((flat,) + prod.shape[2:])
+    return jnp.asarray(_M_LO) @ lo + jnp.asarray(_M_HI) @ hi
+
+
+def _cols_stack(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The same 38 columns in field._mul_stack's form: row i of the
+    outer product, lo parts padded onto columns i..i+18 and hi parts
+    onto i+1..i+19, the 38 rows stacked and summed. No matrix. Chosen
+    on the v5e over field._mul_shift_add's form by the whole kernel in
+    the mixed commit: 55.3 us a secp256k1 lane against 78.6, though one
+    mul alone in a loop at [19, 2048] read 13.18 us against 11.76 (the
+    matrix products 20.32; PERF.md, section 6)."""
+    prod = a[:, None] * b[None, :]  # [19, 19, B]
+    lo = prod & _MASK
+    hi = prod >> RADIX
+    width = 2 * NUM_LIMBS
+    tail_pad = [(0, 0)] * (prod.ndim - 2)
+    rows = []
+    for i in range(NUM_LIMBS):
+        rows.append(jnp.pad(lo[i], [(i, width - NUM_LIMBS - i)] + tail_pad))
+        rows.append(
+            jnp.pad(hi[i], [(i + 1, width - NUM_LIMBS - i - 1)] + tail_pad)
+        )
+    return jnp.sum(jnp.stack(rows, axis=0), axis=0)
+
+
+def _normalize(x: jnp.ndarray) -> jnp.ndarray:
+    """Signed columns [n, B] → the same value as n - 1 14-bit limbs and
+    a signed top that keeps the rest: one sequential carry, the one
+    exact form of that representation."""
     out = []
-    carry = jnp.zeros_like(cols[0])
-    for c in cols[:-1]:
-        t = c + carry
+    carry = jnp.zeros_like(x[0])
+    for i in range(x.shape[0] - 1):
+        t = x[i] + carry
         out.append(t & _MASK)
         carry = t >> RADIX
-    out.append(cols[-1] + carry)  # top keeps the signed remainder
-    return out
+    out.append(x[-1] + carry)
+    return jnp.stack(out, axis=0)
 
 
-def _fold_v(cols36: jnp.ndarray) -> jnp.ndarray:
+def _times_v(h: jnp.ndarray, width: int) -> jnp.ndarray:
+    """h·V as [width, B] columns for limbs h [n, B]: for each nonzero
+    limb v_j of V one [n, B] product, its 14-bit lo parts padded onto
+    columns j..j+n-1 and its signed hi parts onto j+1..j+n (|h| ≤
+    2^15ish → |h·v_j| < 2^30)."""
+    n = h.shape[0]
+    tail_pad = [(0, 0)] * (h.ndim - 1)
+    acc = None
+    for j, v in enumerate(_V_LIMBS):
+        if v:
+            p = h * v
+            term = jnp.pad(p & _MASK, [(j, width - n - j)] + tail_pad)
+            term = term + jnp.pad(
+                p >> RADIX, [(j + 1, width - n - j - 1)] + tail_pad
+            )
+            acc = term if acc is None else acc + term
+    return acc
+
+
+def _fold_v(cols38: jnp.ndarray) -> jnp.ndarray:
     """38 signed columns (|col| < 2^22) → 19 columns, value mod p.
 
     hi := columns 19..37 normalized to 14-bit limbs (+ signed top);
     acc := lo + hi·V with every product split into 14-bit lo / signed
     hi parts (products < 2^26, column sums < 2^24). The fold spills
-    into a few extra columns — one second, tiny fold brings those
-    home."""
-    lo = [cols36[i] for i in range(NUM_LIMBS)]
-    hi = _carry_signed_list([cols36[NUM_LIMBS + i] for i in range(NUM_LIMBS)])
-    acc = lo + [jnp.zeros_like(lo[0]) for _ in range(5)]
+    into columns 19..23 — one second, tiny fold of those 5 brings them
+    home (spill ≤ 5 limbs → columns ≤ 4 + 3 + 1 < 19)."""
+    spill_cols = 5
+    width = NUM_LIMBS + spill_cols
+    tail_pad = [(0, 0)] * (cols38.ndim - 1)
+    hi = _normalize(cols38[NUM_LIMBS:])
+    acc = jnp.pad(cols38[:NUM_LIMBS], [(0, spill_cols)] + tail_pad)
+    acc = acc + _times_v(hi, width)
+    spill = _normalize(acc[NUM_LIMBS:])
+    return acc[:NUM_LIMBS] + _times_v(spill, NUM_LIMBS)
 
-    def fold_into(acc, limbs):
-        for i, h in enumerate(limbs):
-            for j, v in enumerate(_V_LIMBS):
-                if v:
-                    p = h * jnp.int32(v)  # |h| ≤ 2^15ish → |p| < 2^30
-                    acc[i + j] = acc[i + j] + (p & _MASK)
-                    acc[i + j + 1] = acc[i + j + 1] + (p >> RADIX)
-        return acc
 
-    acc = fold_into(acc, hi)  # spills into acc[19..23]
-    spill = _carry_signed_list(acc[NUM_LIMBS:])
-    acc = acc[:NUM_LIMBS] + [jnp.zeros_like(lo[0])] * 5
-    acc = fold_into(acc, spill)
-    # second spill lands inside: spill ≤ 6 limbs → i+j+1 ≤ 6+3 < 19 ✓
-    return jnp.stack(acc[:NUM_LIMBS], axis=0)
+# The two forms of a product's columns; one reduction follows either.
+_MUL_IMPLS = {
+    "matmul": _cols_matmul,
+    "stack": _cols_stack,
+}
+
+
+def _mul_form() -> str:
+    """The platform's form, by field.default_mul_impl()'s platform test:
+    the matrix products where ed25519 takes its matmul (the CPU
+    platform), slices and adds on every other."""
+    return "matmul" if default_mul_impl() == "matmul" else "stack"
 
 
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    flat = NUM_LIMBS * NUM_LIMBS
-    prod = a[:, None] * b[None, :]  # [19, 19, B]
-    lo = (prod & _MASK).reshape((flat,) + prod.shape[2:])
-    hi = (prod >> RADIX).reshape((flat,) + prod.shape[2:])
-    cols36 = jnp.asarray(_M_LO) @ lo + jnp.asarray(_M_HI) @ hi
-    return _reduce(_fold_v(cols36))
+    return _reduce(_fold_v(_MUL_IMPLS[_mul_form()](a, b)))
 
 
 def sq(a: jnp.ndarray) -> jnp.ndarray:

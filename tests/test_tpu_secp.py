@@ -27,6 +27,15 @@ def _assert_parity(pks, msgs, sigs):
     return got
 
 
+@pytest.fixture(params=sorted(F._MUL_IMPLS))
+def form(request, monkeypatch):
+    """F.mul (and everything built on it) in the named form of its
+    columns, whatever the platform: the TPU's slice form otherwise runs
+    only on hardware."""
+    monkeypatch.setattr(F, "_mul_form", lambda: request.param)
+    return request.param
+
+
 class TestSecpField:
     def _fe1(self, n):
         import jax.numpy as jnp
@@ -36,7 +45,7 @@ class TestSecpField:
     def _val(self, x):
         return F.limbs_to_int(np.asarray(F.to_canonical(x))[:, 0])
 
-    def test_ops_parity(self):
+    def test_ops_parity(self, form):
         rng = random.Random(7)
         for _ in range(15):
             a, b = rng.randrange(F.P), rng.randrange(F.P)
@@ -45,7 +54,7 @@ class TestSecpField:
             assert self._val(F.sub(fa, fb)) == (a - b) % F.P
             assert self._val(F.mul(fa, fb)) == (a * b) % F.P
 
-    def test_chained_compositions_preserve_invariant(self):
+    def test_chained_compositions_preserve_invariant(self, form):
         rng = random.Random(11)
         for trial in range(6):
             ints = [rng.randrange(F.P) for _ in range(6)]
@@ -61,13 +70,13 @@ class TestSecpField:
                     x, xi = F.sub(x, fes[i]), (xi - ints[i]) % F.P
             assert self._val(x) == xi, trial
 
-    def test_invert_and_sqrt(self):
+    def test_invert_and_sqrt(self, form):
         inv = F.invert(self._fe1(987654321))
         assert self._val(inv) * 987654321 % F.P == 1
         s = self._val(F.sqrt_candidate(self._fe1(9)))
         assert pow(s, 2, F.P) == 9
 
-    def test_identity_chain_stays_bounded(self):
+    def test_identity_chain_stays_bounded(self, form):
         """The radix-14 redesign exists exactly for this: long identity-
         doubling chains must not inflate limbs past the invariant."""
         import jax.numpy as jnp
@@ -82,6 +91,180 @@ class TestSecpField:
             assert self._val(acc[0]) == 0 and self._val(acc[2]) == 0, i
             m = max(int(np.abs(np.asarray(c)).max()) for c in acc)
             assert m < (1 << F.RADIX) + 4096, (i, m)
+
+
+# the invariant's edges, as test_identity_chain_stays_bounded holds them:
+# limbs in [-4, 2^14 + 4096)
+_LOW, _TOP = -4, (1 << F.RADIX) + 4095
+_CORNERS = {
+    "all_top": [_TOP] * F.NUM_LIMBS,
+    "all_bottom": [_LOW] * F.NUM_LIMBS,
+    "alternating": [_TOP, _LOW] * 9 + [_TOP],
+    "alternating_from_bottom": [_LOW, _TOP] * 9 + [_LOW],
+    "limb0_top_rest_2^14": [_TOP] + [1 << F.RADIX] * (F.NUM_LIMBS - 1),
+}
+
+
+def _limbs(rows):
+    import jax.numpy as jnp
+
+    return jnp.array(np.asarray(rows, np.int64).T, jnp.int32)
+
+
+class TestProductForms:
+    """The platforms' two forms of a product's columns are one set of
+    integers: every verdict, bound and test of one holds for the other."""
+
+    @pytest.fixture(scope="class")
+    def operands(self):
+        rng = np.random.default_rng(39)
+        corners = list(_CORNERS.values())
+        a = corners + corners + rng.integers(
+            _LOW, _TOP + 1, (64, F.NUM_LIMBS)).tolist()
+        b = corners + corners[::-1] + rng.integers(
+            _LOW, _TOP + 1, (64, F.NUM_LIMBS)).tolist()
+        return _limbs(a), _limbs(b)
+
+    def test_columns_are_integer_identical(self, operands):
+        a, b = operands
+        want = np.asarray(F._cols_matmul(a, b))
+        got = np.asarray(F._cols_stack(a, b))
+        assert got.shape == (2 * F.NUM_LIMBS, a.shape[1])
+        assert np.array_equal(got, want)
+        assert np.abs(want).max() < 1 << 21  # _fold_v wants < 2^22
+
+    def test_limbs_are_integer_identical_and_in_the_invariant(
+            self, operands, monkeypatch):
+        a, b = operands
+        out = {}
+        for name in sorted(F._MUL_IMPLS):
+            monkeypatch.setattr(F, "_mul_form", lambda n=name: n)
+            out[name] = np.asarray(F.mul(a, b))
+        assert np.array_equal(out["stack"], out["matmul"])
+        limbs = out["stack"]
+        assert limbs.min() >= _LOW and limbs.max() <= _TOP
+        an, bn, got = np.asarray(a), np.asarray(b), limbs
+        for k in range(a.shape[1]):
+            x = F.limbs_to_int(an[:, k])
+            y = F.limbs_to_int(bn[:, k])
+            assert F.limbs_to_int(got[:, k]) % F.P == x * y % F.P, k
+
+    def test_a_constant_operand_broadcasts(self, operands):
+        a, _ = operands
+        c = F.const_fe(F.B3)
+        for x, y in ((a, c), (c, a)):
+            assert np.array_equal(np.asarray(F._cols_stack(x, y)),
+                                  np.asarray(F._cols_matmul(x, y)))
+
+
+def test_the_platform_names_the_form(monkeypatch):
+    """The CPU platform keeps the matrix products (XLA:CPU compiles the
+    whole kernel for the tests); every other platform takes the slices.
+    No option chooses it."""
+    import jax
+
+    seen = []
+    for name in sorted(F._MUL_IMPLS):
+        monkeypatch.setitem(
+            F._MUL_IMPLS, name,
+            lambda a, b, n=name: seen.append(n) or F._cols_matmul(a, b))
+    one = F.const_fe(1)
+    for backend, form in (("cpu", "matmul"), ("tpu", "stack")):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        monkeypatch.setenv("CBFT_TPU_MUL", "f32")  # ed25519's alone
+        assert F._mul_form() == form
+        F.mul(one, one)
+        assert seen[-1] == form
+
+
+# secp_field's reduction as it was written first, one limb row at a time:
+# the oracle the vector form is held to, integer for integer
+def _per_row_carry_round(x):
+    import jax.numpy as jnp
+
+    c = x >> F.RADIX
+    kept = x & F._MASK
+    shifted = jnp.concatenate([jnp.zeros_like(c[:1]), c[:-1]], axis=0)
+    out = kept + shifted
+    top = c[F.NUM_LIMBS - 1]
+    for i, v in enumerate(F._V_LIMBS):
+        if v:
+            out = out.at[i].add(top * jnp.int32(v))
+    return out
+
+
+def _per_row_carry_signed_list(cols):
+    import jax.numpy as jnp
+
+    out = []
+    carry = jnp.zeros_like(cols[0])
+    for c in cols[:-1]:
+        t = c + carry
+        out.append(t & F._MASK)
+        carry = t >> F.RADIX
+    out.append(cols[-1] + carry)
+    return out
+
+
+def _per_row_fold_v(cols36):
+    import jax.numpy as jnp
+
+    lo = [cols36[i] for i in range(F.NUM_LIMBS)]
+    hi = _per_row_carry_signed_list(
+        [cols36[F.NUM_LIMBS + i] for i in range(F.NUM_LIMBS)])
+    acc = lo + [jnp.zeros_like(lo[0]) for _ in range(5)]
+
+    def fold_into(acc, limbs):
+        for i, h in enumerate(limbs):
+            for j, v in enumerate(F._V_LIMBS):
+                if v:
+                    p = h * jnp.int32(v)
+                    acc[i + j] = acc[i + j] + (p & F._MASK)
+                    acc[i + j + 1] = acc[i + j + 1] + (p >> F.RADIX)
+        return acc
+
+    acc = fold_into(acc, hi)
+    spill = _per_row_carry_signed_list(acc[F.NUM_LIMBS:])
+    acc = acc[:F.NUM_LIMBS] + [jnp.zeros_like(lo[0])] * 5
+    acc = fold_into(acc, spill)
+    return jnp.stack(acc[:F.NUM_LIMBS], axis=0)
+
+
+class TestVectorReduction:
+    """_carry_round and _fold_v as [19, B] / [5, B] slices give the per-row
+    forms' integers on every input inside their preconditions."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_carry_round_is_the_per_row_round(self, seed):
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(seed)
+        bound = 1 << 25  # _reduce's precondition
+        x = rng.integers(-bound + 1, bound, (F.NUM_LIMBS, 256))
+        x[:, 0], x[:, 1] = bound - 1, -bound + 1
+        x = jnp.asarray(x, jnp.int32)
+        for _ in range(4):
+            want = _per_row_carry_round(x)
+            assert np.array_equal(np.asarray(F._carry_round(x)),
+                                  np.asarray(want))
+            x = want
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_fold_v_is_the_per_row_fold(self, seed):
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(seed + 100)
+        bound = 1 << 22  # _fold_v's precondition
+        cols = rng.integers(-bound + 1, bound, (2 * F.NUM_LIMBS, 256))
+        cols[:, 0], cols[:, 1] = bound - 1, -bound + 1
+        # carry ripples: hi columns of 0 / 2^14 - 1 under a +-1 carry
+        cols[F.NUM_LIMBS:, 2] = (1 << F.RADIX) - 1
+        cols[F.NUM_LIMBS, 2] = 1 << F.RADIX
+        cols[F.NUM_LIMBS:, 3] = 0
+        cols[F.NUM_LIMBS, 3] = -1
+        cols = jnp.asarray(cols, jnp.int32)
+        assert np.array_equal(np.asarray(F._fold_v(cols)),
+                              np.asarray(_per_row_fold_v(cols)))
 
 
 class TestSecpVerifyParity:
