@@ -346,8 +346,10 @@ class Metrics:
 # changed, and the key holds nothing of a program: kernel name, shapes
 # and fingerprints are those of the executables a checkout upgraded in
 # place still has in its store. 4: secp_field's product is slices and
-# adds off the CPU platform, so the secp256k1 program changed.
-_STORE_FORMAT = 4
+# adds off the CPU platform, so the secp256k1 program changed. 5: the
+# secp256k1 ladder is one Pallas kernel off the CPU platform
+# (secp_ladder), a Mosaic call inside the same program.
+_STORE_FORMAT = 5
 
 
 class ExecutableStore:

@@ -1,4 +1,4 @@
-"""Batched secp256k1 ECDSA verification as one XLA tensor program.
+"""Batched secp256k1 ECDSA verification as one jitted program.
 
 SURVEY.md §2.1 names the secp256k1 batch kernel as the stretch companion
 to the ed25519 north star; §7 stage 10 calls for mixed-key batches
@@ -9,6 +9,13 @@ joint radix-4 Straus double-scalar multiplication u1·G + u2·Q over 128
 flow. The wire format is compact (one u32[32,B] buffer of raw LE words
 plus an int32[B] flag vector — 132 bytes/sig); limb splitting and digit
 extraction run on device, mirroring ed25519_batch.unpack_wire.
+
+The program is XLA's but for its ladder: where secp_field._mul_form()
+takes the slice form (every platform but the CPU) the 128 digit steps
+are one Pallas kernel (secp_ladder)
+holding accumulator, table and digit rows in VMEM, inside the same
+jitted program; the CPU platform keeps them as a fori_loop of XLA point
+operations, limb for limb the same integers.
 
 Point arithmetic uses the Renes–Costello–Batina COMPLETE addition
 formulas for a = 0 curves (Algorithm 7; b3 = 3·7 = 21) in homogeneous
@@ -43,9 +50,10 @@ import numpy as np
 from jax import lax
 
 from cometbft_tpu.crypto.tpu import secp_field as fe
+from cometbft_tpu.crypto.tpu import secp_ladder
 from cometbft_tpu.crypto.tpu.secp_field import N, P
+from cometbft_tpu.crypto.tpu.secp_ladder import NUM_DIGITS
 
-NUM_DIGITS = 128  # 256 bits, 2-bit windows
 _GX = 0x79BE667EF9DCBBAC55A06295CE870B07029BFCDB2DCE28D959F2815B16F81798
 _GY = 0x483ADA7726A3C4655DA4FBFC0E1108A8FD17B448A68554199C47D08FFB10D4B8
 
@@ -213,7 +221,15 @@ def _verify_math(
     u1_digits: jnp.ndarray,  # int32[128,B]  u1 2-bit digits, MSB first
     u2_digits: jnp.ndarray,  # int32[128,B]  u2 2-bit digits, MSB first
 ) -> jnp.ndarray:
-    """bool[B]: R' = u1·G + u2·Q exists, is finite, and R'.x ≡ r mod n."""
+    """bool[B]: R' = u1·G + u2·Q exists, is finite, and R'.x ≡ r mod n.
+
+    Decompression, the 16-entry table (11 point additions), the final
+    inversion and the comparison are XLA operations; the ladder over the
+    128 digit rows is secp_ladder's pallas_call where secp_field's
+    product takes its slice form (every platform but the CPU), a
+    fori_loop of the same point additions on the CPU (XLA:CPU compiles
+    it for the tests; the Pallas ladder's unrolled steps are too large a
+    graph for that compiler)."""
     qy, on_curve = decompress(qx, q_parity)
     q: Point = (qx, qy, jnp.broadcast_to(_ONE, qx.shape))
 
@@ -231,17 +247,20 @@ def _verify_math(
                 pt = point_add(_G_POINTS[ds], q_pts[dh])
             entries.append(pt)
 
-    batch = qx.shape[1:]
-    ident: Point = tuple(
-        jnp.broadcast_to(c, (fe.NUM_LIMBS,) + batch) for c in _ID_POINT
-    )
+    if fe._mul_form() == "stack":
+        rx, ry, rz = secp_ladder.ladder(u1_digits + 4 * u2_digits, entries)
+    else:
+        batch = qx.shape[1:]
+        ident: Point = tuple(
+            jnp.broadcast_to(c, (fe.NUM_LIMBS,) + batch) for c in _ID_POINT
+        )
 
-    def body(i, acc: Point) -> Point:
-        acc = point_dbl(point_dbl(acc))
-        idx = u1_digits[i] + 4 * u2_digits[i]
-        return point_add(acc, _select_point(entries, idx))
+        def body(i, acc: Point) -> Point:
+            acc = point_dbl(point_dbl(acc))
+            idx = u1_digits[i] + 4 * u2_digits[i]
+            return point_add(acc, _select_point(entries, idx))
 
-    rx, ry, rz = lax.fori_loop(0, NUM_DIGITS, body, ident)
+        rx, ry, rz = lax.fori_loop(0, NUM_DIGITS, body, ident)
 
     finite = ~fe.is_zero(rz)
     x_aff = fe.mul(rx, fe.invert(rz))

@@ -267,6 +267,248 @@ class TestVectorReduction:
                               np.asarray(_per_row_fold_v(cols)))
 
 
+def _tiled(x, sub=8):
+    """[19, B] -> the kernel's field element: 19 numpy limbs [sub, 128]."""
+    x = np.asarray(x, np.int32)
+    return [x[i].reshape(sub, -1) for i in range(F.NUM_LIMBS)]
+
+
+def _untiled(limbs):
+    return np.stack([np.asarray(v).reshape(-1) for v in limbs])
+
+
+def _multiples_of_g(count, rng):
+    """k·G for k = 1..count in homogeneous (X:Y:Z) with a random Z each,
+    as three [19, count] limb arrays."""
+    pts, pt = [], None
+    for _ in range(count):
+        pt = secp256k1_batch._addp(pt, secp256k1_batch._G1)
+        pts.append(pt)
+    cols = []
+    for x, y in pts:
+        lam = int(rng.integers(1, 1 << 62)) * 0x9E3779B97F4A7C15 % F.P
+        cols.append([F.int_to_limbs(v * lam % F.P) for v in (x, y, 1)])
+    arr = np.asarray(cols, np.int32)  # [count, 3, 19]
+    return tuple(arr[:, k].T for k in range(3))
+
+
+_IDENTITY = tuple(np.asarray(F.const_fe(v)) for v in (0, 1, 0))
+
+
+class TestPallasLadder:
+    """secp_ladder, the ladder the chip runs: its product, point addition
+    and steps give secp_field's and secp256k1_batch's limbs EXACTLY. The
+    kernel body's functions run eagerly on numpy limbs (the same integer
+    operations; a whole addition under interpret mode or jitted on XLA:CPU
+    is too large a graph to compile); the product alone and the tiling
+    run as pallas_calls in interpret mode. The whole kernel's verdicts
+    are the mixed cell's on the chip."""
+
+    LANES = 1024  # one tile: 8 x 128
+
+    def _operands(self):
+        """[19, 1024] a, b: the representation's bounds, 0, 1, p - 1 and
+        values near 2^256 against each other, then random limbs."""
+        rng = np.random.default_rng(41)
+        special = list(_CORNERS.values()) + [
+            F.int_to_limbs(v) for v in (0, 1, F.P - 1, F.P, 2**256 - 1,
+                                        2**256 - 2**32, 2**255)]
+        a = rng.integers(_LOW, _TOP + 1, (self.LANES, F.NUM_LIMBS))
+        b = rng.integers(_LOW, _TOP + 1, (self.LANES, F.NUM_LIMBS))
+        k = len(special)
+        for i in range(k):
+            for j in range(k):
+                a[i * k + j], b[i * k + j] = special[i], special[j]
+        return a.T.astype(np.int32), b.T.astype(np.int32)
+
+    def test_the_product_kernel_is_secp_field_mul(self):
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+
+        from cometbft_tpu.crypto.tpu import secp_ladder as L
+
+        a, b = self._operands()
+
+        def kernel(a_ref, b_ref, out_ref):  # on _Limbs, as the ladder's
+            out = L.mul([L._Limb(a_ref[i]) for i in range(F.NUM_LIMBS)],
+                        [L._Limb(b_ref[i]) for i in range(F.NUM_LIMBS)])
+            for i, limb in enumerate(out):
+                out_ref[i] = limb.x
+
+        shape = (F.NUM_LIMBS, 8, 128)
+        got = pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),
+            interpret=True,
+        )(a.reshape(shape), b.reshape(shape))
+        want = np.asarray(F.mul(jnp.asarray(a), jnp.asarray(b)))
+        assert np.array_equal(np.asarray(got).reshape(want.shape), want)
+        # the product by b3, as the addition makes it: constant limbs
+        want = np.asarray(F.mul(jnp.asarray(a), F.const_fe(F.B3)))
+        assert np.array_equal(_untiled(L.mul(_tiled(a), L._B3)), want)
+
+    def test_the_point_addition_is_point_add(self):
+        import jax
+        import jax.numpy as jnp
+
+        from cometbft_tpu.crypto.tpu import secp_ladder as L
+
+        rng = np.random.default_rng(42)
+        group = self.LANES // 8
+        real = _multiples_of_g(2 * group, rng)
+        p = [np.empty((F.NUM_LIMBS, self.LANES), np.int32) for _ in range(3)]
+        q = [np.empty_like(c) for c in p]
+
+        def put(lanes, pt, qt):
+            for k in range(3):
+                p[k][:, lanes] = pt[k]
+                q[k][:, lanes] = qt[k]
+
+        rand = [rng.integers(_LOW, _TOP + 1, (F.NUM_LIMBS, group))
+                for _ in range(6)]
+        put(slice(0, group), rand[:3], rand[3:])
+        first = tuple(c[:, :group] for c in real)
+        second = tuple(c[:, group:] for c in real)
+        neg = (first[0], np.asarray(F.neg(jnp.asarray(first[1]))), first[2])
+        put(slice(group, 2 * group), first, second)
+        put(slice(2 * group, 3 * group), first, first)  # a doubling
+        put(slice(3 * group, 4 * group), first, neg)  # p + (-p)
+        put(slice(4 * group, 5 * group), _IDENTITY, first)
+        put(slice(5 * group, 6 * group), first, _IDENTITY)
+        put(slice(6 * group, 7 * group), _IDENTITY, _IDENTITY)
+        put(slice(7 * group, 8 * group), second, first)
+        got = L.point_add(tuple(_tiled(c) for c in p),
+                          tuple(_tiled(c) for c in q))
+        want = secp256k1_batch.point_add(tuple(map(jnp.asarray, p)),
+                                         tuple(map(jnp.asarray, q)))
+        for g, w in zip(got, want):
+            assert np.array_equal(_untiled(g), np.asarray(w))
+        # the kernel's form: on _Limbs, lax primitives, run op by op
+        with jax.disable_jit():
+            traced = L._traced_point_add(
+                tuple(list(map(jnp.asarray, _tiled(c))) for c in p),
+                tuple(list(map(jnp.asarray, _tiled(c))) for c in q))
+        for t, g in zip(traced, got):
+            assert np.array_equal(_untiled(t), _untiled(g))
+        z = np.asarray(F.to_canonical(jnp.asarray(_untiled(got[2]))))
+        assert not z[:, 3 * group:4 * group].any()  # the identity's Z
+        assert z[:, group:3 * group].any(axis=0).all()
+
+    def test_ladder_steps_are_the_xla_steps(self):
+        import jax.numpy as jnp
+
+        from cometbft_tpu.crypto.tpu import secp_ladder as L
+
+        rng = np.random.default_rng(43)
+        pool = _multiples_of_g(64, rng)
+        pick = rng.integers(0, 64, (17, self.LANES))
+        entries = [tuple(c[:, pick[e]] for c in pool) for e in range(16)]
+        acc = tuple(c[:, pick[16]] for c in pool)
+        digits = rng.integers(0, 16, (3, self.LANES)).astype(np.int32)
+        digits[:, :16] = np.arange(16)[None]  # every entry chosen
+        table = [[_tiled(c) for c in pt] for pt in entries]
+        xla_entries = [tuple(map(jnp.asarray, pt)) for pt in entries]
+        got, want = tuple(_tiled(c) for c in acc), tuple(map(jnp.asarray, acc))
+        for i in range(digits.shape[0]):
+            entry = L.select(lambda e, k, limb: table[e][k][limb],
+                             digits[i].reshape(8, 128))
+            got = L.ladder_step(
+                got, tuple([np.asarray(v) for v in c] for c in entry))
+            got = tuple([np.asarray(v) for v in c] for c in got)
+            want = secp256k1_batch.point_dbl(secp256k1_batch.point_dbl(want))
+            want = secp256k1_batch.point_add(
+                want, secp256k1_batch._select_point(
+                    xla_entries, jnp.asarray(digits[i])))
+            for g, w in zip(got, want):
+                assert np.array_equal(_untiled(g), np.asarray(w)), i
+
+    @pytest.mark.parametrize("lanes", [200, 1500])
+    def test_the_tiles_cover_every_lane(self, lanes, monkeypatch):
+        """The pallas_call's tiles, index maps, padding and slicing, with
+        the point arithmetic stood in for by a cheap limb-wise map (the
+        select stays): one narrow tile of the batch rounded up to 128
+        lanes (200: 256 lanes, 2 sublanes), or tiles of 1,024 (1,500: two,
+        the second padded)."""
+        from cometbft_tpu.crypto.tpu import secp_ladder as L
+
+        def stand_in(p, q):
+            return tuple([(a * 3 + b) & 0xFFFFF for a, b in zip(x, y)]
+                         for x, y in zip(p, q))
+
+        monkeypatch.setattr(L, "_traced_point_add", stand_in)
+        rng = np.random.default_rng(lanes)
+        idx = rng.integers(0, 16, (L.NUM_DIGITS, lanes)).astype(np.int32)
+        entries = [
+            tuple(np.asarray(F.const_fe(e * 3 + k + 1)) if e % 5 == 0 else
+                  rng.integers(0, 1 << 14, (F.NUM_LIMBS, lanes)).astype(
+                      np.int32) for k in range(3))
+            for e in range(16)]
+        got = L.ladder(idx, entries, interpret=True)
+        table = np.stack([np.stack([np.broadcast_to(c, (F.NUM_LIMBS, lanes))
+                                    for c in pt]) for pt in entries])
+        acc = np.stack([np.broadcast_to(c, (F.NUM_LIMBS, lanes))
+                        for c in _IDENTITY]).astype(np.int64)
+        for i in range(L.NUM_DIGITS):
+            acc = (acc * 3 + acc) & 0xFFFFF
+            acc = (acc * 3 + acc) & 0xFFFFF
+            acc = (acc * 3 + table[idx[i], :, :, np.arange(lanes)].transpose(
+                1, 2, 0)) & 0xFFFFF
+        for k in range(3):
+            assert np.array_equal(np.asarray(got[k]), acc[k]), k
+        assert L.tile_plan(lanes) == {200: (256, 2), 1500: (2048, 8)}[lanes]
+        assert L.tile_plan(64) == (128, 1) and L.tile_plan(1024) == (1024, 8)
+
+
+def test_the_platform_names_the_ladder(monkeypatch):
+    """Off the CPU platform the ladder is secp_ladder's pallas_call, fed
+    the combined digit row u1 + 4·u2 and the 16 table points; the CPU
+    platform's program keeps the XLA fori_loop of 128 steps. No option
+    chooses it. (The point and field arithmetic around the ladder is
+    stood in for: the choice is what is under test.)"""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from cometbft_tpu.crypto.tpu import secp_ladder
+
+    ladders, loops = [], []
+
+    def spy_ladder(idx, entries):
+        ladders.append((np.asarray(idx), entries))
+        return tuple(jnp.zeros_like(c) for c in entries[5])
+
+    def spy_loop(lo, hi, body, init):
+        loops.append(hi - lo)
+        return init
+
+    monkeypatch.setattr(secp_ladder, "ladder", spy_ladder)
+    monkeypatch.setattr(secp256k1_batch, "lax",
+                        SimpleNamespace(fori_loop=spy_loop))
+    monkeypatch.setattr(secp256k1_batch, "decompress",
+                        lambda qx, parity: (qx, parity == parity))
+    monkeypatch.setattr(secp256k1_batch, "point_add", lambda p, q: p)
+    monkeypatch.setattr(F, "invert", lambda x: x)
+    monkeypatch.setattr(F, "mul", lambda a, b: a)
+    rng = np.random.default_rng(44)
+    b = 8
+    fe_in = jnp.asarray(rng.integers(0, 1 << 14, (F.NUM_LIMBS, b)), jnp.int32)
+    u1, u2 = (jnp.asarray(rng.integers(0, 4, (secp256k1_batch.NUM_DIGITS, b)),
+                          jnp.int32) for _ in range(2))
+    args = (fe_in, jnp.zeros(b, jnp.int32), fe_in, fe_in,
+            jnp.zeros(b, bool), u1, u2)
+    for backend, pallas in (("cpu", False), ("tpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        monkeypatch.setenv("CBFT_TPU_MUL", "f32")  # ed25519's alone
+        assert (F._mul_form() == "stack") is pallas
+        secp256k1_batch._verify_math(*args)
+        assert (len(ladders), loops) == (int(pallas),
+                                         [secp256k1_batch.NUM_DIGITS])
+    idx, entries = ladders[0]
+    assert np.array_equal(idx, np.asarray(u1) + 4 * np.asarray(u2))
+    assert len(entries) == 16 and all(len(pt) == 3 for pt in entries)
+
+
 class TestSecpVerifyParity:
     @pytest.fixture(scope="class")
     def keys(self):
