@@ -459,8 +459,8 @@ def _stage_p50():
 
 
 def _stage_variants():
-    """A/B matrix on the live platform: CBFT_TPU_MUL forms, device-side
-    hashing, and the shard_map mega-commit."""
+    """A/B matrix on the live platform: CBFT_TPU_MUL forms and the
+    shard_map mega-commit."""
     _set_cache()
     import jax
 
@@ -478,13 +478,6 @@ def _stage_variants():
         print(json.dumps(out), flush=True)
     os.environ.pop("CBFT_TPU_MUL", None)
     jax.clear_caches()
-    os.environ["CBFT_TPU_HASH"] = "device"
-    try:
-        out["device_hash_sigs_per_sec"] = round(_time_verify_batch(*batch), 1)
-    except Exception as exc:  # noqa: BLE001
-        out["device_hash_sigs_per_sec"] = f"error: {exc}"[:120]
-    os.environ.pop("CBFT_TPU_HASH", None)
-    print(json.dumps(out), flush=True)
     try:
         out["sharded_10k_commit"] = _sharded_mega_commit()
     except Exception as exc:  # noqa: BLE001
@@ -518,15 +511,15 @@ def _stage_variants():
 
 def _stage_breakdown():
     """Where a batch-4096 verify spends its time: host packing (incl.
-    SHA-512 in host-hash mode), host→device transfer, and device compute
-    split into decompress+table vs the Straus loop (jitted separately),
-    for both the legacy u32 word wire and the compact uint8 wire.
-    The separated pieces don't add exactly to the fused kernel (fusion
-    across the split is lost) but bound each phase honestly. Every
-    stage reports the MEDIAN of 5 timed reps (after a warm rep): a
-    single-run sample at the ~0.1 ms scale jittered enough to report a
-    negative Straus-loop estimate in round 5, so the derived loop time
-    is a clamped-at-zero difference of medians."""
+    the host SHA-512 challenge), host→device transfer, and device
+    compute split into decompress+table vs the Straus loop (jitted
+    separately), on the compact uint8 wire. The separated pieces don't
+    add exactly to the fused kernel (fusion across the split is lost)
+    but bound each phase honestly. Every stage reports the MEDIAN of 5
+    timed reps (after a warm rep): a single-run sample at the ~0.1 ms
+    scale jittered enough to report a negative Straus-loop estimate in
+    round 5, so the derived loop time is a clamped-at-zero difference
+    of medians."""
     _set_cache()
     import statistics
 
@@ -548,25 +541,13 @@ def _stage_breakdown():
     pks, msgs, sigs = _make_batch(4096)
     n = len(pks)
 
-    out["host_prepare_ms"] = med_ms(
-        lambda: eb.prepare_batch(pks, msgs, sigs)
-    )
     out["host_prepare_compact_ms"] = med_ms(
         lambda: eb.prepare_batch_compact(pks, msgs, sigs)
     )
     print(json.dumps(out), flush=True)
 
-    (*packed, _valid) = eb.prepare_batch(pks, msgs, sigs)
     (wire_c, _valid_c) = eb.prepare_batch_compact(pks, msgs, sigs)
-    out["wire_bytes_per_lane"] = round(
-        sum(a.nbytes for a in packed) / n, 1
-    )
     out["compact_wire_bytes_per_lane"] = round(wire_c.nbytes / n, 1)
-    out["transfer_ms"] = med_ms(
-        lambda: jax.block_until_ready(
-            [jax.device_put(jnp.asarray(a)) for a in packed]
-        )
-    )
     out["transfer_compact_ms"] = med_ms(
         lambda: jax.block_until_ready(
             jax.device_put(jnp.asarray(wire_c))
@@ -574,12 +555,13 @@ def _stage_breakdown():
     )
     print(json.dumps(out), flush=True)
 
-    dev = [jax.device_put(jnp.asarray(a)) for a in packed]
     dev_c = jax.device_put(jnp.asarray(wire_c))
 
     @jax.jit
     def decompress_and_table(wire):
-        ay, a_sign, _r_y, _r_sign, _s, _h = eb.unpack_wire(wire)
+        ay, a_sign, _r_y, _r_sign, _s, _h = eb.unpack_wire(
+            eb.bytes_to_words(wire)
+        )
         x, ok = eb.decompress(ay, a_sign)
         nx = eb.fe.neg(x)
         neg_a = (nx, ay, jnp.broadcast_to(eb._ONE_FE, ay.shape), eb.fe.mul(nx, ay))
@@ -587,56 +569,21 @@ def _stage_breakdown():
         a3 = eb.point_add(a2, neg_a)
         return ok, a2[0], a3[0]
 
-    (wire,) = dev
     med_decomp = med_ms(
-        lambda: jax.block_until_ready(decompress_and_table(wire))
+        lambda: jax.block_until_ready(decompress_and_table(dev_c))
     )
     out["device_decompress_table_ms"] = med_decomp
     print(json.dumps(out), flush=True)
 
     med_full = med_ms(
-        lambda: jax.block_until_ready(eb.verify_kernel(*dev))
-    )
-    out["device_full_kernel_ms"] = med_full
-    out["device_full_kernel_compact_ms"] = med_ms(
         lambda: jax.block_until_ready(eb.verify_kernel_compact(dev_c))
     )
+    out["device_full_kernel_compact_ms"] = med_full
     # clamped difference of medians: the two programs are jitted
     # separately, so at TPU speeds the subtraction can go (slightly)
     # negative — that means "decompress-dominated", not negative time
     out["device_straus_loop_ms_est"] = round(
         max(0.0, med_full - med_decomp), 2
-    )
-    print(json.dumps(out), flush=True)
-
-    # device-hash pipeline, called explicitly (no env gating needed)
-    out["host_prepare_devicehash_ms"] = med_ms(
-        lambda: eb.prepare_batch_device_hash(pks, msgs, sigs)
-    )
-    out["host_prepare_devicehash_compact_ms"] = med_ms(
-        lambda: eb.prepare_batch_device_hash_compact(pks, msgs, sigs)
-    )
-    (*packed_dh, _valid) = eb.prepare_batch_device_hash(pks, msgs, sigs)
-    wire_dc, msg_dc, mlen_dc, _valid = eb.prepare_batch_device_hash_compact(
-        pks, msgs, sigs
-    )
-    out["devicehash_wire_bytes_per_lane"] = round(
-        sum(a.nbytes for a in packed_dh) / n, 1
-    )
-    out["devicehash_compact_wire_bytes_per_lane"] = round(
-        (wire_dc.nbytes + msg_dc.nbytes + mlen_dc.nbytes) / n, 1
-    )
-    dev_dh = [jax.device_put(jnp.asarray(a)) for a in packed_dh]
-    out["device_full_kernel_devicehash_ms"] = med_ms(
-        lambda: jax.block_until_ready(eb.verify_full_kernel(*dev_dh))
-    )
-    dev_dc = [
-        jax.device_put(jnp.asarray(a)) for a in (wire_dc, msg_dc, mlen_dc)
-    ]
-    out["device_full_kernel_devicehash_compact_ms"] = med_ms(
-        lambda: jax.block_until_ready(
-            eb.verify_full_kernel_compact(*dev_dc)
-        )
     )
     print(json.dumps(out), flush=True)
 
@@ -659,7 +606,7 @@ def _sharded_mega_commit():
     n = 10_000
     pad = 10_240  # multiple of 8 devices × 128 lanes
     pks, msgs, sigs = _make_batch(n)
-    (*packed, valid) = ed25519_batch.prepare_batch(pks, msgs, sigs)
+    (*packed, valid) = ed25519_batch.prepare_batch_compact(pks, msgs, sigs)
     assert valid.all()
 
     def pad_to(a):
@@ -672,7 +619,7 @@ def _sharded_mega_commit():
         for a in packed
     )
     step = jax.jit(
-        ed25519_batch._verify_core,
+        ed25519_batch._verify_core_compact,
         in_shardings=shardings,
         out_shardings=NamedSharding(mesh, PS("batch")),
     )

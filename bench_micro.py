@@ -791,72 +791,6 @@ def bench_decisions() -> dict:
     }
 
 
-def bench_pack() -> dict:
-    """Host cost of the compact uint8 pack vs the u32 word pack it
-    replaces (crypto/tpu/ed25519_batch.py), asserted on CPU-only CI —
-    the ISSUE-13 acceptance bound that moving limb unpacking on-device
-    must not sneak extra host time into prepare:
-
-    - both packs run over the same 4096-lane batch, best-of-5 per mode,
-      interleaved so machine noise hits both equally; the timed region
-      is the full prepare (parse + host SHA-512 + pack) because that is
-      the phase the wire ledger attributes as ``pack``;
-    - the compact prepare must cost no more than the word prepare plus
-      10% measurement headroom — structurally it does strictly less
-      work (one transposed byte copy per plane, no u32 word views);
-    - both wires must decode to identical verdict inputs (the parity
-      property the dedicated tests cover bit-exactly; here a cheap
-      reconstruction check guards the bench itself against drift).
-
-    ``pack_margin_pct`` is ``10.0 − overhead_pct`` so the harness's
-    ">0" invariant IS the compact-no-slower assertion.
-    """
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-    import numpy as np
-
-    from bench import _make_batch
-    from cometbft_tpu.crypto.tpu import ed25519_batch as eb
-
-    n = 4096
-    pks, msgs, sigs = _make_batch(n)
-
-    words_s, compact_s = [], []
-    for _ in range(5):  # interleave so drift hits both modes equally
-        t0 = time.perf_counter()
-        wire_w, valid_w = eb.prepare_batch(pks, msgs, sigs)
-        words_s.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        wire_c, valid_c = eb.prepare_batch_compact(pks, msgs, sigs)
-        compact_s.append(time.perf_counter() - t0)
-    base, comp = min(words_s), min(compact_s)
-
-    # parity guard: the compact rows must carry the exact word wire
-    r = wire_c.astype(np.uint32)
-    rebuilt = (
-        r[0::4] | (r[1::4] << 8) | (r[2::4] << 16) | (r[3::4] << 24)
-    )
-    if not (rebuilt == wire_w).all() or not (valid_w == valid_c).all():
-        raise AssertionError("compact wire does not reconstruct the word wire")
-
-    overhead_pct = (comp - base) / base * 100.0
-    if overhead_pct >= 10.0:
-        raise AssertionError(
-            f"compact pack {overhead_pct:.1f}% slower than the word "
-            f"pack it replaces (words={base * 1e3:.2f}ms "
-            f"compact={comp * 1e3:.2f}ms)"
-        )
-    return {
-        "words_pack_ms": round(base * 1e3, 2),
-        "compact_pack_ms": round(comp * 1e3, 2),
-        "words_bytes_per_lane": round(wire_w.nbytes / n, 1),
-        "compact_bytes_per_lane": round(wire_c.nbytes / n, 1),
-        "pack_margin_pct": round(10.0 - overhead_pct, 2),
-    }
-
-
 def bench_challenges() -> dict:
     """The challenge call of a launch's pack (native.ed25519_challenges,
     PR 37) by lanes and threads: the probe that fixed
@@ -922,7 +856,6 @@ SECTIONS = {
     "challenges": bench_challenges,
     "coldboot": bench_coldboot,
     "decisions": bench_decisions,
-    "pack": bench_pack,
     "ed25519": bench_ed25519,
     "validator_set": bench_validator_set,
     "light": bench_light,

@@ -443,8 +443,7 @@ def _await_device_plane(node, cfg, min_height: int, t0: float) -> dict:
         say("warm boot: %d executables, %.0f s compiling; calibration: %s" % (
             len(warmed), sum(o["compile_s"] for o in warmed),
             json.dumps({k: table.get(k) for k in (
-                "ed25519", "ed25519_min_batch", "hash",
-                "hash_device_min_batch", "merkle_min_leaves")}),
+                "ed25519", "ed25519_min_batch", "merkle_min_leaves")}),
         ))
     books = Books(node)
     routed = books.decision_lanes()
@@ -462,9 +461,8 @@ def _await_device_plane(node, cfg, min_height: int, t0: float) -> dict:
         "warm_boot": warmed,
         "calibration": {
             k: table.get(k) for k in (
-                "ed25519", "ed25519_min_batch", "hash",
-                "hash_device_min_batch", "merkle", "merkle_min_leaves",
-                "sharded", "compile",
+                "ed25519", "ed25519_min_batch", "merkle",
+                "merkle_min_leaves", "sharded", "compile",
             )
         },
         # the canary's lanes and the calibration sweep's: what was on the

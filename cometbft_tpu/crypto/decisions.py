@@ -11,8 +11,8 @@ taken. This module closes that loop: every coalesced flush that reaches
   fraction, per-device breaker states, keystore residency, qos class
   mix;
 * per-candidate **predicted cost** for the cpu / single / sharded
-  rungs (plus the indexed-keystore and device-hash sub-routes when the
-  wire ledger has a profile for them);
+  rungs (plus the indexed-keystore sub-route when the wire ledger has
+  a profile for it);
 * the route actually **taken** (exactly what the scheduler's
   ``_note_route`` counted, so per-route decision counts reconcile with
   ``queue_snapshot()['routes']`` to the unit) and the **final** route
@@ -67,9 +67,9 @@ SUBSYSTEM = "verify_route"
 
 # The three first-class routing rungs every decision prices.
 ROUTES = ("cpu", "single", "sharded")
-# PR 13 sub-routes priced opportunistically when the wire ledger has
-# seen them (they only exist on the device plane).
-SUB_ROUTES = ("indexed", "device_hash")
+# Sub-routes priced opportunistically when the wire ledger has seen
+# them (they only exist on the device plane).
+SUB_ROUTES = ("indexed",)
 
 DEFAULT_WINDOW = 64        # rolling decision window for MAPE / regret rate
 DEFAULT_MAPE_TRIP = 2.0    # windowed MAPE above this trips the watchdog

@@ -1525,12 +1525,10 @@ class VerifyScheduler(BaseService):
           AND every pubkey of the flush resident in one fresh keystore
           entry (keystore.covers; sys.modules-guarded so CPU-only nodes
           never import the TPU package here).
-        * device_hash — never a verify-flush candidate (it serves the
-          hash plane); priced for observability, filtered here.
         """
         feasible = {
             "cpu": True, "single": False, "sharded": False,
-            "indexed": False, "device_hash": False,
+            "indexed": False,
         }
         if self.spec.name == "cpu" or self._floor_keeps_on_host(items):
             return feasible

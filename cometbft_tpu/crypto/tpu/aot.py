@@ -96,20 +96,18 @@ def unwrap_kernel(kernel) -> Any:
 
 class _KernelReg:
     """One explicitly-registered kernel: its stable name, the warmup
-    shape template (bucket -> arg (shape, dtype) list), the default
-    donation spec the dispatch layer uses for it, and whether the
-    process's current routing can dispatch it at all."""
+    shape template (bucket -> arg (shape, dtype) list) and the default
+    donation spec the dispatch layer uses for it."""
 
     __slots__ = ("name", "kernel", "bucket_shapes", "donate_from",
-                 "reachable", "forced", "launch")
+                 "forced", "launch")
 
-    def __init__(self, name, kernel, bucket_shapes, donate_from, reachable,
-                 forced, launch):
+    def __init__(self, name, kernel, bucket_shapes, donate_from, forced,
+                 launch):
         self.name = name
         self.kernel = kernel
         self.bucket_shapes = bucket_shapes
         self.donate_from = donate_from
-        self.reachable = reachable
         self.forced = forced
         self.launch = launch
 
@@ -119,7 +117,6 @@ def register_kernel(
     kernel,
     bucket_shapes: Optional[Callable[[int], List[Tuple[tuple, Any]]]] = None,
     donate_from: int = 0,
-    reachable: Optional[Callable[[], bool]] = None,
     forced: bool = False,
     launch: Optional[int] = None,
 ) -> None:
@@ -128,9 +125,6 @@ def register_kernel(
     (shape, dtype) list for a padded batch bucket. Registered kernels
     are what ``warmup_plan`` pre-compiles; registration holds a strong
     reference, so the name can never be re-assigned by id reuse.
-    ``reachable()`` says whether the routing in force can dispatch the
-    kernel (a wire format or hash placement nothing selects is not
-    worth a compile per bucket — ~45 s each on a v5e); omitted = yes.
     ``forced`` marks the kernel the supervisor's canary and triage
     dispatch BELOW the routing floor (force_device): its smallest bucket
     is warmed first, ahead of the ladder. ``launch`` is the size, in
@@ -141,8 +135,7 @@ def register_kernel(
     inner = unwrap_kernel(kernel)
     with _name_mtx:
         _registered[name] = _KernelReg(
-            name, kernel, bucket_shapes, donate_from, reachable, forced,
-            launch,
+            name, kernel, bucket_shapes, donate_from, forced, launch,
         )
         _name_by_id[id(inner)] = (name, None, inner)
 
@@ -189,22 +182,20 @@ CURVES = ("ed25519", "secp256k1", "sr25519")
 def registered_kernels(
     key_types: Sequence[str] = DEFAULT_KEY_TYPES,
 ) -> List[_KernelReg]:
-    """Warmup-eligible registrations: those with a shape template that
-    the routing in force can reach, but a curve's kernel (its name
-    starts with the key type) whose key type is not among ``key_types``:
-    no validator key of the node's chain can reach it, and it is not
-    worth a compile per bucket."""
+    """Warmup-eligible registrations: those with a shape template, but a
+    curve's kernel (its name starts with the key type) whose key type
+    is not among ``key_types``: no validator key of the node's chain
+    can reach it, and it is not worth a compile per bucket."""
 
     def admitted(name: str) -> bool:
         curve = name.split(".", 1)[0]
         return curve not in CURVES or curve in key_types
 
     with _name_mtx:
-        regs = [
+        return [
             r for r in _registered.values()
             if r.bucket_shapes and admitted(r.name)
         ]
-    return [r for r in regs if r.reachable is None or r.reachable()]
 
 
 # --------------------------------------------------------------------------

@@ -101,17 +101,6 @@ def ed25519_min_batch() -> Optional[int]:
     return _floor(load_table(), "ed25519_min_batch")
 
 
-def hash_device_min_batch() -> Optional[int]:
-    """Measured batch size above which on-device SHA-512 hashing
-    (verify_full_kernel_compact — message bytes ship raw, the padding
-    and digest run fused with the verify) beats host hashing, or None
-    when unmeasured / the device never won. hash_route() then keeps
-    SHA-512 on the host — round 5 measured the device-hash path LOSING
-    (38.8k vs 75.8k sigs/s at 16k), so an unproven crossover must never
-    open that route."""
-    return _floor(load_table(), "hash_device_min_batch")
-
-
 def _crossover(points: Dict[int, Tuple[float, float]]) -> Optional[int]:
     """Smallest measured size from which the device wins at EVERY
     larger measured size too — a single lucky window in the middle of
@@ -131,8 +120,7 @@ def _sweep(sizes, measure) -> Dict[int, Tuple[float, float]]:
     down, stopping after the first size at which the device loses:
     _crossover reads nothing below a loss, and every further point is a
     bucket the warm boot may not have compiled (~50-70 s apiece cold on
-    a v5e — the device-hash kernels are never in the ladder until this
-    sweep says they win)."""
+    a v5e)."""
     points: Dict[int, Tuple[float, float]] = {}
     for n in sorted(sizes, reverse=True):
         device_ms, other_ms = points[n] = measure(n)
@@ -212,28 +200,6 @@ def run_calibration(
     }
     table["ed25519_min_batch"] = _crossover(ed_pts)
 
-    # host-vs-device hashing crossover: same sizes, same dispatch route,
-    # only the SHA-512 placement differs — hash_route() consults the
-    # result instead of trusting an env flag. Convention matches
-    # _crossover: "device" = on-device hashing, "cpu" = host hashing.
-    def hash_point(n):
-        pks, msgs, sigs = [pk.bytes()] * n, [msg] * n, [sig] * n
-        return tuple(
-            _best_ms(
-                lambda: ed25519_batch.verify_batch(
-                    pks, msgs, sigs, hash=placement
-                ),
-                reps,
-            )
-            for placement in ("device", "host")
-        )
-
-    hash_pts = _sweep(ed_sizes, hash_point)
-    table["hash"] = {
-        str(n): {"device_ms": round(d, 2), "host_ms": round(c, 2)}
-        for n, (d, c) in hash_pts.items()
-    }
-    table["hash_device_min_batch"] = _crossover(hash_pts)
     return table
 
 
@@ -342,8 +308,7 @@ def route_cost_seed_ms(route: str, bucket: int) -> Optional[float]:
     ledger's prediction ladder (self EWMA → wire CostProfile → this).
     Answers from the measured per-size points: ``cpu``/``single`` from
     the ed25519 sweep, ``sharded`` from the current topology's sharded
-    sweep, ``device_hash`` from the hash-placement sweep. The indexed
-    sub-route has no calibration sweep (it only exists against a live
+    sweep. The indexed sub-route has no calibration sweep (it only exists against a live
     resident key store), so it prices None until observed live."""
     table = load_table()
     if not table:
@@ -358,11 +323,6 @@ def route_cost_seed_ms(route: str, bucket: int) -> Optional[float]:
             return None
         key = "cpu_ms" if route == "cpu" else "device_ms"
         return _nearest_scaled_ms(points, key, bucket)
-    if route == "device_hash":
-        points = table.get("hash")
-        if not isinstance(points, dict):
-            return None
-        return _nearest_scaled_ms(points, "device_ms", bucket)
     if route == "sharded":
         sharded = table.get("sharded")
         if not isinstance(sharded, dict):

@@ -21,16 +21,6 @@ ops — rather than a per-lane gather, which XLA lowers to a (slow,
 serializing) dynamic-gather on TPU. Everything is uniform across the
 batch — no data-dependent control flow, ideal for SIMD lanes.
 
-Two hashing modes (CBFT_TPU_HASH):
-  * ``host`` — h = SHA-512(R ‖ A ‖ M) mod L per signature on the host
-    while packing (one native call a launch, _challenge_scalars); the
-    device runs only the group math.
-  * ``device`` — the full pipeline is ONE dispatch: batched SHA-512
-    (sha512.py, 64-bit lanes in 2×u32), exact mod-L reduction
-    (scalar.sc_reduce — ref10 sc_reduce semantics, required for parity on
-    torsioned keys), 2-bit digit extraction, then the Straus loop. The
-    host's per-signature work drops to pure byte packing.
-
 Semantics contract: accept/reject is bit-identical to the CPU backend
 (OpenSSL via `cryptography`, itself matching ref10):
   * cofactorless check: encode([s]B + [h](-A)) must equal R byte-for-byte;
@@ -41,7 +31,10 @@ Semantics contract: accept/reject is bit-identical to the CPU backend
   * x = 0 with sign bit set yields -0 = 0 (no special rejection), as ref10;
   * non-canonical R never matches (raw-limb compare = byte compare).
 
-SHA-512(R ‖ A ‖ M) mod L runs host-side (C): messages are short and
+One program verifies a keyed batch (verify_batch): the compact wire,
+u8[128,B] rows of A, R, S and h = SHA-512(R ‖ A ‖ M) mod L as raw
+little-endian bytes. The challenge runs host-side while packing (one
+native C call a launch, _challenge_scalars): messages are short and
 variable-length, hashing is ~1% of the work; the 253-doubling scalar
 multiplication — >99% of the FLOPs — is what the TPU executes.
 """
@@ -254,22 +247,16 @@ def bytes_to_words(rows: jnp.ndarray) -> jnp.ndarray:
     return r[0::4] | (r[1::4] << 8) | (r[2::4] << 16) | (r[3::4] << 24)
 
 
-def _unpack_points_scalar(wire: jnp.ndarray):
-    """Rows 0:24 of the wire (A, R, S — shared between the host-hash and
-    device-hash layouts) → (ay, a_sign, r_y, r_sign, s_digits)."""
+def unpack_wire(wire: jnp.ndarray):
+    """u32[32,B] wire (rows 0:8 A, 8:16 R, 16:24 S, 24:32 h, all LE
+    words) → the six unpacked kernel inputs."""
     pk_w, r_w = wire[0:8], wire[8:16]
     ay = unpack_fe_limbs(pk_w)
     a_sign = (pk_w[7] >> 31).astype(jnp.int32)
     r_y = unpack_fe_limbs(r_w)
     r_sign = (r_w[7] >> 31).astype(jnp.int32)
     s_digits = unpack_digits(wire[16:24])
-    return ay, a_sign, r_y, r_sign, s_digits
-
-
-def unpack_wire(wire: jnp.ndarray):
-    """u32[32,B] wire (rows 0:8 A, 8:16 R, 16:24 S, 24:32 h, all LE
-    words) → the six unpacked kernel inputs."""
-    return _unpack_points_scalar(wire) + (unpack_digits(wire[24:32]),)
+    return ay, a_sign, r_y, r_sign, s_digits, unpack_digits(wire[24:32])
 
 
 def _verify_unpacked(
@@ -326,16 +313,6 @@ def _verify_unpacked(
     return y_eq & sign_eq & ok
 
 
-def _verify_core(wire: jnp.ndarray) -> jnp.ndarray:
-    """bool[B] from the u32[32,B] wire buffer (host-hash mode). ONE input
-    array per dispatch: 128 bytes/sig on the link instead of the 1,160
-    bytes/sig the pre-split limb+digit arrays cost."""
-    return _verify_unpacked(*unpack_wire(wire))
-
-
-verify_kernel = jax.jit(_verify_core)
-
-
 def _verify_core_compact(wire: jnp.ndarray) -> jnp.ndarray:
     """bool[B] from the COMPACT u8[128,B] wire (rows 0:32 A, 32:64 R,
     64:96 S, 96:128 h — raw little-endian bytes). The whole decompress
@@ -346,51 +323,6 @@ def _verify_core_compact(wire: jnp.ndarray) -> jnp.ndarray:
 
 
 verify_kernel_compact = jax.jit(_verify_core_compact)
-
-
-@jax.jit
-def verify_full_kernel(
-    wire: jnp.ndarray,  # u32[24,B]  rows 0:8 A, 8:16 R, 16:24 S (LE words)
-    msg_hi: jnp.ndarray,  # u32[n_blocks,16,B]  padded R‖A‖M, BE word hi
-    msg_lo: jnp.ndarray,  # u32[n_blocks,16,B]
-    msg_nblocks: jnp.ndarray,  # int32[B]  live block count per lane
-) -> jnp.ndarray:
-    """The whole verification — SHA-512, mod-L, digits, Straus — as one
-    device program: no host work between hash and group math, no extra
-    dispatches (CBFT_TPU_HASH=device path)."""
-    from cometbft_tpu.crypto.tpu import scalar, sha512
-
-    ay, a_sign, r_y, r_sign, s_digits = _unpack_points_scalar(wire)
-    dig_hi, dig_lo = sha512.sha512_blocks(msg_hi, msg_lo, msg_nblocks)
-    h = scalar.sc_reduce(scalar.digest_to_limbs(dig_hi, dig_lo))
-    h_digits = scalar.digits_msb_first(h)
-    return _verify_unpacked(ay, a_sign, r_y, r_sign, s_digits, h_digits)
-
-
-@jax.jit
-def verify_full_kernel_compact(
-    wire: jnp.ndarray,  # u8[96,B]  rows 0:32 A, 32:64 R, 64:96 S (raw bytes)
-    msg: jnp.ndarray,  # u8[MP,B]  raw message bytes, zero-filled past mlen
-    mlen: jnp.ndarray,  # int32[B]  live message bytes per lane
-) -> jnp.ndarray:
-    """The compact device-hash pipeline: SHA-512 PADDING and
-    compression, mod-L, digit windowing, decompress, and the Straus
-    loop — one fused program from raw bytes. The 64-byte hash prefix
-    R ‖ A is reassembled from the wire on device, so the link never
-    ships those bytes twice and the message plane carries padded raw
-    uint8 instead of pre-split u32 block words (128 B per block per
-    lane → the actual message length rounded to the block grid)."""
-    from cometbft_tpu.crypto.tpu import scalar, sha512
-
-    words = bytes_to_words(wire)  # u32[24,B]
-    ay, a_sign, r_y, r_sign, s_digits = _unpack_points_scalar(words)
-    prefix = jnp.concatenate([wire[32:64], wire[0:32]], axis=0)  # R ‖ A
-    max_blocks = (64 + msg.shape[0]) // 128  # staging keeps this exact
-    hi, lo, n_live = sha512.blocks_from_bytes(prefix, msg, mlen, max_blocks)
-    dig_hi, dig_lo = sha512.sha512_blocks(hi, lo, n_live)
-    h = scalar.sc_reduce(scalar.digest_to_limbs(dig_hi, dig_lo))
-    h_digits = scalar.digits_msb_first(h)
-    return _verify_unpacked(ay, a_sign, r_y, r_sign, s_digits, h_digits)
 
 
 def _verify_core_indexed(
@@ -560,38 +492,10 @@ def _challenge_scalars_py(pk_arr, sig_arr, msgs, valid) -> np.ndarray:
     return h_arr
 
 
-def prepare_batch(
-    pub_keys: Sequence[bytes],
-    msgs: Sequence[bytes],
-    sigs: Sequence[bytes],
-):
-    """Host-side packing for the host-hash mode → (wire u32[32,B], valid).
-
-    The wire buffer carries the raw little-endian words of A, R, S and
-    h = SHA-512(R ‖ A ‖ M) mod L; limb splitting and digit extraction
-    moved on-device (unpack_wire) so the link carries 128 bytes/sig,
-    not 1,160."""
-    pk_arr, sig_arr, valid = _parse_inputs(pub_keys, sigs)
-    h_arr = _challenge_scalars(pk_arr, sig_arr, msgs, valid)
-
-    wire = np.concatenate(
-        [
-            _le_words(pk_arr),
-            _le_words(sig_arr[:, :32]),
-            _le_words(sig_arr[:, 32:]),
-            _le_words(h_arr),
-        ],
-        axis=0,
-    )
-    return wire, valid
-
-
 def pack_compact_rows(*row_arrs: np.ndarray) -> np.ndarray:
     """Stack u8[B,k] byte arrays into the compact byte-major wire
     u8[Σk,B]: one preallocated buffer and one transposed copy per
-    plane — no word views, no concatenate — which is why the compact
-    pack can never cost more host time than the word pack it replaces
-    (bench_micro `pack` asserts this on CPU CI)."""
+    plane — no word views, no concatenate."""
     n = row_arrs[0].shape[0]
     rows = sum(a.shape[1] for a in row_arrs)
     wire = np.empty((rows, n), np.uint8)
@@ -607,12 +511,10 @@ def prepare_batch_compact(
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
 ):
-    """Host-side packing for the compact host-hash wire →
-    (wire u8[128,B], valid): rows 0:32 A, 32:64 R, 64:96 S,
-    96:128 h, raw little-endian bytes. Bit-identical inputs to
-    prepare_batch's u32 wire (the kernel's bytes_to_words prologue
-    reproduces the exact words), shipped without any host word
-    packing."""
+    """Host-side packing for the compact wire → (wire u8[128,B],
+    valid): rows 0:32 A, 32:64 R, 64:96 S, 96:128 h, raw
+    little-endian bytes. The kernel's bytes_to_words prologue makes
+    the u32 words on device; the host packs no words."""
     pk_arr, sig_arr, valid = _parse_inputs(pub_keys, sigs)
     h_arr = _challenge_scalars(pk_arr, sig_arr, msgs, valid)
     wire = pack_compact_rows(
@@ -621,148 +523,36 @@ def prepare_batch_compact(
     return wire, valid
 
 
-def prepare_batch_device_hash(
-    pub_keys: Sequence[bytes],
-    msgs: Sequence[bytes],
-    sigs: Sequence[bytes],
-):
-    """Host-side packing for the device-hash mode: no hashing at all on
-    the host — R ‖ A ‖ M is padded into SHA-512 blocks (bulk numpy) and
-    the kernel does the rest. → (wire u32[24,B], msg_hi, msg_lo,
-    nblocks, valid)."""
-    from cometbft_tpu.crypto.tpu import sha512
-
-    pk_arr, sig_arr, valid = _parse_inputs(pub_keys, sigs)
-    hash_msgs = [
-        sig_arr[i, :32].tobytes() + pk_arr[i].tobytes() + bytes(msgs[i])
-        for i in range(len(pub_keys))
-    ]
-    msg_hi, msg_lo, nblocks = sha512.pad_ragged_np(hash_msgs)
-    wire = np.concatenate(
-        [
-            _le_words(pk_arr),
-            _le_words(sig_arr[:, :32]),
-            _le_words(sig_arr[:, 32:]),
-        ],
-        axis=0,
-    )
-    return wire, msg_hi, msg_lo, nblocks, valid
-
-
-def prepare_batch_device_hash_compact(
-    pub_keys: Sequence[bytes],
-    msgs: Sequence[bytes],
-    sigs: Sequence[bytes],
-):
-    """Compact device-hash packing → (wire u8[96,B], msg u8[MP,B],
-    mlen int32[B], valid). Three wins over prepare_batch_device_hash:
-    the wire is raw bytes (no word packing), the 64-byte R ‖ A hash
-    prefix is NOT re-shipped with the message (the kernel rebuilds it
-    from the wire), and SHA padding happens on device — the message
-    plane is one bulk-scattered uint8 block instead of per-lane padded
-    u32 hi/lo word planes, with no per-message Python concatenation."""
-    from cometbft_tpu.crypto.tpu import sha512
-
-    pk_arr, sig_arr, valid = _parse_inputs(pub_keys, sigs)
-    wire = pack_compact_rows(pk_arr, sig_arr[:, :32], sig_arr[:, 32:])
-    msg, mlen = sha512.stage_ragged_np(msgs, prefix_len=64)
-    return wire, msg, mlen, valid
-
-
-def hash_mode() -> str:
-    """CBFT_TPU_HASH resolution: ``host`` and ``device`` pin the hash
-    placement for A/B runs; ``auto`` (the default) lets the calibration
-    crossover measured at warmup decide per dispatch size
-    (hash_route)."""
-    import os
-
-    mode = os.environ.get("CBFT_TPU_HASH", "auto")
-    if mode not in ("host", "device", "auto"):
-        raise ValueError(
-            f"unknown CBFT_TPU_HASH={mode!r}; choose from "
-            "['auto', 'device', 'host']"
-        )
-    return mode
-
-
-def hash_route(n: int) -> str:
-    """Where h = SHA-512(R ‖ A ‖ M) runs for an n-lane dispatch:
-    the env pin when set, else the measured crossover
-    (calibrate.hash_device_min_batch — recorded by the warmup
-    calibration sweep). Unmeasured (fresh node, CPU CI) → host: the
-    round-5 probe showed the old device-hash path LOSING (38.8k vs
-    75.8k sigs/s at 16k), so unproven means the safe side."""
-    mode = hash_mode()
-    if mode != "auto":
-        return mode
-    from cometbft_tpu.crypto.tpu import calibrate
-
-    floor = calibrate.hash_device_min_batch()
-    return "device" if floor is not None and n >= floor else "host"
-
-
-def wire_format() -> str:
-    """CBFT_TPU_WIRE: ``compact`` (default — raw uint8 rows, decompress
-    prologue on device) or ``words`` (the pre-PR-13 u32 word wire, kept
-    as the A/B and parity reference)."""
-    import os
-
-    fmt = os.environ.get("CBFT_TPU_WIRE", "compact")
-    if fmt not in ("compact", "words"):
-        raise ValueError(
-            f"unknown CBFT_TPU_WIRE={fmt!r}; choose from "
-            "['compact', 'words']"
-        )
-    return fmt
-
-
 def verify_batch(
     pub_keys: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
-    hash: Optional[str] = None,
 ) -> List[bool]:
-    """Public entry used by crypto.batch.TPUBatchVerifier. Packing runs
-    per dispatch chunk (the callable form of dispatch_batch) so the host
-    hashing of chunk i+1 overlaps the device's work on chunk i. The
+    """Public entry used by crypto.batch.TPUBatchVerifier: the compact
+    wire (prepare_batch_compact) through verify_kernel_compact. Packing
+    runs per dispatch chunk (the callable form of dispatch_batch) so the
+    host hashing of chunk i+1 overlaps the device's work on chunk i. The
     three columns are sequences of bytes-like lanes, taken as they are
     and only sliced here: a lane's bytes are copied once, by its
     launch's pack (``_parse_inputs``; the messages by the hash), and the
-    length / s < L checks are made there.
-
-    Route selection is two-dimensional: wire_format() picks compact
-    (raw uint8 rows, on-device decompress — the default) vs the legacy
-    u32 word wire, and hash_route(n) picks where SHA-512 runs (env pin
-    or the measured calibration crossover) unless the caller names the
-    placement (``hash`` — the calibration sweep times both)."""
+    length / s < L checks are made there."""
     n = len(pub_keys)
     if n == 0:
         return []
-    compact = wire_format() == "compact"
-    if (hash or hash_route(n)) == "device":
-        prepare = (
-            prepare_batch_device_hash_compact
-            if compact else prepare_batch_device_hash
-        )
-        kernel = (
-            verify_full_kernel_compact if compact else verify_full_kernel
-        )
-    else:
-        prepare = prepare_batch_compact if compact else prepare_batch
-        kernel = verify_kernel_compact if compact else verify_kernel
     valid_full = np.ones(n, bool)
 
     def chunk_pack(start: int, end: int):
-        (*packed, valid) = prepare(
+        wire, valid = prepare_batch_compact(
             pub_keys[start:end], msgs[start:end], sigs[start:end]
         )
         valid_full[start:end] = valid
-        return packed
+        return [wire]
 
     from cometbft_tpu.crypto.tpu import mesh as mesh_mod
 
     out = mesh_mod.dispatch_batch(
-        kernel, chunk_pack, n, _MAX_CHUNK, _MIN_PAD, launch=_LAUNCH_LANES
+        verify_kernel_compact, chunk_pack, n, _MAX_CHUNK, _MIN_PAD,
+        launch=_LAUNCH_LANES,
     )
     return (out & valid_full).tolist()
 
@@ -779,22 +569,16 @@ def verify_batch(
 # the set of signers varies per commit.
 
 
-# The cache itself now lives in the generational DeviceKeyStore
-# (crypto/tpu/keystore.py): same LRU + adopt-the-race-winner contract,
-# plus generation tagging (store generation, topology generation) and
-# the indexed-dispatch pubkey table. The module-level names below are
-# aliases onto the store's own state so existing callers (warmup, tests
-# that evict synthetic valsets) keep working unchanged.
+# The cache lives in the generational DeviceKeyStore
+# (crypto/tpu/keystore.py): LRU + adopt-the-race-winner, generation
+# tagging (store generation, topology generation) and the
+# indexed-dispatch pubkey table.
 from cometbft_tpu.crypto.tpu import keystore as _keystore_mod
 
 _keystore = _keystore_mod.default_store()
-_ResidentValset = _keystore_mod.KeyStoreEntry
-_RESIDENT_CACHE_MAX = _keystore_mod.CACHE_MAX
-_resident_cache = _keystore._entries
-_resident_mtx = _keystore._mtx
 
 
-def _get_resident(valset_id: bytes, pub_keys) -> _ResidentValset:
+def _get_resident(valset_id: bytes, pub_keys) -> _keystore_mod.KeyStoreEntry:
     return _keystore.get(valset_id, pub_keys, _build_resident)
 
 
@@ -815,34 +599,12 @@ verify_kernel_resident = jax.jit(_verify_core_resident)
 
 
 # AOT registration: stable names (never id()-keyed) plus the per-bucket
-# arg shape templates warm boot pre-compiles (crypto/tpu/aot.py).
-# verify_full_kernel has no template — its msg-block axis is ragged per
-# commit, so it cannot be bucket-warmed; it still gets a stable name.
-def _device_hash_reachable() -> bool:
-    """Can hash_route() send a dispatch to the device-hash kernel: the
-    env pin, or a calibration sweep in which on-device SHA-512 won."""
-    mode = hash_mode()
-    if mode != "auto":
-        return mode == "device"
-    from cometbft_tpu.crypto.tpu import calibrate
-
-    return calibrate.hash_device_min_batch() is not None
-
-
+# arg shape templates warm boot pre-compiles (crypto/tpu/aot.py). The
+# indexed kernel's table axis tracks the valset's size, so it has no
+# static template.
 def _register_aot_kernels():
     from cometbft_tpu.crypto.tpu import aot
 
-    # the u32 word wire and the device-hash pipeline are dispatched only
-    # when CBFT_TPU_WIRE / hash_route select them: warm boot skips what
-    # the routing in force cannot reach (``reachable``)
-    aot.register_kernel(
-        "ed25519.verify",
-        verify_kernel,
-        bucket_shapes=lambda b: [((32, b), np.uint32)],
-        reachable=lambda: wire_format() == "words",
-        forced=True,
-        launch=_LAUNCH_LANES,
-    )
     aot.register_kernel(
         "ed25519.verify_resident",
         verify_kernel_resident,
@@ -850,31 +612,11 @@ def _register_aot_kernels():
         donate_from=1,
         launch=_LAUNCH_LANES,
     )
-    aot.register_kernel("ed25519.verify_full", verify_full_kernel)
-    # compact-wire kernels (PR 13): the host-hash compact wire is the
-    # default dispatch route, so it gets the same bucket warm plan as
-    # the word wire it replaces. The device-hash compact kernel warms
-    # the 2-block message bucket (MP = 2·128 − 64 = 192 — every
-    # prevote/precommit lands there); other message paddings compile on
-    # first use. The indexed kernel's table axis tracks valset size, so
-    # it has no static template either.
     aot.register_kernel(
         "ed25519.verify_compact",
         verify_kernel_compact,
         bucket_shapes=lambda b: [((128, b), np.uint8)],
-        reachable=lambda: wire_format() == "compact",
         forced=True,
-        launch=_LAUNCH_LANES,
-    )
-    aot.register_kernel(
-        "ed25519.verify_full_compact",
-        verify_full_kernel_compact,
-        bucket_shapes=lambda b: [
-            ((96, b), np.uint8), ((192, b), np.uint8), ((b,), np.int32)
-        ],
-        reachable=lambda: (
-            wire_format() == "compact" and _device_hash_reachable()
-        ),
         launch=_LAUNCH_LANES,
     )
     aot.register_kernel(
@@ -885,7 +627,9 @@ def _register_aot_kernels():
 _register_aot_kernels()
 
 
-def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
+def _build_resident(
+    pub_keys: Sequence[bytes],
+) -> _keystore_mod.KeyStoreEntry:
     """Pad the valset's pubkey rows into the dispatch chunk layout and
     place them on device: sharded over the current shard plan's mesh
     (mesh.shard_plan: the healthy fault domains that own a chip) where
@@ -923,7 +667,7 @@ def _build_resident(pub_keys: Sequence[bytes]) -> _ResidentValset:
             a_dev = jax.device_put(jnp.asarray(a_words))
         chunks.append((start, end, size, a_dev))
 
-    rv = _ResidentValset()
+    rv = _keystore_mod.KeyStoreEntry()
     rv.chunks = chunks
     rv.plan = plan
     rv.pk_arr = pk_arr

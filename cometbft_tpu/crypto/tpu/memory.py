@@ -223,16 +223,10 @@ class MemoryPlane:
     def bytes_per_lane(self, kernel: str, lanes: int) -> float:
         """Modeled footprint per lane for a ``lanes``-wide padded chunk
         of ``kernel`` — the calibrated EWMA when the bucket (or any
-        neighbor) is warm, else the static Straus seed. A compact-wire
-        variant (``*_compact``) whose own model is cold borrows the base
-        kernel's calibration: the Straus working set dominates and is
-        identical, only the (smaller) input plane differs, so the base
-        model is a strictly-safe overestimate while the variant warms."""
+        neighbor) is warm, else the static Straus seed."""
         bucket = _pow2_bucket(lanes)
         with self._lock:
             buckets = self._model.get(kernel)
-            if not buckets and kernel.endswith("_compact"):
-                buckets = self._model.get(kernel[: -len("_compact")])
             if buckets:
                 if bucket in buckets:
                     return buckets[bucket]

@@ -20,8 +20,7 @@ which is what XLA:CPU's compile time follows); on every other platform
 the outer product's rows padded into place, stacked and summed: slices
 and adds. The same integers either way (exact int32 sums,
 |col| < 2^21). (2) Two V-folds of columns 19..37 with lo/hi product
-splits (the scalar.py sc_reduce pattern), on [19, B] / [5, B] slices.
-(3) Four vectorized carry rounds.
+splits, on [19, B] / [5, B] slices. (3) Four vectorized carry rounds.
 
 Verification-only: no constant-time requirements. Exactness is pinned
 by randomized chained-composition parity tests against CPython big-int

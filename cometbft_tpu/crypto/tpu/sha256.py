@@ -142,7 +142,7 @@ def sha256_blocks_ragged(
 
     Mixed-length batch: every lane runs all n_blocks compressions but
     keeps its state unchanged past its own live count — the branch-free
-    way to hash ragged messages (same trick as sha512.sha512_blocks)."""
+    way to hash ragged messages."""
     state = jnp.broadcast_to(jnp.asarray(_IV), blocks.shape[:-2] + (8,))
     for i in range(blocks.shape[-2]):  # small static count — unrolled
         new = _compress(state, blocks[..., i, :])

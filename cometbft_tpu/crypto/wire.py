@@ -113,21 +113,18 @@ DEFAULT_WINDOW = 64     # EWMA window (samples); alpha = 2 / (window + 1)
 _MAX_SAMPLES = 512      # per-phase percentile retention per profile
 _MAX_DISPATCHES = 128   # recent dispatch records kept for reconciliation
 # ed25519 verify wire: 32 B pubkey + 64 B sig + 32 B SHA-512 digest per
-# lane — the cold-boot bytes/lane guess before any chunk is observed.
-# Holds for both the compact uint8 wire (128 rows × 1 B) and the legacy
-# u32 word wire (32 rows × 4 B); the indexed key-store route (100
-# B/lane) and the device-hash route (96 B + message block) diverge from
-# it, which the live bytes_per_lane gauge then reflects.
+# lane (the compact uint8 wire, 128 rows × 1 B) — the cold-boot
+# bytes/lane guess before any chunk is observed; the indexed key-store
+# route (100 B/lane) diverges from it, which the live bytes_per_lane
+# gauge then reflects.
 DEFAULT_BYTES_PER_LANE = 128.0
 # Per-route cold-boot bytes/lane where the wire format is known to
 # diverge from the compact baseline: the indexed key-store route ships
-# 96 B compact R ‖ S ‖ h plus a 4 B int32 table index, the device-hash
-# route ships the 96 B rows without the precomputed digest. Used by
+# 96 B compact R ‖ S ‖ h plus a 4 B int32 table index. Used by
 # the cold link-probe seed so a never-observed indexed candidate is
 # priced with its real (smaller) transfer leg.
 ROUTE_BYTES_PER_LANE = {
     "indexed": 100.0,
-    "device_hash": 96.0,
     # verify-as-a-service row flushes: the socket payload IS the compact
     # wire (128 B/lane on the frame, re-used verbatim for device_put)
     "service": 128.0,

@@ -112,7 +112,8 @@ class TestLedgerCore:
         )
         dec = led.open(n=10, reason="size")
         assert dec.predicted["indexed"] == 2.0
-        assert "device_hash" not in dec.predicted
+        cold = DecisionLedger(cost_profile=_StubProfile({"single": 3.0}))
+        assert "indexed" not in cold.open(n=10, reason="size").predicted
 
     def test_self_ewma_outranks_wire_profile_once_warm(self):
         led = DecisionLedger(cost_profile=_StubProfile({"cpu": 100.0}))

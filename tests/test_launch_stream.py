@@ -170,7 +170,7 @@ def entry(request, monkeypatch, lanes):
 
                 monkeypatch.setattr(eb, "prepare_batch_compact", prepare)
                 with mesh.route_scope(mesh.ROUTE_SINGLE):
-                    return eb.verify_batch(pks, msgs, sigs, hash="host")
+                    return eb.verify_batch(pks, msgs, sigs)
 
             yield _Entry("single", "dev0", "mesh", [64] * 4, run, truth,
                          spans=SHORT_FIRST)
@@ -347,7 +347,7 @@ def test_a_keyed_flush_streams_at_the_launch_size(monkeypatch, forged):
     try:
         with tracelib.use(root), mesh.route_scope(mesh.ROUTE_SINGLE):
             got = eb.verify_batch(
-                [k.pub_key().bytes() for k in keys], msgs, sigs, hash="host")
+                [k.pub_key().bytes() for k in keys], msgs, sigs)
     finally:
         root.end()
         topology.set_default_topology(before)

@@ -180,7 +180,7 @@ class TestFeasibilityAndRegret:
         )
         feas = {
             "cpu": True, "single": True, "sharded": False,
-            "indexed": False, "device_hash": False,
+            "indexed": False,
         }
         dec = led.open(8, "test", feasible=feas)
         dec.taken = "single"
@@ -210,7 +210,7 @@ class TestFeasibilityAndRegret:
         )
         assert feas == {
             "cpu": True, "single": False, "sharded": False,
-            "indexed": False, "device_hash": False,
+            "indexed": False,
         }
         dec = ledger.open(64, "test", feasible=feas)
         with declib.use(dec):

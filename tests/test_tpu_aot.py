@@ -59,6 +59,47 @@ def tmp_store(tmp_path):
     aot.configure_exec_store(None)
 
 
+class TestEd25519Registrations:
+    def test_three_programs_two_warmed(self):
+        """A keyed ed25519 flush has one program: the registry holds the
+        compact, resident and indexed kernels and nothing else of the
+        curve, and the warm plan's input is the compact and resident
+        ones, with their bucket shapes and flags."""
+        from cometbft_tpu.crypto.tpu import ed25519_batch as eb
+
+        regs = {
+            name: r for name, r in aot._registered.items()
+            if name.startswith("ed25519.")
+        }
+        assert set(regs) == {
+            "ed25519.verify_compact", "ed25519.verify_resident",
+            "ed25519.verify_indexed",
+        }
+        warm = [
+            r.name for r in aot.registered_kernels()
+            if r.name.startswith("ed25519.")
+        ]
+        assert warm == ["ed25519.verify_resident", "ed25519.verify_compact"]
+        compact = regs["ed25519.verify_compact"]
+        assert compact.kernel is eb.verify_kernel_compact
+        assert compact.bucket_shapes(64) == [((128, 64), np.uint8)]
+        assert (compact.forced, compact.launch, compact.donate_from) == (
+            True, eb._LAUNCH_LANES, 0,
+        )
+        resident = regs["ed25519.verify_resident"]
+        assert resident.bucket_shapes(64) == [
+            ((8, 64), np.uint32), ((24, 64), np.uint32),
+        ]
+        assert (resident.forced, resident.launch, resident.donate_from) == (
+            False, eb._LAUNCH_LANES, 1,
+        )
+        indexed = regs["ed25519.verify_indexed"]
+        assert indexed.bucket_shapes is None
+        assert (indexed.forced, indexed.launch, indexed.donate_from) == (
+            False, None, 1,
+        )
+
+
 class TestStableKernelName:
     def test_registration_wins_and_pins(self):
         k = _toy_kernel()

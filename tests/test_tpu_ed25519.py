@@ -11,7 +11,7 @@ import pytest
 
 from cometbft_tpu.crypto import batch as cbatch
 from cometbft_tpu.crypto import ed25519 as ed
-from cometbft_tpu.crypto.tpu import ed25519_batch, field as fe
+from cometbft_tpu.crypto.tpu import ed25519_batch, field as fe, keystore
 
 
 def _cpu_verify(pk: bytes, msg: bytes, sig: bytes) -> bool:
@@ -555,15 +555,12 @@ def test_whole_kernel_parity_under_the_chips_form(monkeypatch):
         jax.clear_caches()
 
 
-class TestDeviceHashMode:
-    """CBFT_TPU_HASH=device: SHA-512 + sc_reduce + digits run on-device in
-    the same dispatch as the group math. Accept/reject must stay
-    bit-identical — including on small-order keys, where an inexact mod-L
-    would change [h](-A)."""
-
-    @pytest.fixture(autouse=True)
-    def _device_hash(self, monkeypatch):
-        monkeypatch.setenv("CBFT_TPU_HASH", "device")
+class TestHostChallengeEdges:
+    """verify_batch's host challenge h = SHA-512(R ‖ A ‖ M) mod L on the
+    edges the other batches do not reach: ragged messages of 0-300
+    bytes through the native call, a small-order key, where an inexact
+    mod-L would change [h](-A), and wrong lengths. Accept/reject must
+    stay bit-identical to the CPU verifier."""
 
     def test_valid_and_corrupted(self):
         rng = np.random.default_rng(5)
@@ -674,7 +671,8 @@ class TestValsetResident:
         # chunk cap 64 (= the kernel's min pad) + 100 lanes → 2 resident
         # chunks, with absent/corrupt lanes in BOTH chunks
         monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", "64")
-        ed25519_batch._resident_cache.clear()
+        store = keystore.default_store()
+        store.invalidate()
         n = 100
         keys, pks = self._valset(n)
         msgs, sigs = [], []
@@ -695,7 +693,7 @@ class TestValsetResident:
 
         vid = h.sha256(b"".join(pks)).digest()
         got = ed25519_batch.verify_valset_resident(vid, pks, msgs, sigs)
-        assert len(ed25519_batch._resident_cache[vid].chunks) == 2
+        assert len(store.entry_for(vid).chunks) == 2
         want = []
         for i in range(n):
             if msgs[i] is None:
@@ -713,7 +711,8 @@ class TestValsetResident:
 
     def test_cache_reused_across_commits_and_evicted_by_lru(self, monkeypatch):
         monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
-        ed25519_batch._resident_cache.clear()
+        store = keystore.default_store()
+        store.invalidate()
         import hashlib as h
 
         keys, pks = self._valset(8, tag=78)
@@ -724,18 +723,15 @@ class TestValsetResident:
             assert all(
                 ed25519_batch.verify_valset_resident(vid, pks, msgs, sigs)
             )
-        assert len(ed25519_batch._resident_cache) == 1  # one set, reused
+        assert store.residency()["entries"] == 1  # one set, reused
         # rotate through >MAX distinct valsets: LRU bounds the cache
-        for tag in range(100, 100 + ed25519_batch._RESIDENT_CACHE_MAX + 2):
+        for tag in range(100, 100 + keystore.CACHE_MAX + 2):
             ks, ps = self._valset(4, tag=tag)
             v = h.sha256(b"".join(ps)).digest()
             m = [b"x"] * 4
             s = [k.sign(b"x") for k in ks]
             assert all(ed25519_batch.verify_valset_resident(v, ps, m, s))
-        assert (
-            len(ed25519_batch._resident_cache)
-            == ed25519_batch._RESIDENT_CACHE_MAX
-        )
+        assert store.residency()["entries"] == keystore.CACHE_MAX
 
     def test_verify_commit_routes_resident(self, monkeypatch):
         """End-to-end: ValidatorSet.verify_commit under the tpu backend
@@ -743,7 +739,8 @@ class TestValsetResident:
         identical to the cpu backend."""
         monkeypatch.setenv("CBFT_TPU_MIN_BATCH", "1")
         monkeypatch.delenv("CBFT_TPU_MAX_CHUNK", raising=False)
-        ed25519_batch._resident_cache.clear()
+        store = keystore.default_store()
+        store.invalidate()
         from cometbft_tpu.types.test_util import (
             deterministic_validator_set,
             make_block_id,
@@ -755,7 +752,7 @@ class TestValsetResident:
         commit = make_commit(bid, 5, 1, vset, privs, "res-chain")
         vset.verify_commit("res-chain", bid, 5, commit, backend="cpu")
         vset.verify_commit("res-chain", bid, 5, commit, backend="tpu")
-        assert len(ed25519_batch._resident_cache) == 1  # resident path ran
+        assert store.residency()["entries"] == 1  # resident path ran
         # corrupt one signature: both backends must reject identically
         bad = bytearray(commit.signatures[2].signature)
         bad[6] ^= 1

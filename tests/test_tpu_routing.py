@@ -22,7 +22,7 @@ import pytest
 from cometbft_tpu.crypto import batch as cbatch
 from cometbft_tpu.crypto import merkle as cpu_merkle
 from cometbft_tpu.crypto.batch import BackendSpec
-from cometbft_tpu.crypto.tpu import calibrate, ed25519_batch
+from cometbft_tpu.crypto.tpu import calibrate, ed25519_batch, keystore
 from cometbft_tpu.crypto.tpu import merkle as tpu_merkle
 from cometbft_tpu.types import test_util
 from cometbft_tpu.types.validator_set import Fraction
@@ -119,6 +119,38 @@ class TestTableIO:
         st = os.stat(path)
         os.utime(path, (st.st_atime, st.st_mtime + 2))
         assert calibrate.ed25519_min_batch() == 128
+
+
+class TestRouteCostSeed:
+    """route_cost_seed_ms: the priced router's cold seed, from the
+    calibration table's measured points."""
+
+    _POINTS = {
+        "256": {"device_ms": 4.0, "cpu_ms": 8.0},
+        "1024": {"device_ms": 6.0, "cpu_ms": 30.0},
+    }
+
+    def test_cpu_and_single_scale_the_nearest_point(
+        self, tmp_path, clean_routing
+    ):
+        _write_table(str(tmp_path / "cal.json"), ed25519=self._POINTS)
+        assert calibrate.route_cost_seed_ms("single", 2048) == 12.0
+        assert calibrate.route_cost_seed_ms("cpu", 128) == 4.0
+        assert calibrate.route_cost_seed_ms("indexed", 256) is None
+
+    def test_an_old_hash_section_prices_nothing(
+        self, tmp_path, clean_routing
+    ):
+        # a table recorded while the calibration still swept the hash
+        # placement keeps loading; its hash section is simply not read
+        _write_table(
+            str(tmp_path / "cal.json"), ed25519=self._POINTS,
+            hash={"64": {"device_ms": 1.0, "host_ms": 2.0}},
+            hash_device_min_batch=64,
+        )
+        assert calibrate.load_table() is not None
+        assert calibrate.route_cost_seed_ms("device_hash", 64) is None
+        assert calibrate.route_cost_seed_ms("single", 256) == 4.0
 
 
 class TestEd25519FloorPrecedence:
@@ -258,7 +290,7 @@ class TestConcurrentResident:
         msgs = [b"race vote %d" % i for i in range(8)]
         sigs = [k.sign(m) for k, m in zip(keys, msgs)]
         vid = hashlib.sha256(b"".join(pks)).digest()
-        ed25519_batch._resident_cache.pop(vid, None)
+        keystore.default_store().invalidate(vid)
 
         barrier = threading.Barrier(2)
         results, errors = [None, None], []
@@ -282,7 +314,7 @@ class TestConcurrentResident:
             t.join(timeout=120)
         assert not errors, errors
         assert results[0] == results[1] == [True] * 8
-        assert vid in ed25519_batch._resident_cache
+        assert keystore.default_store().entry_for(vid) is not None
 
     def test_two_threads_verify_commit_concurrently(self, clean_routing):
         vals, privs = test_util.deterministic_validator_set(4, 10)

@@ -263,8 +263,7 @@ class TestPredictMsEdges:
 
     def test_cold_ledger_every_route_is_none(self):
         ledger = WireLedger()
-        for route in ("cpu", "single", "sharded", "indexed",
-                      "device_hash"):
+        for route in ("cpu", "single", "sharded", "indexed"):
             assert ledger.predict_ms(route, 64) is None
 
 

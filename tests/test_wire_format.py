@@ -1,14 +1,16 @@
-"""Compact uint8 wire — bit-identical to the legacy u32 word wire.
+"""The compact uint8 wire — the one program of a keyed ed25519 flush.
 
-The compact format (PR 13) ships raw 32-byte little-endian encodings as
-uint8 rows and reconstructs u32 words on device (bytes_to_words) before
-the shared limb-unpack / sign-extract / digit-window prologue. These
-tests pin the property the whole device-resident hot path rests on: for
-any batch — across chunk boundaries, non-canonical s, all-zero and
-all-ones rows — the on-device decompress produces bit-identical words
-and verdicts vs the host prepare_batch word wire. Runs on the virtual
-CPU mesh (conftest.py).
+The compact format ships raw 32-byte little-endian encodings as uint8
+rows and reconstructs u32 words on device (bytes_to_words) before the
+limb-unpack / sign-extract / digit-window prologue. These tests pin the
+property the whole hot path rests on: for any batch — across chunk
+boundaries, non-canonical s, all-zero and all-ones rows — the on-device
+words equal a host little-endian word view of the same bytes, and the
+verdicts equal the CPU verifier's. Runs on the virtual CPU mesh
+(conftest.py).
 """
+
+import hashlib
 
 import numpy as np
 import pytest
@@ -45,77 +47,83 @@ def _words_from_compact(wire_c):
     return np.asarray(eb.bytes_to_words(jnp.asarray(wire_c)))
 
 
+def _word_oracle(wire_c):
+    """u32[rows/4, B]: each lane's byte rows read as little-endian u32
+    words on the host (a ``"<u4"`` view), independent of the kernel."""
+    lanes = np.ascontiguousarray(wire_c.T)  # u8[B, rows]
+    return np.ascontiguousarray(lanes.view("<u4").T)
+
+
 def _kernel_verdicts(pks, msgs, sigs):
-    """(word-kernel mask, compact-kernel mask, shared valid) for one
-    un-chunked dispatch of both formats on identical inputs."""
+    """(compact-kernel mask, valid) for one un-chunked dispatch, after
+    checking that the device's words are the host oracle's."""
     import jax.numpy as jnp
 
-    wire_w, valid_w = eb.prepare_batch(pks, msgs, sigs)
     wire_c, valid_c = eb.prepare_batch_compact(pks, msgs, sigs)
-    np.testing.assert_array_equal(valid_w, valid_c)
-    got_w = np.asarray(eb.verify_kernel(jnp.asarray(wire_w)))
+    np.testing.assert_array_equal(
+        _words_from_compact(wire_c), _word_oracle(wire_c)
+    )
     got_c = np.asarray(eb.verify_kernel_compact(jnp.asarray(wire_c)))
-    return got_w, got_c, valid_w
+    return got_c, valid_c
 
 
 class TestWordReconstruction:
-    """bytes_to_words(compact rows) must equal the host word pack —
-    the limb planes downstream are then identical by construction."""
+    """bytes_to_words(compact rows) must equal a host little-endian word
+    view of the same bytes — the limb planes downstream are then the
+    ones the words define."""
 
     def test_bit_identical_words(self):
         pks, msgs, sigs = _batch(17, corrupt_every=5)
-        wire_w, valid_w = eb.prepare_batch(pks, msgs, sigs)
         wire_c, valid_c = eb.prepare_batch_compact(pks, msgs, sigs)
         assert wire_c.dtype == np.uint8
         assert wire_c.shape == (128, 17)
-        assert wire_w.shape == (32, 17)
-        np.testing.assert_array_equal(_words_from_compact(wire_c), wire_w)
-        np.testing.assert_array_equal(valid_c, valid_w)
+        words = _words_from_compact(wire_c)
+        assert words.shape == (32, 17)
+        np.testing.assert_array_equal(words, _word_oracle(wire_c))
+        # A's first word, from the key bytes themselves
+        for lane in range(17):
+            assert int(words[0, lane]) == int.from_bytes(
+                pks[lane][:4], "little"
+            )
+        assert valid_c.all()
 
     def test_row_layout(self):
-        # rows 0:32 A, 32:64 R, 64:96 S — raw bytes, lane-minor
+        # rows 0:32 A, 32:64 R, 64:96 S, 96:128 h — raw bytes,
+        # lane-minor; h = SHA-512(R ‖ A ‖ M) mod L
         pks, msgs, sigs = _batch(3)
         wire_c, _ = eb.prepare_batch_compact(pks, msgs, sigs)
         for lane in range(3):
             assert wire_c[0:32, lane].tobytes() == pks[lane]
             assert wire_c[32:64, lane].tobytes() == sigs[lane][:32]
             assert wire_c[64:96, lane].tobytes() == sigs[lane][32:]
-
-    def test_device_hash_wire_shares_point_rows(self):
-        # the 96-row device-hash wire is the host-hash wire minus h
-        pks, msgs, sigs = _batch(5)
-        full, _ = eb.prepare_batch_compact(pks, msgs, sigs)
-        wire, msg, mlen, valid = eb.prepare_batch_device_hash_compact(
-            pks, msgs, sigs
-        )
-        assert wire.shape == (96, 5)
-        np.testing.assert_array_equal(wire, full[:96])
-        assert np.all(valid)
-        assert list(mlen) == [len(m) for m in msgs]
+            h = int.from_bytes(
+                hashlib.sha512(
+                    sigs[lane][:32] + pks[lane] + msgs[lane]
+                ).digest(),
+                "little",
+            ) % _L
+            assert wire_c[96:128, lane].tobytes() == h.to_bytes(32, "little")
 
 
 class TestVerdictParity:
-    """Both kernels, identical batch → identical accept/reject masks,
-    and (& valid) identical to the serial CPU verifier."""
+    """The compact kernel's accept/reject mask (& valid) is the serial
+    CPU verifier's."""
 
     def test_mixed_valid_invalid(self):
         pks, msgs, sigs = _batch(13, corrupt_every=4)
-        got_w, got_c, valid = _kernel_verdicts(pks, msgs, sigs)
-        np.testing.assert_array_equal(got_w, got_c)
+        got_c, valid = _kernel_verdicts(pks, msgs, sigs)
         want = _cpu(pks, msgs, sigs)
         assert list(got_c & valid) == want
 
     def test_non_canonical_s(self):
         # s' = s + L encodes the same residue but MUST reject (the CPU
-        # path enforces canonical s); both wires carry the raw bytes and
-        # both must agree lane-for-lane
+        # path enforces canonical s); the wire carries the raw bytes
         pks, msgs, sigs = _batch(4, tag=b"noncanon")
         bad = list(sigs)
         for i in (1, 3):
             s_int = int.from_bytes(sigs[i][32:], "little")
             bad[i] = sigs[i][:32] + (s_int + _L).to_bytes(32, "little")
-        got_w, got_c, valid = _kernel_verdicts(pks, msgs, bad)
-        np.testing.assert_array_equal(got_w, got_c)
+        got_c, valid = _kernel_verdicts(pks, msgs, bad)
         assert list(valid) == [True, False, True, False]
         assert list(got_c & valid) == _cpu(pks, msgs, bad)
         assert _cpu(pks, msgs, bad) == [True, False, True, False]
@@ -123,70 +131,53 @@ class TestVerdictParity:
     def test_all_zero_and_all_ones_rows(self):
         # degenerate encodings: zero A (identity-adjacent y=0), zero
         # R/S, and 0xFF everywhere (y ≥ p, s ≥ L). No semantics asserted
-        # beyond: both formats produce the same words and the same
-        # verdicts, and nothing accepts that the CPU path rejects.
+        # beyond: the device's words are the host view of the bytes, and
+        # nothing accepts that the CPU path rejects.
         pks = [b"\x00" * 32, b"\xff" * 32, b"\x00" * 32, b"\xff" * 32]
         sigs = [b"\x00" * 64, b"\xff" * 64, b"\xff" * 64, b"\x00" * 64]
         msgs = [b"z", b"o", b"zo", b"oz"]
-        wire_w, _ = eb.prepare_batch(pks, msgs, sigs)
-        wire_c, _ = eb.prepare_batch_compact(pks, msgs, sigs)
-        np.testing.assert_array_equal(_words_from_compact(wire_c), wire_w)
-        got_w, got_c, valid = _kernel_verdicts(pks, msgs, sigs)
-        np.testing.assert_array_equal(got_w, got_c)
+        got_c, valid = _kernel_verdicts(pks, msgs, sigs)
         assert list(got_c & valid) == _cpu(pks, msgs, sigs)
-
-    def test_device_hash_compact_parity(self):
-        # fused on-device SHA-512 route, ragged message lengths
-        # straddling the 1-block/2-block boundary
-        import jax.numpy as jnp
-
-        rng = np.random.default_rng(23)
-        keys = [ed.gen_priv_key_from_secret(b"dh-%d" % i) for i in range(9)]
-        msgs = [bytes(rng.bytes(int(rng.integers(0, 200)))) for _ in keys]
-        sigs = [k.sign(m) for k, m in zip(keys, msgs)]
-        pks = [k.pub_key().bytes() for k in keys]
-        b = bytearray(sigs[2])
-        b[40] ^= 0x80
-        sigs[2] = bytes(b)
-
-        wire, msg, mlen, valid = eb.prepare_batch_device_hash_compact(
-            pks, msgs, sigs
-        )
-        got = np.asarray(
-            eb.verify_full_kernel_compact(
-                jnp.asarray(wire), jnp.asarray(msg), jnp.asarray(mlen)
-            )
-        )
-        _, host_c, _ = _kernel_verdicts(pks, msgs, sigs)
-        np.testing.assert_array_equal(got, host_c)
-        assert list(got & valid) == _cpu(pks, msgs, sigs)
 
 
 class TestChunkedCompactDispatch:
-    """verify_batch with the compact wire (the default) across chunk
-    boundaries: the staged-prefetch reassembly must keep lane order and
-    never smear a verdict onto a neighbor chunk."""
+    """verify_batch across chunk boundaries: the staged-prefetch
+    reassembly must keep lane order and never smear a verdict onto a
+    neighbor chunk."""
 
     @pytest.mark.parametrize("size", [63, 64, 65, 129])
     def test_boundary_sizes(self, size, monkeypatch):
         monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", "64")
-        monkeypatch.setenv("CBFT_TPU_WIRE", "compact")
-        monkeypatch.setenv("CBFT_TPU_HASH", "host")
         pks, msgs, sigs = _batch(size, tag=b"chunk", corrupt_every=9)
         got = eb.verify_batch(pks, msgs, sigs)
         assert got == _cpu(pks, msgs, sigs)
 
-    def test_words_and_compact_agree_chunked(self, monkeypatch):
-        monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", "64")
-        monkeypatch.setenv("CBFT_TPU_HASH", "host")
+    def test_chunked_agrees_with_one_dispatch(self, monkeypatch):
         pks, msgs, sigs = _batch(100, tag=b"agree", corrupt_every=7)
-        monkeypatch.setenv("CBFT_TPU_WIRE", "compact")
+        got_one, valid = _kernel_verdicts(pks, msgs, sigs)
+        monkeypatch.setenv("CBFT_TPU_MAX_CHUNK", "64")
         got_c = eb.verify_batch(pks, msgs, sigs)
-        monkeypatch.setenv("CBFT_TPU_WIRE", "words")
-        got_w = eb.verify_batch(pks, msgs, sigs)
-        assert got_c == got_w == _cpu(pks, msgs, sigs)
+        assert got_c == list(got_one & valid) == _cpu(pks, msgs, sigs)
 
-    def test_wire_format_env_validation(self, monkeypatch):
-        monkeypatch.setenv("CBFT_TPU_WIRE", "gzip")
-        with pytest.raises(ValueError, match="CBFT_TPU_WIRE"):
-            eb.wire_format()
+    @pytest.mark.parametrize(
+        "knob", ["CBFT_TPU_WIRE=words", "CBFT_TPU_HASH=device"]
+    )
+    def test_retired_knobs_leave_the_one_program(self, knob, monkeypatch):
+        # a setting of the retired wire and hash knobs, left in an
+        # operator's environment, changes nothing: verify_batch
+        # dispatches the compact kernel and answers the CPU's verdicts
+        from cometbft_tpu.crypto.tpu import mesh
+
+        name, value = knob.split("=")
+        monkeypatch.setenv(name, value)
+        kernels = []
+        real = mesh.dispatch_batch
+
+        def spy(kernel, *a, **kw):
+            kernels.append(kernel)
+            return real(kernel, *a, **kw)
+
+        monkeypatch.setattr(mesh, "dispatch_batch", spy)
+        pks, msgs, sigs = _batch(9, tag=b"retired", corrupt_every=4)
+        assert eb.verify_batch(pks, msgs, sigs) == _cpu(pks, msgs, sigs)
+        assert kernels == [eb.verify_kernel_compact]

@@ -1,6 +1,6 @@
 """Measure the SURVEY §7 stage-10 sharded mega-commit: a 10k-signature
-commit verified through _verify_core jitted over an explicit device mesh
-with the batch (lane) axis sharded.
+commit verified through _verify_core_compact jitted over an explicit
+device mesh with the batch (lane) axis sharded.
 
 Runs on the virtual 8-device CPU mesh (the bench variants stage runs the
 same program via _sharded_mega_commit) and prints one JSON record naming
@@ -46,7 +46,7 @@ for i in range(N):
     pks.append(k.pub_key().bytes())
     msgs.append(m)
     sigs.append(k.sign(m))
-(*packed, valid) = ed25519_batch.prepare_batch(pks, msgs, sigs)
+(*packed, valid) = ed25519_batch.prepare_batch_compact(pks, msgs, sigs)
 assert valid.all()
 t_prep = time.time() - t_start
 
@@ -64,7 +64,7 @@ shardings = tuple(
     for a in packed
 )
 step = jax.jit(
-    ed25519_batch._verify_core,
+    ed25519_batch._verify_core_compact,
     in_shardings=shardings,
     out_shardings=NamedSharding(mesh, PS("batch")),
 )
